@@ -789,20 +789,32 @@ class DevicePager:
             self._led.close_group(e.group)
 
 
+def backend_info() -> dict:
+    """What jax is actually running on: ``platform``, ``device_kind``
+    and ``device_count`` of the default backend.  Initializes the
+    backend on first call (and raises if it cannot)."""
+    import opensearch_tpu.common.jaxenv  # noqa: F401
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_count": len(devs)}
+
+
 def _backend_memory_stats() -> dict:
     """``jax`` device ``memory_stats()`` where the backend provides it
     (TPU/GPU do; CPU returns None) — the allocator's own view next to
     the ledger's."""
     try:
         import jax
-        dev = jax.devices()[0]
-        raw = dev.memory_stats()
+        info = backend_info()
+        raw = jax.devices()[0].memory_stats()
         if not raw:
-            return {"available": False, "platform": dev.platform}
+            return {"available": False, **info}
         keep = {k: int(v) for k, v in raw.items()
                 if isinstance(v, (int, float)) and (
                     "bytes" in k or "allocs" in k)}
-        return {"available": True, "platform": dev.platform, **keep}
+        return {"available": True, **info, **keep}
     except Exception:
         return {"available": False}
 

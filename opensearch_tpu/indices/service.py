@@ -876,8 +876,7 @@ class IndexService:
 
     def _mesh_degrade(self, body: dict, reason: str) -> dict:
         """Demote a mesh request to the counted host scatter fallback:
-        an unavailable shard_map, a mesh that cannot be built (member
-        loss / too few devices), an open ``mesh`` circuit breaker, or a
+        a mesh that cannot be built (member loss / too few devices), an open ``mesh`` circuit breaker, or a
         device error mid-collective all land here — the request
         degrades (same per-shard scoring stats, coordinator-order
         merge), never 500s."""
@@ -896,18 +895,8 @@ class IndexService:
         from opensearch_tpu.common.device_health import (device_health,
                                                          is_device_error)
         from opensearch_tpu.search import insights
+        from opensearch_tpu.parallel.dist_search import MeshSearcher
         health = device_health()
-        try:
-            from opensearch_tpu.parallel import dist_search
-            if not dist_search.MESH_AVAILABLE:
-                raise ImportError("no shard_map in this jax")
-            MeshSearcher = dist_search.MeshSearcher
-        except ImportError:
-            # graceful degradation: a jax without any shard_map spelling
-            # (see parallel/dist_search.py) must not 500 the request —
-            # the host scatter preserves mesh semantics minus the ICI
-            # collective, and the fallback is a counted, alertable event
-            return self._mesh_degrade(body, "shard_map unavailable")
         if not health.allow("mesh"):
             # open mesh breaker: don't re-attempt a failing collective
             # per request — demote until a half-open probe re-closes it

@@ -583,8 +583,12 @@ def main(argv=None):
     node = Node(args.data_path, name=args.name,
                 cluster_name=args.cluster_name, host=args.host,
                 port=args.port).start()
+    from opensearch_tpu.common.device_ledger import backend_info
+    dev = backend_info()
     print(f"[{args.name}] listening on http://{args.host}:{node.port} "
-          f"(data: {args.data_path})", flush=True)
+          f"(data: {args.data_path}; platform: {dev['platform']}, "
+          f"device_kind: {dev['device_kind']}, "
+          f"devices: {dev['device_count']})", flush=True)
     stop = threading.Event()
     signal.signal(signal.SIGTERM, lambda *_: stop.set())
     signal.signal(signal.SIGINT, lambda *_: stop.set())

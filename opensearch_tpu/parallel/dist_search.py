@@ -26,38 +26,10 @@ import numpy as np
 import opensearch_tpu.common.jaxenv  # noqa: F401
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# jax moved shard_map out of experimental (and renamed the replication
-# checker kwarg check_rep -> check_vma) across the versions this engine
-# supports; normalize on one callable so the mesh path works on both.
-# When neither spelling exists the mesh is unavailable and
-# IndexService._mesh_search degrades to the host scatter path (counted
-# in search.mesh.fallback) instead of crashing the request.
-try:
-    from jax import shard_map as _shard_map_impl
-    _CHECK_KW = "check_vma"
-except ImportError:                    # pre-0.6 jax: experimental module
-    try:
-        from jax.experimental.shard_map import shard_map as _shard_map_impl
-        _CHECK_KW = "check_rep"
-    except ImportError:
-        _shard_map_impl = None
-        _CHECK_KW = None
-
-MESH_AVAILABLE = _shard_map_impl is not None
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    if _shard_map_impl is None:
-        raise ImportError("no shard_map in this jax installation")
-    kw = {_CHECK_KW: check_vma}
-    return _shard_map_impl(f, mesh=mesh, in_specs=in_specs,
-                           out_specs=out_specs, **kw)
-
-
-from opensearch_tpu.ops import bm25 as bm25_ops   # noqa: E402
+from opensearch_tpu.ops import bm25 as bm25_ops
 
 
 def make_mesh(n_devices: int, axis: str = "shards") -> Mesh:
@@ -268,8 +240,7 @@ class MeshSearcher:
         """size:0 metric-agg request fully on the mesh: every shard
         computes its (sum, count, min, max) partial on its own device,
         ONE collective reduces them over ICI, and the host reads back
-        5 scalars per agg — no per-shard partial serialization
-        (VERDICT r4 weak #5: the agg reduce as a collective)."""
+        5 scalars per agg — no per-shard partial serialization."""
         import time as _time
 
         from opensearch_tpu.ops import aggs as agg_ops
@@ -488,21 +459,23 @@ class MeshSearcher:
 
 def sharded_metric_reduce(mesh: Mesh, axis: str = "shards"):
     """[S, 5] per-shard metric partials (sum, count, min, max, total) ->
-    one replicated [5] via ICI collectives — the device-side
+    one replicated [5] over ICI — the device-side
     InternalAggregations.reduce for the metric family
     (SearchPhaseController.reducedQueryPhase riding the mesh instead of
-    the coordinator's heap)."""
+    the coordinator's heap).
 
-    @partial(shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P())
+    ONE all-gather, then every device folds the S rows itself: the
+    partials are float64 (see ``mesh_metric_aggs``), which the TPU
+    emulates, and its emulated all-reduce implements sum only — a
+    ``pmin``/``pmax`` over float64 does not compile there."""
+
+    @partial(shard_map, mesh=mesh, in_specs=P(axis, None), out_specs=P(),
+             check_vma=False)
     def reduce(parts):
-        row = parts[0]
-        return jnp.stack([
-            lax.psum(row[0], axis),
-            lax.psum(row[1], axis),
-            lax.pmin(row[2], axis),
-            lax.pmax(row[3], axis),
-            lax.psum(row[4], axis),
-        ])
+        rows = lax.all_gather(parts[0], axis)          # [S, 5] everywhere
+        return jnp.stack([rows[:, 0].sum(), rows[:, 1].sum(),
+                          rows[:, 2].min(), rows[:, 3].max(),
+                          rows[:, 4].sum()])
 
     return reduce
 

@@ -37,6 +37,7 @@ import numpy as np
 
 import opensearch_tpu.common.jaxenv  # noqa: F401
 import jax.numpy as jnp
+from jax import lax
 
 from opensearch_tpu.common.errors import OpenSearchTpuError
 
@@ -272,14 +273,17 @@ class _Evaluator(ast.NodeVisitor):
                 q = self.visit(node.args[0])
                 f = _doc_field_of(node.args[1])
                 vec, exists = self.vectors[f]
-                dots = vec @ q
+                # exact scoring: see ops/knn.py on matmul precision
+                dots = jnp.matmul(vec, q,
+                                  precision=lax.Precision.HIGHEST)
+                q2 = jnp.sum(q * q)
                 if name == "dotProduct":
                     return dots
                 if name == "l2Squared":
                     v2 = jnp.sum(vec * vec, axis=1)
-                    return jnp.maximum(v2 - 2.0 * dots + jnp.dot(q, q), 0.0)
+                    return jnp.maximum(v2 - 2.0 * dots + q2, 0.0)
                 norms = jnp.sqrt(jnp.sum(vec * vec, axis=1))
-                qn = jnp.sqrt(jnp.dot(q, q))
+                qn = jnp.sqrt(q2)
                 return dots / jnp.maximum(norms * qn, 1e-30)
             if name in _BARE_FNS:
                 args = [self.visit(a) for a in node.args]

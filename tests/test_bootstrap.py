@@ -26,6 +26,22 @@ def test_default_checks_run_and_report_cleanly(tmp_path):
                      "accelerator runtime"}
 
 
+def test_accelerator_check_initializes_the_backend(monkeypatch):
+    """The check brings the backend up at boot (a failure there must not
+    wait for the first search) and reports a backend that cannot."""
+    from opensearch_tpu import bootstrap
+    from opensearch_tpu.common import device_ledger
+
+    assert bootstrap._accelerator_check() is None
+    assert device_ledger.backend_info()["platform"] == "cpu"
+
+    def broken():
+        raise RuntimeError("Unable to initialize backend 'tpu'")
+    monkeypatch.setattr(device_ledger, "backend_info", broken)
+    msg = bootstrap._accelerator_check()
+    assert msg.startswith("jax runtime unavailable") and "tpu" in msg
+
+
 def test_enforce_reports_all_failures():
     checks = [BootstrapCheck("ok", lambda: None),
               BootstrapCheck("a", lambda: "first problem"),

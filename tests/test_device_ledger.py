@@ -356,7 +356,11 @@ def test_nodes_stats_device_section_and_budget_setting(node):
     assert dev["transfers"]["stage"]["bytes"] > 0
     assert dev["transfers"]["fetch"]["bytes"] > 0
     assert dev["compile_registry"]["total"] >= 1
-    assert "backend" in dev
+    # what jax actually runs on, so a node that came up on the wrong
+    # backend says so from the client's side
+    assert dev["backend"]["platform"] == "cpu"
+    assert dev["backend"]["device_kind"] == "cpu"
+    assert dev["backend"]["device_count"] == 8
     # dynamic budget below the footprint -> counted eviction, and the
     # SAME query answers byte-identically off the host tables
     s, _ = call(node, "PUT", "/_cluster/settings", {

@@ -475,13 +475,14 @@ def test_cat_recovery_and_nodes_stats_recovery_section(node):
 
 # -- mesh fallback (satellite: the pre-existing 500) ------------------------
 
-def test_mesh_unavailable_degrades_to_host_scatter(node, monkeypatch):
-    """With no shard_map in jax, index.search.mesh must not 500: the
+def test_mesh_unavailable_degrades_to_host_scatter(node):
+    """A mesh that loses a member mid-collective must not 500: the
     host scatter serves the request with mesh semantics (per-shard
     scoring stats, coordinator merge order) and the fallback is counted
     in search.mesh.fallback."""
-    from opensearch_tpu.parallel import dist_search
+    from opensearch_tpu.common.device_health import device_health
     from opensearch_tpu.search.executor import merge_hit_rows
+    from opensearch_tpu.testing.fault_injection import DeviceFaultInjector
     s, _ = call(node, "PUT", "/meshfall", {
         "settings": {"number_of_shards": 4, "search.mesh": True},
         "mappings": {"properties": {"t": {"type": "text"},
@@ -495,13 +496,18 @@ def test_mesh_unavailable_degrades_to_host_scatter(node, monkeypatch):
                 ndjson=lines)
     assert s == 200 and not r["errors"]
 
-    monkeypatch.setattr(dist_search, "MESH_AVAILABLE", False)
     node.insights.reset()
     before = metrics().counter("search.mesh.fallback").value
     body = {"query": {"match": {"t": "common"}}, "size": 8}
     svc = node.indices.get("meshfall")
     assert svc._use_mesh(body)          # the request still opts in
-    s, resp = call(node, "POST", "/meshfall/_search", body)
+    inj = DeviceFaultInjector(seed=5)
+    inj.lose_mesh_member()
+    try:
+        with inj:
+            s, resp = call(node, "POST", "/meshfall/_search", body)
+    finally:
+        device_health().reset()         # the loss counted a mesh failure
     assert s == 200, resp               # no 500
     assert metrics().counter("search.mesh.fallback").value == before + 1
     assert resp["hits"]["total"]["value"] == 40
@@ -522,12 +528,9 @@ def test_mesh_unavailable_degrades_to_host_scatter(node, monkeypatch):
     assert "mesh_fallback" in paths
 
 
-def test_mesh_shim_still_serves_mesh_when_available(node):
-    """Regression guard for the shard_map compat shim itself: when the
-    mesh IS available the request takes it (no fallback count)."""
-    from opensearch_tpu.parallel import dist_search
-    if not dist_search.MESH_AVAILABLE:
-        pytest.skip("no shard_map in this jax")
+def test_mesh_serves_mesh_when_healthy(node):
+    """With every member present the request takes the mesh (no
+    fallback count)."""
     before = metrics().counter("search.mesh.fallback").value
     body = {"query": {"match": {"t": "common"}}, "size": 5}
     s, resp = call(node, "POST", "/meshfall/_search", body)

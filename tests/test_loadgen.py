@@ -405,3 +405,36 @@ def test_check_seeded_rng_covers_loadgen():
         [sys.executable, TOOLS + "/check_seeded_rng.py", loadgen],
         capture_output=True, text=True)
     assert out.returncode == 0, out.stdout + out.stderr
+
+
+def test_bench_is_one_process_and_a_raised_phase_fails_it(tmp_path):
+    """`python bench.py` runs its phases in ONE process on jax's default
+    backend (here the CPU conftest selects), prints exactly one result
+    line for the live run, keeps going past a phase that raises — and
+    then exits non-zero."""
+    import json
+    import os
+    import subprocess
+    import sys
+
+    phases = tmp_path / "phases.jsonl"
+    env = dict(os.environ, OSTPU_BENCH_PHASES=str(phases),
+               OSTPU_BENCH_DOCS="2000", OSTPU_BENCH_QUERIES="64",
+               OSTPU_BENCH_CONCURRENCY="not-a-number")  # continuous raises
+    for gate in ("SCALE", "SOAK", "LOAD", "AUTOSCALE", "QOS", "DEVFAULTS",
+                 "TIER"):
+        env[f"OSTPU_BENCH_{gate}"] = "0"
+    r = subprocess.run([sys.executable, REPO + "/bench.py"], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 1, r.stderr[-2000:]
+    out = [json.loads(ln) for ln in r.stdout.splitlines()]
+    assert len(out) == 1 and out[0]["platform"] == "cpu"
+    assert "recorded" not in out[0] and out[0]["value"] > 0
+    lines = [json.loads(ln) for ln in phases.read_text().splitlines()]
+    names = [ln["phase"] for ln in lines]
+    assert "ValueError" in lines[names.index("continuous")]["error"]
+    # the phases before AND after the one that raised still ran
+    assert names[:4] == ["baseline", "smoke", "batched", "sequential"]
+    assert {"profile", "insights", "device"} <= set(
+        names[names.index("continuous"):])
+    assert all("attempt" not in ln for ln in lines)

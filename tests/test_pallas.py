@@ -1,5 +1,6 @@
-"""Pallas kernel parity: the hand-scheduled TPU kernels must agree
-exactly with the XLA-fused jnp formulations (interpret mode on CPU)."""
+"""Pallas kernel parity: the hand-scheduled TPU kernels must agree with
+the XLA-fused jnp formulations (interpret mode on CPU; chip_smoke.py
+compiles them on the chip against a float32 numpy reference)."""
 
 import jax.numpy as jnp
 import numpy as np
@@ -43,6 +44,29 @@ def test_topk_auto_pallas_path(data, monkeypatch):
     rv, ri = knn_topk(vectors, valid, query, space="l2", k=7)
     np.testing.assert_allclose(np.asarray(pv), np.asarray(rv), rtol=1e-6)
     np.testing.assert_array_equal(np.asarray(pi), np.asarray(ri))
+
+
+@pytest.mark.parametrize("backend,interpret",
+                         [("cpu", True), ("tpu", False), ("gpu", False)])
+def test_topk_auto_interprets_only_on_cpu(data, monkeypatch, backend,
+                                          interpret):
+    """cpu -> interpret, anything else -> compile."""
+    import jax
+
+    from opensearch_tpu.ops import pallas_knn
+
+    seen = []
+
+    def fake(vectors, valid, query, *, space, interpret):
+        seen.append(interpret)
+        return knn_scores(vectors, valid, query, space=space)
+
+    monkeypatch.setenv("OSTPU_PALLAS", "1")
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    monkeypatch.setattr(pallas_knn, "knn_scores_pallas", fake)
+    vectors, valid, query = data
+    knn_topk_auto(vectors, valid, query, space="l2", k=3)
+    assert seen == [interpret]
 
 
 def test_topk_auto_falls_back_on_odd_layout(rng, monkeypatch):

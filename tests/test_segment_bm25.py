@@ -132,3 +132,96 @@ def test_multivalued_numeric_dv(segment):
     assert dv.value_docs.tolist() == [0, 0, 0, 1, 3, 3]
     assert dv.minv[0] == 1 and dv.maxv[0] == 3
     assert not dv.exists[2]
+
+
+# -- write path: per-field columns are built once, not once per doc ----------
+
+DV_MAPPING = {"properties": {
+    "body": {"type": "text"}, "n": {"type": "long"},
+    "x": {"type": "double"}, "k": {"type": "keyword"},
+    "g": {"type": "geo_point"}}}
+
+
+def _dv_docs(n_docs: int, seed: int = 5) -> list:
+    """Sparse, multi-valued doc-value fields: every fourth doc lacks
+    ``x``, every fifth has two ``n`` values, every seventh lacks ``g``."""
+    rng = random.Random(seed)
+    docs = []
+    for i in range(n_docs):
+        d = {"body": " ".join(rng.choice(VOCAB) for _ in range(6)),
+             "n": [i, i % 13] if i % 5 == 0 else i,
+             "k": [f"k{i % 11}", f"k{i % 3}"]}
+        if i % 4:
+            d["x"] = rng.random() * 100
+        if i % 7:
+            d["g"] = {"lat": rng.uniform(-80, 80),
+                      "lon": rng.uniform(-170, 170)}
+        docs.append(d)
+    return docs
+
+
+def test_build_columns_equal_the_per_doc_loop_reference():
+    """The columns equal what the straightforward per-doc loop yields
+    (one list per doc per field, filled by setdefault as the writer used
+    to, at O(n) per doc)."""
+    mapper = DocumentMapper(DV_MAPPING)
+    parsed = [mapper.parse(str(i), d)
+              for i, d in enumerate(_dv_docs(200))]
+    n = len(parsed)
+    seg = SegmentWriter().build(parsed, "cols")
+
+    longs, doubles, ordinals, geos, lens, present = {}, {}, {}, {}, {}, {}
+    for i, doc in enumerate(parsed):
+        for f, vals in doc.longs.items():
+            longs.setdefault(f, [[] for _ in range(n)])[i].extend(vals)
+        for f, vals in doc.doubles.items():
+            doubles.setdefault(f, [[] for _ in range(n)])[i].extend(vals)
+        for f, vals in doc.ordinals.items():
+            ordinals.setdefault(f, [[] for _ in range(n)])[i].extend(vals)
+        for f, pts in doc.geo_points.items():
+            geos.setdefault(f, [[] for _ in range(n)])[i].extend(pts)
+        for f, length in doc.field_lengths.items():
+            lens.setdefault(f, np.zeros(n, np.float32))[i] = length
+            present.setdefault(f, np.zeros(n, bool))[i] = True
+
+    def same(a, b):
+        for name, want in vars(b).items():
+            got = getattr(a, name)
+            if isinstance(want, np.ndarray):
+                np.testing.assert_array_equal(got, want, err_msg=name)
+            else:
+                assert got == want, name
+
+    assert set(seg.numeric_dv) == set(longs) | set(doubles) == {"n", "x"}
+    for f, per_doc in longs.items():
+        same(seg.numeric_dv[f],
+             SegmentWriter._build_numeric(per_doc, n, "long"))
+    for f, per_doc in doubles.items():
+        same(seg.numeric_dv[f],
+             SegmentWriter._build_numeric(per_doc, n, "double"))
+    assert set(seg.ordinal_dv) == set(ordinals) == {"k"}
+    for f, per_doc in ordinals.items():
+        same(seg.ordinal_dv[f], SegmentWriter._build_ordinal(per_doc, n))
+    assert set(seg.geo_dv) == set(geos) == {"g"}
+    for f, per_doc in geos.items():
+        same(seg.geo_dv[f], SegmentWriter._build_geo(per_doc, n))
+    for f in lens:
+        np.testing.assert_array_equal(seg.postings[f].doc_lens, lens[f])
+        np.testing.assert_array_equal(seg.postings[f].present, present[f])
+
+
+def test_build_30k_docs_with_doc_values_inside_a_fixed_bound():
+    """One refresh-sized build stays linear in the buffered docs: 30,000
+    docs with long, double, keyword and geo fields finish well inside a
+    minute (evaluating an n-element default per doc per field took well
+    over ten)."""
+    import time
+
+    mapper = DocumentMapper(DV_MAPPING)
+    parsed = [mapper.parse(str(i), d)
+              for i, d in enumerate(_dv_docs(30_000))]
+    t0 = time.monotonic()
+    seg = SegmentWriter().build(parsed, "big")
+    assert time.monotonic() - t0 < 60.0
+    assert seg.n_docs == 30_000
+    assert len(seg.numeric_dv["n"].values) == 30_000 + 6_000
