@@ -109,6 +109,9 @@ class KernelCompileRegistry:
          "topk_from_scores"),
         ("batch.batch_impact_union_topk", "opensearch_tpu.search.batch",
          "batch_impact_union_topk"),
+        ("knn.knn_scores", "opensearch_tpu.ops.knn", "knn_scores"),
+        ("knn.knn_topk", "opensearch_tpu.ops.knn", "knn_topk"),
+        ("knn.knn_topk_batch", "opensearch_tpu.ops.knn", "knn_topk_batch"),
     )
 
     def __init__(self):

@@ -13,28 +13,41 @@ at the fidelity this engine needs:
   histograms with percentile readout, surfaced by ``_nodes/stats``
   under a ``telemetry`` section.
 
-Timing uses ``time.monotonic`` (durations must never jump with wall
-clock); span start/end wall timestamps are kept separately for display.
-Everything is cheap enough to stay always-on: a span is one small object
-and two dict writes, matching the reference's default no-sampling OTel
-configuration in tests.
+A span reads two clocks once, at its start: ``time.monotonic_ns`` (the
+clock durations come from, and the one a profiler session's markers are
+stamped with) and ``time.time_ns`` (the wall timestamp, for display).
+Spans opened with ``start_span`` also enter a
+``jax.profiler.TraceAnnotation``, so a profiler session shows them on
+host lines of the same trace as the device's operations.  Everything
+stays always-on: a span is one small object appended to a ring, and its
+dict is only built when somebody reads the ring.
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import threading
 import time
-import uuid
 from bisect import bisect_left
 from collections import deque
+from random import getrandbits
 from typing import Optional
 
 _current_span: "contextvars.ContextVar[Optional[Span]]" = \
     contextvars.ContextVar("opensearch_tpu_span", default=None)
 
 TRACEPARENT = "traceparent"
+
+_trace_annotation = None      # jax.profiler.TraceAnnotation, on first use
+
+
+def _load_trace_annotation():
+    global _trace_annotation
+    from jax.profiler import TraceAnnotation
+    _trace_annotation = TraceAnnotation
+    return TraceAnnotation
 
 
 class SpanContext:
@@ -70,17 +83,21 @@ class Span:
     """One timed operation.  ``end()`` freezes the duration and ships the
     span to the tracer's in-memory exporter."""
 
+    __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_span_id",
+                 "attributes", "start_nanos", "start_wall_nanos",
+                 "duration_nanos", "error")
+
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_span_id: Optional[str],
                  attributes: Optional[dict] = None):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
-        self.span_id = uuid.uuid4().hex[:16]
+        self.span_id = f"{getrandbits(64):016x}"
         self.parent_span_id = parent_span_id
-        self.attributes: dict = dict(attributes or {})
-        self.start_time_millis = int(time.time() * 1000)  # wall-clock: display timestamp
-        self._start = time.monotonic()
+        self.attributes: dict = dict(attributes) if attributes else {}
+        self.start_nanos = time.monotonic_ns()
+        self.start_wall_nanos = time.time_ns()  # wall-clock: display timestamp
         self.duration_nanos: Optional[int] = None
         self.error: Optional[str] = None
 
@@ -97,19 +114,50 @@ class Span:
     def end(self) -> None:
         if self.duration_nanos is not None:
             return                       # idempotent
-        self.duration_nanos = int((time.monotonic() - self._start) * 1e9)
+        self.duration_nanos = time.monotonic_ns() - self.start_nanos
         self.tracer._export(self)
 
     def to_dict(self) -> dict:
         out = {"name": self.name, "trace_id": self.trace_id,
                "span_id": self.span_id,
                "parent_span_id": self.parent_span_id,
-               "start_time_in_millis": self.start_time_millis,
+               # wall clock, the fraction kept to the microsecond
+               "start_time_in_millis": self.start_wall_nanos // 1000 / 1e3,
+               # time.monotonic_ns: orders spans against each other and
+               # against anything else stamped with that clock
+               "start_time_in_nanos": self.start_nanos,
                "duration_in_nanos": self.duration_nanos,
                "attributes": dict(self.attributes)}
         if self.error is not None:
             out["error"] = self.error
         return out
+
+
+class _SpanScope:
+    """``with tracer.start_span(...) as span``: the span is current for
+    the body and mirrored into a profiler session, if one is running."""
+
+    __slots__ = ("span", "_token", "_mirror")
+
+    def __init__(self, span: Span):
+        self.span = span
+
+    def __enter__(self) -> Span:
+        span = self.span
+        self._token = _current_span.set(span)
+        self._mirror = mirror = (_trace_annotation
+                                 or _load_trace_annotation())(span.name)
+        mirror.__enter__()
+        return span
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self._mirror.__exit__(exc_type, exc, tb)
+        _current_span.reset(self._token)
+        span = self.span
+        if exc is not None:
+            span.record_error(exc)
+        span.end()
+        return False
 
 
 class Tracer:
@@ -122,46 +170,34 @@ class Tracer:
     shard executions join the coordinator's trace.
     """
 
-    def __init__(self, max_spans: int = 2048):
-        self._finished: "deque[dict]" = deque(maxlen=max_spans)
-        self._lock = threading.Lock()
+    def __init__(self, max_spans: int = 8192):
+        # appended to by every thread that ends a span: a deque's append
+        # is thread-safe, so ending a span takes no lock
+        self._finished: "deque[Span]" = deque(maxlen=max_spans)
 
     # -- span lifecycle ---------------------------------------------------
 
     def begin_span(self, name: str, attributes: Optional[dict] = None,
                    parent: "SpanContext | Span | None" = None) -> Span:
-        """Non-context-manager start (callers that end() across scopes)."""
+        """Non-context-manager start (callers that end() across scopes
+        or threads; not mirrored into the profiler's trace)."""
         if parent is None:
             parent = _current_span.get()
         if parent is None:
-            trace_id, parent_id = uuid.uuid4().hex, None
-        else:
-            trace_id = parent.trace_id
-            parent_id = (parent.span_id if isinstance(parent, SpanContext)
-                         else parent.span_id)
-        return Span(self, name, trace_id, parent_id, attributes)
+            return Span(self, name, f"{getrandbits(128):032x}", None,
+                        attributes)
+        return Span(self, name, parent.trace_id, parent.span_id, attributes)
 
-    @contextlib.contextmanager
     def start_span(self, name: str, attributes: Optional[dict] = None,
-                   parent: "SpanContext | Span | None" = None):
-        span = self.begin_span(name, attributes, parent)
-        token = _current_span.set(span)
-        try:
-            yield span
-        except BaseException as e:
-            span.record_error(e)
-            raise
-        finally:
-            _current_span.reset(token)
-            span.end()
+                   parent: "SpanContext | Span | None" = None) -> _SpanScope:
+        return _SpanScope(self.begin_span(name, attributes, parent))
 
     @staticmethod
     def current() -> Optional[Span]:
         return _current_span.get()
 
     def _export(self, span: Span) -> None:
-        with self._lock:
-            self._finished.append(span.to_dict())
+        self._finished.append(span)
 
     # -- context propagation (TracingContextPropagator analog) ------------
 
@@ -191,16 +227,19 @@ class Tracer:
     def recent(self, limit: int = 100,
                trace_id: Optional[str] = None) -> list[dict]:
         """Most-recent finished spans, newest first."""
-        with self._lock:
-            spans = list(self._finished)
+        while True:
+            try:
+                spans = list(self._finished)
+                break
+            except RuntimeError:         # a span ended during the copy
+                continue
         spans.reverse()
         if trace_id:
-            spans = [s for s in spans if s["trace_id"] == trace_id]
-        return spans[: max(0, int(limit))]
+            spans = [s for s in spans if s.trace_id == trace_id]
+        return [s.to_dict() for s in spans[: max(0, int(limit))]]
 
     def reset(self) -> None:
-        with self._lock:
-            self._finished.clear()
+        self._finished.clear()
 
 
 # default latency buckets in milliseconds (upper bounds; +inf implied) —
@@ -460,6 +499,36 @@ class FlightRecorder:
             self._ring.clear()
 
 
+class GcTimer:
+    """A ``gc.callbacks`` hook: how many collections ran and how long
+    they took (the reference's ``jvm.gc.collectors.*`` pair).  A
+    collection stops every thread, so its time is a pause some request
+    saw.  The collector calls the hook holding the interpreter lock and
+    never nests collections: plain attributes are enough."""
+
+    def __init__(self):
+        self.count = 0
+        self.nanos = 0
+        self._started = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._started = time.monotonic_ns()
+        elif self._started is not None:
+            self.nanos += time.monotonic_ns() - self._started
+            self.count += 1
+            self._started = None
+
+    def install(self) -> None:
+        """Once per process, however many nodes it starts."""
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+
+    def stats(self) -> dict:
+        return {"collection_count": self.count,
+                "collection_time_in_millis": self.nanos / 1e6}
+
+
 # -- process-wide defaults (the breaker_service() singleton pattern) -----
 #
 # Multi-node-in-one-process tests share these; spans carry a ``node``
@@ -468,6 +537,7 @@ class FlightRecorder:
 _tracer = Tracer()
 _metrics = MetricsRegistry()
 _flight_recorder = FlightRecorder()
+_gc_timer = GcTimer()
 
 
 def tracer() -> Tracer:
@@ -480,3 +550,7 @@ def metrics() -> MetricsRegistry:
 
 def flight_recorder() -> FlightRecorder:
     return _flight_recorder
+
+
+def gc_timer() -> GcTimer:
+    return _gc_timer

@@ -537,6 +537,10 @@ class Node:
                 "and no TLS: basic-auth credentials travel in cleartext "
                 "(the reference's security plugin requires TLS here)",
                 self.host)
+        # collector pauses into _nodes/stats runtime.gc, from the first
+        # request on (one hook a process, shared by its nodes)
+        from opensearch_tpu.common.telemetry import gc_timer
+        gc_timer().install()
         self.http.start()
         # overload monitor: evaluates node duress on a cadence even when
         # no new searches arrive to tick it (SearchBackpressureService's

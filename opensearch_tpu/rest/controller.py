@@ -282,8 +282,6 @@ class RestController:
                         action, f"{method} {path}",
                         headers=task_headers)
                     token = taskmod.set_current(task)
-                    # root span: honors an incoming W3C traceparent so
-                    # client-initiated traces continue through the node
                     attrs = {"http.method": method, "http.path": path,
                              "action": action,
                              "node": getattr(self.node, "node_id",
@@ -308,10 +306,15 @@ class RestController:
                     from opensearch_tpu.search import insights
                     searchish = action in ("indices:data/read/search",
                                            "indices:data/read/msearch")
+                    # under the HTTP front end a child of http.request,
+                    # which has honoured an incoming W3C traceparent;
+                    # called directly, the root that honours it
+                    parent = (None if tracer().current() is not None
+                              else tracer().extract(headers))
                     try:
                         with admission, tracer().start_span(
                                 f"rest:{action}", attributes=attrs,
-                                parent=tracer().extract(headers)) as span, \
+                                parent=parent) as span, \
                                 metrics().time_ms("rest.request_ms"), \
                                 insights.collecting() as sink:
                             metrics().counter("rest.requests").inc()
@@ -339,6 +342,11 @@ class RestController:
                         return status, resp
                     finally:
                         taskmod.reset_current(token)
+                        if searchish:
+                            # what the request cost the host: the thread
+                            # CPU time the task metered, cumulative
+                            metrics().counter("search.cpu_micros").inc(
+                                task.cpu_time_nanos // 1000)
                         self.node.task_manager.unregister(task)
             # method-mismatch vs not-found distinction
             if any(r.rx.match(path.rstrip("/") or "/") for r in self.routes):
@@ -724,7 +732,7 @@ class RestController:
 
     def h_nodes_stats(self, req):
         from opensearch_tpu.common.breakers import breaker_service
-        from opensearch_tpu.common.telemetry import metrics
+        from opensearch_tpu.common.telemetry import gc_timer, metrics
         from opensearch_tpu.indices.request_cache import request_cache
         # probe on read: stats reflect CURRENT disk health, not boot-time
         self.node.fs_health.check()
@@ -806,6 +814,9 @@ class RestController:
                 "replication": self._replication_stats(),
                 "os": _os_stats(),
                 "process": _process_stats(),
+                # the interpreter's collector: collections run and the
+                # time they stopped every thread for (cumulative)
+                "runtime": {"gc": gc_timer().stats()},
                 # counters + latency histograms with p50/p90/p99 readout
                 # (the telemetry SPI's MetricsRegistry surface)
                 "telemetry": metrics().stats(),

@@ -11,8 +11,12 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 from urllib.parse import parse_qsl, urlsplit
+
+from opensearch_tpu.common.telemetry import (TRACEPARENT, SpanContext,
+                                             metrics, tracer)
 
 
 def _cat_table(rows: list[dict], want_header: bool,
@@ -40,7 +44,32 @@ class _Handler(BaseHTTPRequestHandler):
     def log_message(self, fmt, *args):  # quiet
         pass
 
+    def setup(self):
+        # the thread hand-off: from accept's return on the serving thread
+        # to this handler thread running
+        accepted = self.server.accepted_ns.pop(self.client_address, None)
+        self._accept_wait_ns = (None if accepted is None
+                                else time.monotonic_ns() - accepted)
+        super().setup()
+
     def _handle(self):
+        attrs = {"http.method": self.command}
+        wait_ns, self._accept_wait_ns = self._accept_wait_ns, None
+        if wait_ns is not None:
+            # the connection's first request only: the time between the
+            # requests of a kept-alive connection is the client's
+            attrs["accept_wait_ns"] = wait_ns
+            metrics().histogram("rest.accept_wait_ms").observe(
+                wait_ns / 1e6)
+        with tracer().start_span(
+                "http.request", attrs,
+                parent=SpanContext.from_traceparent(
+                    self.headers.get(TRACEPARENT))) as span:
+            span.set_attribute("http.status", self._respond())
+
+    def _respond(self) -> int:
+        """Body read, dispatch, serialisation and socket write; returns
+        the status sent."""
         from opensearch_tpu.common.breakers import (CircuitBreakingError,
                                                     breaker_service)
 
@@ -126,6 +155,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
         if self.command != "HEAD":
             self.wfile.write(data)
+        return status
 
     do_GET = do_POST = do_PUT = do_DELETE = do_HEAD = _handle
 
@@ -137,6 +167,20 @@ class _Server(ThreadingHTTPServer):
     # refuses/resets, which clients see as transport errors rather than
     # an honest 429 with Retry-After.  The OS clamps to somaxconn.
     request_queue_size = 1024
+
+    def __init__(self, *args, **kwargs):
+        # client address -> time.monotonic_ns() when accept returned
+        self.accepted_ns: dict = {}
+        super().__init__(*args, **kwargs)
+
+    def process_request(self, request, client_address):
+        # on the serving thread, before the handler thread is started
+        self.accepted_ns[client_address] = time.monotonic_ns()
+        try:
+            super().process_request(request, client_address)
+        except BaseException:        # no handler thread will take it
+            self.accepted_ns.pop(client_address, None)
+            raise
 
 
 class HttpServer:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import ipaddress
 import math
 import re
+import time
 from dataclasses import dataclass
 
 import numpy as np
@@ -787,9 +788,12 @@ def _c_knn(q, ctx, scored):
     """
     import jax.numpy as jnp
 
+    from opensearch_tpu.common.device_ledger import device_ledger
+    from opensearch_tpu.common.telemetry import tracer
     from opensearch_tpu.ops.ivf import IvfPqIndex, ivf_search, ivfpq_search_l2
     from opensearch_tpu.ops.knn import knn_topk_auto
 
+    ledger = device_ledger()
     ft = ctx.field_type(q.field)
     if ft is None:
         return _none()
@@ -837,6 +841,7 @@ def _c_knn(q, ctx, scored):
             _s, fmask = P.run_full(fplan, dims, A, ins,
                                    jnp.asarray(np.float32(-np.inf)))  # staging-ok: per-query input
             valid = valid & fmask
+            ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
         kk = min(q.k, dseg.n_pad)
         ann = (seg.ann_index(q.field, method)
                if use_ann and filter_state is None else None)
@@ -862,13 +867,20 @@ def _c_knn(q, ctx, scored):
             vals, idx = knn_topk_auto(vcol["values"], valid, qvec_j,
                                       space=space, k=kk)
         pending.append((seg_order, vals, idx))
+        ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
     # phase 2: one host sync for all segments' top-k
     candidates = []          # (score, seg_order, local)
-    for seg_order, vals, idx in pending:
-        vals, idx = np.asarray(vals), np.asarray(idx)
-        keep = (vals > -np.inf) & (idx >= 0)
-        for v, i in zip(vals[keep], idx[keep]):
-            candidates.append((float(v), seg_order, int(i)))
+    if pending:
+        t_sync = time.monotonic()
+        fetched_bytes = 0
+        with tracer().start_span("device.sync", {"site": "knn_prepass"}):
+            for seg_order, vals, idx in pending:
+                vals, idx = np.asarray(vals), np.asarray(idx)
+                fetched_bytes += vals.nbytes + idx.nbytes
+                keep = (vals > -np.inf) & (idx >= 0)
+                for v, i in zip(vals[keep], idx[keep]):
+                    candidates.append((float(v), seg_order, int(i)))
+        ledger.record_fetch(fetched_bytes, time.monotonic() - t_sync)
     candidates.sort(key=lambda t: (-t[0], t[1], t[2]))
     winners: dict[int, list[tuple[int, float]]] = {}
     for score, seg_order, local in candidates[: q.k]:

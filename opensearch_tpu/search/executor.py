@@ -12,6 +12,7 @@ Lucene's tie-break (score desc, then index order = (segment, local doc)).
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import time
@@ -278,7 +279,14 @@ class ShardSearcher:
         old one.  A repeated query shape therefore does zero
         parse/compile work (`search.plan_cache.hits`).  ``prof`` (a
         QueryProfiler) times the cache lookup / parse / compile and
-        records the hit-vs-miss attribution."""
+        records the hit-vs-miss attribution.  The ``query.plan`` span
+        covers all of it: for ``knn`` and ``percolate`` compiling runs
+        the whole pre-pass, device programs and host sync included."""
+        with _tracer().start_span("query.plan"):
+            return self._compiled(query_json, scored, with_key, prof,
+                                  iattrs)
+
+    def _compiled(self, query_json, scored, with_key, prof, iattrs):
         from opensearch_tpu.common.cache import attached_cache
 
         t_lookup = time.monotonic() if prof is not None else 0.0
@@ -346,12 +354,29 @@ class ShardSearcher:
         walk(value)
         return total
 
-    def _prepared(self, plan, bind, seg, dseg, ckey, prof=None):
+    def _segment_inputs(self, plan, bind, seg, needed, ckey, prof):
+        """(dseg, dims, ins, A): everything one segment's program takes,
+        under a ``segment.prepare`` span — the host work and the H2D
+        before a launch."""
+        with _tracer().start_span("segment.prepare",
+                                  {"prepared": "miss"}) as span:
+            dseg = seg.device()
+            # prepare FIRST: dims tells build_arrays which array groups
+            # the lowering left deliberately partial (quantized segments)
+            dims, ins = self._prepared(plan, bind, seg, dseg, ckey,
+                                       prof=prof, span=span)
+            A = build_arrays(dseg, needed, self.mapper,
+                             live=self.ctx.live_jnp(seg, dseg),
+                             partial_ok=plan.skip_arrays(dims))
+        return dseg, dims, ins, A
+
+    def _prepared(self, plan, bind, seg, dseg, ckey, prof=None, span=None):
         """``plan.prepare``'s per-(plan, segment) static products —
         padded term ids, staged impact references, device scalars —
         cached so a repeated query shape does zero host-side prepare
         work (and zero H2D transfers) per segment.  ``prof`` records
-        prepare time and the per-segment prepared-bindings hit/miss."""
+        prepare time and the per-segment prepared-bindings hit/miss;
+        a hit is also written on ``span`` (its ``prepared`` attribute)."""
         if ckey is None:
             if prof is None:
                 return plan.prepare(bind, seg, dseg, self.ctx)
@@ -374,8 +399,11 @@ class ShardSearcher:
             else:
                 out = plan.prepare(bind, seg, dseg, self.ctx)
             cache.put(key, out)
-        elif prof is not None:
-            prof.inc("prepared_hits")
+        else:
+            if span is not None:
+                span.set_attribute("prepared", "hit")
+            if prof is not None:
+                prof.inc("prepared_hits")
         return out
 
     # -- public API -------------------------------------------------------
@@ -855,15 +883,8 @@ class ShardSearcher:
                     {"segment": seg.seg_id, "index": self.index_name,
                      "shard": self.shard_id}):
                 try:
-                    dseg = seg.device()
-                    # prepare FIRST: dims tells build_arrays which
-                    # array groups the lowering left deliberately
-                    # partial (quantized segments)
-                    dims, ins = self._prepared(plan, bind, seg, dseg,
-                                               ckey, prof=prof)
-                    A = build_arrays(dseg, needed, self.mapper,
-                                     live=self.ctx.live_jnp(seg, dseg),
-                                     partial_ok=plan.skip_arrays(dims))
+                    dseg, dims, ins, A = self._segment_inputs(
+                        plan, bind, seg, needed, ckey, prof)
                     scores, matched = P.run_full(plan, dims, A, ins, ms)
                 except Exception as exc:
                     if not is_device_error(exc):
@@ -1052,16 +1073,8 @@ class ShardSearcher:
                         f"[{type(plan).__name__}] has no host fallback")
                 else:
                     try:
-                        dseg = seg.device()
-                        # prepare FIRST so dims can mark the quantized
-                        # lowering's deliberately-partial array groups
-                        dims, ins = self._prepared(plan, bind, seg,
-                                                   dseg, ckey, prof=prof)
-                        A = build_arrays(dseg, needed, self.mapper,
-                                         live=self.ctx.live_jnp(seg,
-                                                                dseg),
-                                         partial_ok=plan.skip_arrays(
-                                             dims))
+                        dseg, dims, ins, A = self._segment_inputs(
+                            plan, bind, seg, needed, ckey, prof)
                         k = min(k_want, dseg.n_pad)
                         launched.append([si, *P.run_topk(plan, dims, k,
                                                          A, ins, ms),
@@ -1108,51 +1121,57 @@ class ShardSearcher:
         total = 0
         max_score = -np.inf
         fetched_bytes = 0
-        for si, vals, idx, tot, mx, synced in launched:
-            if synced is None:                 # device result: D2H fetch
-                seg = self.segments[si]
-                try:
-                    vals = np.asarray(vals)
-                    idx = np.asarray(idx)
-                    bad = check_finite(vals)
-                except Exception as exc:       # fault surfaced at sync
-                    if not is_device_error(exc):
-                        raise
-                    health.record_failure("dispatch", exc)
-                    if not host_capable:
-                        raise DeviceDegradedError(
-                            "device failure syncing segment "
-                            f"[{seg.seg_id}]: "
-                            f"{type(exc).__name__}: {exc}") from exc
-                    bad = -1                   # recompute below
-                if bad:
-                    if bad > 0:
-                        health.record_poison(
-                            kernel="run_topk", segment=seg.seg_id,
-                            index=self.index_name, shard=self.shard_id,
-                            bad=bad)
+        # the span only where a device result is read back: the host
+        # paths above left theirs in ``synced``
+        with (_tracer().start_span("device.sync", {"site": "topk"})
+              if any(entry[5] is None for entry in launched)
+              else contextlib.nullcontext()):
+            for si, vals, idx, tot, mx, synced in launched:
+                if synced is None:                 # device result: D2H fetch
+                    seg = self.segments[si]
+                    try:
+                        vals = np.asarray(vals)
+                        idx = np.asarray(idx)
+                        bad = check_finite(vals)
+                    except Exception as exc:       # fault surfaced at sync
+                        if not is_device_error(exc):
+                            raise
+                        health.record_failure("dispatch", exc)
                         if not host_capable:
                             raise DeviceDegradedError(
-                                "non-finite device scores on segment "
-                                f"[{seg.seg_id}] and the plan has no "
-                                "host fallback")
-                    _ledger().record_host_fallback()
-                    vals, idx, tot, mx = plan.host_topk(  # engine-ok: poison-recompute backend
-                        bind, seg, self.ctx.lives[id(seg)],
-                        min(k_want, seg.n_docs), min_score)
-                    vals = np.asarray(vals)
-                    idx = np.asarray(idx)
+                                "device failure syncing segment "
+                                f"[{seg.seg_id}]: "
+                                f"{type(exc).__name__}: {exc}") from exc
+                        bad = -1                   # recompute below
+                    if bad:
+                        if bad > 0:
+                            health.record_poison(
+                                kernel="run_topk", segment=seg.seg_id,
+                                index=self.index_name, shard=self.shard_id,
+                                bad=bad)
+                            if not host_capable:
+                                raise DeviceDegradedError(
+                                    "non-finite device scores on segment "
+                                    f"[{seg.seg_id}] and the plan has no "
+                                    "host fallback")
+                        _ledger().record_host_fallback()
+                        vals, idx, tot, mx = plan.host_topk(  # engine-ok: poison-recompute backend
+                            bind, seg, self.ctx.lives[id(seg)],
+                            min(k_want, seg.n_docs), min_score)
+                        vals = np.asarray(vals)
+                        idx = np.asarray(idx)
+                    else:
+                        health.record_success("dispatch")
+                        fetched_bytes += vals.nbytes + idx.nbytes + 16
                 else:
-                    health.record_success("dispatch")
-                    fetched_bytes += vals.nbytes + idx.nbytes + 16
-            else:
-                vals = synced
-                idx = np.asarray(idx)
-            keep = vals > -np.inf
-            per_seg.append((vals[keep], np.full(int(keep.sum()), si, _I32),
-                            idx[keep]))
-            total += int(tot)
-            max_score = max(max_score, float(mx))
+                    vals = synced
+                    idx = np.asarray(idx)
+                keep = vals > -np.inf
+                per_seg.append((vals[keep],
+                                np.full(int(keep.sum()), si, _I32),
+                                idx[keep]))
+                total += int(tot)
+                max_score = max(max_score, float(mx))
         if fetched_bytes:
             _ledger().record_fetch(fetched_bytes,
                                    time.monotonic() - t_sync)

@@ -251,6 +251,21 @@ def test_compile_registry_counts_query_kernels():
     assert counts["unavailable"] == 0
 
 
+def test_compile_registry_lists_the_knn_kernels():
+    """Both configurations' kernels: ops/knn.py's jit entries beside the
+    plan's."""
+    from opensearch_tpu.ops import knn
+
+    vectors = np.arange(32, dtype=np.float32).reshape(8, 4)
+    before = kernel_registry().counts()["kernels"]
+    assert {"knn.knn_scores", "knn.knn_topk",
+            "knn.knn_topk_batch"} <= set(before)
+    knn.knn_topk(vectors, np.ones(8, bool), vectors[3], space="l2", k=5)
+    after = kernel_registry().counts()
+    assert after["kernels"]["knn.knn_topk"] == before["knn.knn_topk"] + 1
+    assert after["unavailable"] == 0
+
+
 def test_compile_registry_unavailable_fallback():
     reg = KernelCompileRegistry()
     reg._defaults_loaded = True             # isolate from the real kernels

@@ -235,10 +235,15 @@ def test_client_surfaces_retry_after_header(tmp_path):
                         return
 
         threads = [threading.Thread(target=swarm) for _ in range(8)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
+        # the one permit is held while the swarm runs: whether two of
+        # its searches overlap inside the gate is the scheduler's to
+        # say, and a request that runs through in one interpreter-lock
+        # slice never meets another
+        with node.search_backpressure.admission.acquire("search"):
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
         assert saw is not None, "no 429 under max_concurrent=1 swarm"
         assert saw.retry_after is not None and saw.retry_after >= 1.0
         assert "Retry-After" in saw.headers

@@ -81,6 +81,105 @@ def test_span_buffer_is_bounded():
     assert spans[0]["name"] == "s49"       # newest first
 
 
+def test_default_ring_holds_8192_spans_newest_first():
+    t = Tracer()
+    for i in range(8200):
+        t.begin_span(f"s{i}").end()
+    spans = t.recent(limit=10000)
+    assert len(spans) == 8192
+    assert spans[0]["name"] == "s8199" and spans[-1]["name"] == "s8"
+    assert [s["name"] for s in t.recent(limit=2)] == ["s8199", "s8198"]
+    assert len(tracer().recent(limit=0)) == 0
+    assert tracer()._finished.maxlen == 8192
+
+
+def test_span_clocks_and_ids():
+    """One reading of each clock at the start: the monotonic one in
+    nanoseconds (what durations and ordering come from), the wall one in
+    milliseconds with its fraction kept; ids at the W3C widths."""
+    t = Tracer()
+    mono0, wall0 = time.monotonic_ns(), time.time() * 1e3  # wall-clock
+    with t.start_span("outer") as outer:
+        with t.start_span("inner"):
+            time.sleep(0.002)
+    mono1, wall1 = time.monotonic_ns(), time.time() * 1e3  # wall-clock
+    outer_d, inner = t.recent()          # newest (last ended) first
+    for d in (inner, outer_d):
+        assert isinstance(d["start_time_in_nanos"], int)
+        assert mono0 <= d["start_time_in_nanos"] <= mono1
+        assert isinstance(d["start_time_in_millis"], float)
+        assert wall0 - 1 <= d["start_time_in_millis"] <= wall1 + 1
+        assert isinstance(d["duration_in_nanos"], int)
+        assert len(d["trace_id"]) == 32 and len(d["span_id"]) == 16
+        int(d["trace_id"], 16), int(d["span_id"], 16)
+    assert inner["duration_in_nanos"] >= 2_000_000
+    assert outer_d["start_time_in_nanos"] <= inner["start_time_in_nanos"]
+    assert (inner["start_time_in_nanos"] + inner["duration_in_nanos"]
+            <= outer_d["start_time_in_nanos"] + outer_d["duration_in_nanos"])
+    assert SpanContext.from_traceparent(
+        outer.context().to_traceparent()).span_id == outer.span_id
+    # read-out builds the dict: a reader's edits do not reach the ring
+    inner["attributes"]["x"] = 1
+    assert "x" not in t.recent()[1]["attributes"]
+
+
+def test_a_span_may_end_on_another_thread():
+    import threading
+    t = Tracer()
+    span = t.begin_span("handed.over", {"a": 1})
+    assert t.current() is None           # begin_span makes nothing current
+    worker = threading.Thread(target=span.end)
+    worker.start()
+    worker.join()
+    span.end()                           # idempotent
+    assert [s["name"] for s in t.recent()] == ["handed.over"]
+
+
+def test_spans_end_on_many_threads_while_the_ring_is_read():
+    """Ending a span takes no lock (a deque's append is thread-safe) and a
+    read-out copies the ring while spans keep ending: no span is lost and
+    no read fails."""
+    import threading
+    t = Tracer(max_spans=100_000)
+    workers, each = 12, 800       # more threads than cores
+    stop = threading.Event()
+    reads = []
+
+    def end_spans(w):
+        for i in range(each):
+            with t.start_span(f"w{w}", {"i": i}):
+                pass
+
+    def read_ring():
+        while not stop.is_set():
+            reads.append(len(t.recent(limit=100_000)))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read_ring)
+        reader.start()
+        threads = [threading.Thread(target=end_spans, args=(w,))
+                   for w in range(workers)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=60)
+        stop.set()
+        reader.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not reader.is_alive() and not any(th.is_alive() for th in threads)
+    spans = t.recent(limit=100_000)
+    assert len(spans) == workers * each
+    assert reads and reads == sorted(reads)       # the ring only grew
+    per_worker = {}
+    for s in spans:
+        per_worker.setdefault(s["name"], []).append(s["attributes"]["i"])
+    assert all(sorted(v) == list(range(each)) for v in per_worker.values())
+    assert len({s["span_id"] for s in spans}) == len(spans)
+
+
 def test_span_records_errors():
     t = Tracer()
     with pytest.raises(ValueError):
