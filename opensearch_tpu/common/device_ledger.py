@@ -247,6 +247,7 @@ class DeviceResidencyLedger:
         self.evictions = 0
         self.restages = 0
         self.host_fallbacks = 0
+        self.slice_gather_programs = 0
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
@@ -369,14 +370,18 @@ class DeviceResidencyLedger:
 
     # -- dispatch + fetch-back accounting ----------------------------------
 
-    def record_dispatch(self, group: Optional[_Group]) -> None:
+    def record_dispatch(self, group: Optional[_Group], *,
+                        slice_gather: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
-        signal budget eviction orders by."""
-        if group is None:
-            return
+        signal budget eviction orders by.  ``slice_gather``: the
+        program's static shape took ``gather_postings``'s contiguous-
+        slice lowering (``ops/bm25.py::slice_lowering``, asked by the
+        caller as the kernel asks it)."""
         with self._lock:
-            group.dispatches += 1
-            group.last_dispatch_tick = next(self._tick)
+            self.slice_gather_programs += bool(slice_gather)
+            if group is not None:
+                group.dispatches += 1
+                group.last_dispatch_tick = next(self._tick)
 
     def record_fetch(self, nbytes: int, seconds: float) -> None:
         """Device→host result readback (the sync regions of the query
@@ -486,6 +491,7 @@ class DeviceResidencyLedger:
             budget = self.budget_bytes
             ev, evb = self.evictions, self._evicted_bytes
             rs, hf = self.restages, self.host_fallbacks
+            slice_gathers = self.slice_gather_programs
         per_index: dict[str, dict] = {}
         resident = 0
         dispatches = 0
@@ -502,6 +508,7 @@ class DeviceResidencyLedger:
             "resident_bytes": resident,
             "resident_segments": len(groups),
             "dispatches": dispatches,
+            "slice_gather_programs": slice_gathers,
             "budget": {
                 "budget_bytes": budget or 0,
                 "evictions": ev,
@@ -575,6 +582,7 @@ class DeviceResidencyLedger:
             self._groups.clear()
             self.budget_bytes = None
             self.evictions = self.restages = self.host_fallbacks = 0
+            self.slice_gather_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
                 t["bytes"] = t["ops"] = 0

@@ -8,6 +8,15 @@ data-parallel program over the whole segment:
     CSR gather of the query terms' postings  ->  BM25 per posting
     ->  scatter-add into a dense per-doc score vector  ->  lax.top_k
 
+The gather (``gather_postings``) lays each term's postings run, one
+contiguous stretch of the staged columns, into a flat ``budget``-sized
+space: as contiguous slices up to the threshold of ``slice_lowering``,
+as an element gather beyond it.  The TPU gathers element by element at
+30-50 ns a lane, which was three quarters of a term-bag program's time;
+a slice copy streams, but costs a window of ``budget`` lanes per term
+slot, so an expansion of hundreds of terms over a small bucket keeps the
+element gather.
+
 This is the BM25S formulation (see PAPERS.md): the tf-side factor
 ``tf / (tf + k1*(1-b + b*dl/avgdl))`` depends only on segment data plus
 the shard-level ``avgdl``, so it is eagerly precomputed ONCE per
@@ -80,13 +89,39 @@ def compute_impacts(tfs, doc_ids, doc_lens, avgdl, *,
     return tfs / (tfs + norm)
 
 
+# Which lowering ``gather_postings`` takes, from its static shape alone.
+# Measured on one v5e over an 8,388,608-slot column (PR 28, PERF.md
+# section 5): a slot of the slice copy costs about 4 us to start plus
+# 0.03 ns a lane, which is 131,072 lanes' worth; a lane of the element
+# gather costs 30-50 ns, some 1,600 copied lanes.  So the copy's
+# ``t_pad`` windows of ``budget`` lanes win while
+#   t_pad * (_SLOT_START_LANES + budget) <= _ELEMENT_LANE * budget:
+# up to 32 slots at a budget of 4,096, 512 at 65,536, 1,024 from 262,144.
+_SLOT_START_LANES = 131072
+_ELEMENT_LANE = 1600
+
+
+def slice_lowering(t_pad: int, budget: int) -> bool:
+    """True where ``gather_postings`` copies each term's run as one
+    contiguous slice, False where it gathers posting by posting.  The
+    kernel and the ``device.slice_gather_programs`` counter both ask
+    here, so they cannot disagree."""
+    return t_pad * (_SLOT_START_LANES + budget) <= _ELEMENT_LANE * budget
+
+
 def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
                     budget: int, pad_doc: int):
     """Flatten the postings of up to T terms into fixed-size arrays.
 
     The CSR rows selected by ``term_ids`` are laid end-to-end into a
-    ``budget``-sized flat space via searchsorted over cumulative lengths —
-    fully on-device, shape-static.
+    ``budget``-sized flat space — fully on-device, shape-static.  A row
+    is one contiguous run ``offsets[t] : offsets[t+1]`` of the columns,
+    so up to the threshold of ``slice_lowering`` each run is copied as a
+    contiguous slice (streaming vector work, ``t_pad`` windows of
+    ``budget`` lanes); beyond it, where that many windows would cost
+    more than they save, every output lane computes its own address and
+    the columns are gathered element by element (a serial gather on the
+    TPU: 30-50 ns a lane).  Both give the same lanes.
 
     Contract: the caller must choose ``budget >= sum(df[term_ids])``
     (the executor computes this from host-side df stats and rounds up to a
@@ -94,21 +129,74 @@ def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
     dropped otherwise.
 
     Returns (docs[B], tfs[B], slot[B], valid[B]): ``slot`` is the index
-    into ``term_ids`` that produced each entry.
+    into ``term_ids`` that produced each entry; lanes past the total hold
+    ``pad_doc`` / ``0.0``.
     """
+    t_pad = term_ids.shape[0]
     starts = offsets[term_ids]
     lens = jnp.where(term_active, offsets[term_ids + 1] - starts, 0)
     cum = jnp.cumsum(lens)
-    total = cum[-1]
     i = jnp.arange(budget, dtype=jnp.int32)
+    valid = i < cum[-1]
+    if slice_lowering(t_pad, budget):
+        # searchsorted(side="right") as t_pad compares a lane: streaming
+        # work, where the binary search's table lookups are element
+        # gathers again
+        slot = jnp.sum(cum[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
+        slot = jnp.minimum(slot, t_pad - 1)
+        d, tf = _copy_runs(doc_ids, tfs, starts, lens, cum - lens,
+                           budget=budget, pad_doc=pad_doc)
+        return d, tf, slot, valid
     slot = jnp.searchsorted(cum, i, side="right").astype(jnp.int32)
-    slot = jnp.minimum(slot, term_ids.shape[0] - 1)
+    slot = jnp.minimum(slot, t_pad - 1)
     prev = jnp.where(slot > 0, cum[slot - 1], 0)
-    valid = i < total
     idx = jnp.where(valid, starts[slot] + i - prev, 0)
     d = jnp.where(valid, doc_ids[idx], pad_doc)
     tf = jnp.where(valid, tfs[idx], 0.0)
     return d, tf, slot, valid
+
+
+def _copy_runs(doc_ids, tfs, starts, lens, prevs, *, budget: int,
+               pad_doc: int):
+    """The slice lowering of ``gather_postings``: slot by slot, read one
+    ``win``-lane window of each column that holds the term's run and
+    write the run's lanes, and no others, at the term's place
+    ``prevs[t]`` in the flat space.
+
+    ``dynamic_slice`` clamps a window's start so that the window fits, so
+    a run near the column's end (or any run, when the column is shorter
+    than ``budget``) begins ``shift`` lanes into its window; the write
+    goes ``shift`` lanes earlier to undo that, into a buffer with ``win``
+    spare lanes on either side so that no write is clamped in turn.  The
+    write is read-modify-write under the run's lane mask: what lies
+    beyond a term's own run never reaches the flat space, and the order
+    of the slots does not matter."""
+    n_post = doc_ids.shape[0]
+    win = min(budget, n_post)
+    lane = jnp.arange(win, dtype=jnp.int32)
+    # a caller that broke the contract loses the lanes past ``budget``,
+    # as the element gather drops them
+    prevs = jnp.minimum(prevs, budget)
+
+    def copy_slot(t, bufs):
+        start = jnp.clip(starts[t], 0, n_post - win)
+        shift = starts[t] - start
+        keep = (lane >= shift) & (lane < shift + lens[t])
+        at = prevs[t] - shift + win
+        return tuple(
+            lax.dynamic_update_slice(
+                buf, jnp.where(keep,
+                               lax.dynamic_slice(col, (start,), (win,)),
+                               lax.dynamic_slice(buf, (at,), (win,))),
+                (at,))
+            for col, buf in zip((doc_ids, tfs), bufs))
+
+    # a loop, not unrolled: the program stays the same size at any t_pad
+    d, tf = lax.fori_loop(
+        0, starts.shape[0], copy_slot,
+        (jnp.full(budget + 2 * win, pad_doc, doc_ids.dtype),
+         jnp.zeros(budget + 2 * win, tfs.dtype)))
+    return d[win:win + budget], tf[win:win + budget]
 
 
 def gather_postings_packed(offsets, packed, base, term_ids, term_active,

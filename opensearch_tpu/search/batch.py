@@ -439,7 +439,9 @@ class BatchGroup:
                 need_counts=prep["need_counts"])
             launches.append((seg_order, vals, idx, tot, mx))
             _device_ledger().record_dispatch(
-                getattr(dseg, "_ledger_group", None))
+                getattr(dseg, "_ledger_group", None),
+                slice_gather=bm25_ops.slice_lowering(
+                    sp["union_tids"].shape[0], sp["budget"]))
             if prof is not None:
                 prof.seg_scanned(seg.seg_id, time.monotonic() - t_seg)
         # ONE host sync region: convert whole launches after the dispatch loop

@@ -841,7 +841,8 @@ def _c_knn(q, ctx, scored):
             _s, fmask = P.run_full(fplan, dims, A, ins,
                                    jnp.asarray(np.float32(-np.inf)))  # staging-ok: per-query input
             valid = valid & fmask
-            ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
+            ledger.record_dispatch(getattr(dseg, "_ledger_group", None),
+                                   slice_gather=fplan.slice_gathers(dims))
         kk = min(q.k, dseg.n_pad)
         ann = (seg.ann_index(q.field, method)
                if use_ann and filter_state is None else None)

@@ -897,7 +897,8 @@ class ShardSearcher:
                         f"{type(exc).__name__}: {exc}") from exc
             health.record_success("dispatch")
             _ledger().record_dispatch(
-                getattr(dseg, "_ledger_group", None))
+                getattr(dseg, "_ledger_group", None),
+                slice_gather=plan.slice_gathers(dims))
             if iattrs is not None:
                 iattrs["scanned"] += 1
             if prof is not None:
@@ -1080,7 +1081,8 @@ class ShardSearcher:
                                                          A, ins, ms),
                                          None])
                         _ledger().record_dispatch(
-                            getattr(dseg, "_ledger_group", None))
+                            getattr(dseg, "_ledger_group", None),
+                            slice_gather=plan.slice_gathers(dims))
                     except Exception as exc:
                         if not is_device_error(exc):
                             raise

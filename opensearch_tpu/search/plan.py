@@ -91,6 +91,15 @@ class Plan:
         (empty): only lowerings that opt in skip anything."""
         return frozenset()
 
+    def slice_gathers(self, dims) -> bool:
+        """True when the program ``eval`` traces for these dims copies
+        postings runs as contiguous slices somewhere — decided by
+        ``bm25_ops.slice_lowering``, which ``gather_postings`` itself
+        asks, from the same ``t_pad`` and ``budget``.  The executor
+        counts such programs (``_nodes/stats`` ``device.
+        slice_gather_programs``).  Composites ask their children."""
+        return False
+
     def max_score_bound(self, bind, seg) -> float:
         """Safe UPPER bound on any single doc's score in this segment —
         the MaxScore/BMW pruning surface over the per-term block-max
@@ -320,6 +329,10 @@ class TermBagPlan(Plan):
         if len(dims) == 4:
             return frozenset({("postings", self.field)})
         return frozenset()
+
+    def slice_gathers(self, dims):
+        # 4-tuple dims = quantized lowering: the bit-packed gather
+        return len(dims) == 3 and bm25_ops.slice_lowering(dims[0], dims[1])
 
     def prefetch_quantized(self, bind, segments) -> int:
         """Prefetch oracle for the pager: rank candidate segments by
@@ -656,6 +669,9 @@ class PostingsMaskPlan(Plan):
                 (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
                  _scalar(bind["boost"], _F32)))
 
+    def slice_gathers(self, dims):
+        return bm25_ops.slice_lowering(*dims)
+
     def eval(self, A, dims, ins):
         t_pad, budget = dims
         tids, active, boost = ins
@@ -752,6 +768,7 @@ class ExpandTermsPlan(Plan):
                  _pad_np(np.ones(len(tids_list), bool), t_pad, False, bool),
                  _scalar(bind["boost"], _F32)))
 
+    slice_gathers = PostingsMaskPlan.slice_gathers
     eval = PostingsMaskPlan.eval
 
 
@@ -855,6 +872,9 @@ class ScriptScorePlan(Plan):
                                   if bind.get("min_score") is not None
                                   else -np.inf, _F32))
 
+    def slice_gathers(self, dims):
+        return self.child.slice_gathers(dims[0])
+
     def eval(self, A, dims, ins):
         (cdims,) = dims
         cins, ncols, vcols, param_vals, boost, min_score = ins
@@ -933,6 +953,10 @@ class BoolPlan(Plan):
         return cdims, (cins, _scalar(bind["boost"], _F32),
                        _scalar(bind["required"], _I32))
 
+    def slice_gathers(self, dims):
+        return any(c.slice_gathers(d)
+                   for c, d in zip(self._children(), dims))
+
     def eval(self, A, dims, ins):
         cins, boost, required = ins
         n_pad = A["live"].shape[0]
@@ -992,6 +1016,9 @@ class DisMaxPlan(Plan):
         return cdims, (cins, _scalar(bind["boost"], _F32),
                        _scalar(bind["tie_breaker"], _F32))
 
+    def slice_gathers(self, dims):
+        return any(c.slice_gathers(d) for c, d in zip(self.children, dims))
+
     def eval(self, A, dims, ins):
         cins, boost, tie = ins
         n_pad = A["live"].shape[0]
@@ -1024,6 +1051,9 @@ class ConstScorePlan(Plan):
     def prepare(self, bind, seg, dseg, ctx):
         cdims, cins = self.child.prepare(bind["child"], seg, dseg, ctx)
         return cdims, (cins, _scalar(bind["boost"], _F32))
+
+    def slice_gathers(self, dims):
+        return self.child.slice_gathers(dims)
 
     def eval(self, A, dims, ins):
         cins, boost = ins
@@ -1256,6 +1286,10 @@ class BoostingPlan(Plan):
         return cdims, (cins, _scalar(bind["boost"], _F32),
                        _scalar(bind["negative_boost"], _F32))
 
+    def slice_gathers(self, dims):
+        return (self.positive.slice_gathers(dims[0])
+                or self.negative.slice_gathers(dims[1]))
+
     def eval(self, A, dims, ins):
         cins, boost, negative_boost = ins
         scores, matched = self.positive.eval(A, dims[0], cins[0])
@@ -1296,6 +1330,9 @@ class TermsSetPlan(Plan):
                _pad_np(bind["weights"], t_pad, 0.0, _F32),
                dseg.impacts(self.field, bind["avgdl"]))  # quantize-ok: TermsSet stays on the f32 lowering
         return (t_pad, pad_bucket(budget)), ins
+
+    def slice_gathers(self, dims):
+        return bm25_ops.slice_lowering(*dims)
 
     def eval(self, A, dims, ins):
         t_pad, budget = dims
@@ -1640,6 +1677,12 @@ class FunctionScorePlan(Plan):
             _fs, fmask = spec.filter.eval(A, fdim, parts[0])
             applicable = fmask
         return value.astype(jnp.float64), applicable
+
+    def slice_gathers(self, dims):
+        cdims, fdims = dims
+        return self.child.slice_gathers(cdims) or any(
+            spec.filter is not None and spec.filter.slice_gathers(fd)
+            for spec, fd in zip(self.functions, fdims))
 
     def eval(self, A, dims, ins):
         cdims, fdims = dims

@@ -215,6 +215,25 @@ def test_device_counters_count_every_program_and_sync(
     assert after["health"]["breakers"]["dispatch"]["failures"] == 0
 
 
+@pytest.mark.parametrize("query,slices", [
+    (BM25[1]["query"], 2),
+    ({"bool": {"filter": [{"terms": {"t": [f"w{i}" for i in range(200)]}}]}},
+     0)], ids=["term_bag", "terms_filter_beyond_the_threshold"])
+def test_slice_gather_programs_counts_what_the_kernel_copied(
+        node, query, slices):
+    """Two segments, a program each: counted when its static shape took
+    ``gather_postings``'s slice lowering (256 term slots over a 4,096
+    bucket gather element by element)."""
+    before = _stats(node)["device"]
+    status, resp = call(node, "POST", "/" + TEXT + "/_search",
+                        {"query": query, "size": 3})
+    assert status == 200 and resp["hits"]["hits"], resp
+    after = _stats(node)["device"]
+    assert after["dispatches"] - before["dispatches"] == 2
+    assert (after["slice_gather_programs"]
+            - before["slice_gather_programs"]) == slices
+
+
 def test_gc_pause_time_moves_across_a_collection(node):
     before = _stats(node)["runtime"]["gc"]
     gc.collect()
