@@ -90,7 +90,7 @@ class ParentBreaker:
 class CircuitBreakerService:
     """The node's breaker registry.  Limits are plain byte counts taken
     from settings (defaults sized for a dev host; production tunes them
-    like the reference's indices.breaker.* settings)."""
+    like the reference's indices.breaker.* settings: ``set_limit``)."""
 
     GB = 1 << 30
 
@@ -107,10 +107,21 @@ class CircuitBreakerService:
             "in_flight_requests",
             int(s.get("breaker.inflight.limit", 2 * self.GB)))
 
+        self._built = {"total": self.parent.limit,
+                       "fielddata": self.fielddata.limit}
+
     def _child(self, name: str, limit: int) -> CircuitBreaker:
         b = CircuitBreaker(name, limit, self.parent)
         self.parent._children.append(b)
         return b
+
+    def set_limit(self, name: str, limit: int) -> None:
+        """Dynamic ``breaker.total.limit`` / ``breaker.fielddata.limit``
+        consumer: an operator sizes the two to the host and the chip
+        (the reference's ``indices.breaker.*.limit``).  0 puts back the
+        limit the service was built with."""
+        target = self.parent if name == "total" else self.fielddata
+        target.limit = int(limit) if limit > 0 else self._built[name]
 
     def stats(self) -> dict:
         out = {b.name: b.stats()

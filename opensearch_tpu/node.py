@@ -214,6 +214,14 @@ class Node:
         # host↔device paging seed, common/device_ledger.py)
         device_budget = Setting.byte_size_setting(
             "device.memory.budget_bytes", 0, dynamic=True)
+        # circuit-breaker limits (common/breakers.py): the fielddata
+        # breaker charges twice a segment's host footprint before it is
+        # staged, so a chip filled past a quarter needs more than the
+        # dev-host defaults; 0 = the limit the service was built with
+        breaker_fielddata = Setting.byte_size_setting(
+            "breaker.fielddata.limit", 0, dynamic=True)
+        breaker_total = Setting.byte_size_setting(
+            "breaker.total.limit", 0, dynamic=True)
         # paged quantized index (index/codec.py + the device pager):
         # page accounting granularity, and the per-segment lowering
         # policy ("auto" quantizes segments >= QUANTIZED_MIN_DOCS)
@@ -270,9 +278,9 @@ class Node:
              search_max_lag,
              max_keep_alive, default_keep_alive, allow_partial,
              req_cache_size, ins_enabled, ins_top_n, ins_window,
-             ins_coalesce, device_budget, pager_page_bytes,
-             quantized_mode, dh_enabled, dh_threshold,
-             dh_interval, batcher_enabled,
+             ins_coalesce, device_budget, breaker_fielddata,
+             breaker_total, pager_page_bytes, quantized_mode,
+             dh_enabled, dh_threshold, dh_interval, batcher_enabled,
              batcher_window, batcher_max, qos_shares,
              qos_default_share, qos_adaptive, qos_interval,
              as_enabled, as_min, as_max, as_dwell, as_cooldown,
@@ -330,6 +338,14 @@ class Node:
             lambda v: device_ledger().set_budget(int(v or 0)))
         device_ledger().set_budget(
             int(self.cluster_settings.get(device_budget) or 0))
+        from opensearch_tpu.common.breakers import breaker_service
+        for setting, name in ((breaker_fielddata, "fielddata"),
+                              (breaker_total, "total")):
+            def _apply_limit(v, name=name):
+                breaker_service().set_limit(name, int(v or 0))
+            self.cluster_settings.add_settings_update_consumer(
+                setting, _apply_limit)
+            _apply_limit(self.cluster_settings.get(setting))
         # pager page size reaches the process-global pager immediately;
         # the quantized-mode knob lands on the codec module global (the
         # DEFAULT_ALLOW_PARTIAL_RESULTS idiom) so the lowering decision

@@ -250,6 +250,18 @@ def _ledger():
     return device_ledger()
 
 
+def _plan_kind(plan) -> str:
+    """What a sub-query's plan is, for a span attribute: the label of a
+    pre-pass injected as a mask (``knn``, ``percolate``), else the plan
+    class in snake case (``term_bag``)."""
+    label = getattr(plan, "label", None)
+    if label:
+        return label
+    name = type(plan).__name__.removesuffix("Plan")
+    return "".join("_" + c.lower() if c.isupper() and i else c.lower()
+                   for i, c in enumerate(name))
+
+
 def _health():
     from opensearch_tpu.common.device_health import device_health
     return device_health()
@@ -472,9 +484,8 @@ class ShardSearcher:
                             "query": parse_query(q_json)}
         if isinstance(q_json, dict) and "hybrid" in q_json:
             from opensearch_tpu.search.query_dsl import HybridQuery
-            q = parse_query(q_json)
-            if isinstance(q, HybridQuery):
-                return self._hybrid_search(body, q, t0, fetch_extras)
+            if isinstance(parse_query(q_json), HybridQuery):
+                return self._hybrid_search(body, t0, fetch_extras)
         sort_specs = _parse_sort(body.get("sort"))
         min_score = body.get("min_score")
         source_spec = body.get("_source")
@@ -641,13 +652,16 @@ class ShardSearcher:
                             opt["_index"] = self.index_name
         return resp
 
-    def _hybrid_search(self, body: dict, q, t0,
-                       fetch_extras=None) -> dict:
-        """Hybrid query: each sub-query runs as its own device program;
-        the normalization processor (search/pipeline.py) combines the
-        per-sub-query top lists host-side.  ``_hybrid_pipeline`` in the
-        body carries the processor config (wired by the REST layer from
-        ?search_pipeline=...); absent -> min_max + arithmetic_mean."""
+    def _hybrid_search(self, body: dict, t0, fetch_extras=None) -> dict:
+        """Hybrid query: each sub-query runs the shard's own plan and
+        top-k path (``compiled`` with its plan cache, ``_topk``), one
+        after the other, under a ``hybrid.subquery`` span each; the
+        normalization processor (search/pipeline.py) combines the
+        per-sub-query top lists host-side under ``hybrid.normalize``.
+        ``_hybrid_pipeline`` in the body carries the processor config
+        (wired by the REST layer from ?search_pipeline=...); absent ->
+        min_max + arithmetic_mean.  A profiled request gets one profiler
+        a sub-query."""
         from opensearch_tpu.common.errors import ValidationError
         from opensearch_tpu.search.pipeline import NormalizationConfig
 
@@ -663,31 +677,70 @@ class ShardSearcher:
         k_want = from_ + size
         deadline = SearchDeadline(body.get("timeout"), t0)
         conf = NormalizationConfig(body.get("_hybrid_pipeline"))
+        profiled = bool(body.get("profile"))
+        if profiled:
+            from opensearch_tpu.search.profile import (QueryProfiler,
+                                                       describe_plan)
+        sections = []
         per_query_rows = []
         max_total = 0
-        for subq in q.queries:
+        ia = {"plan_cache": "hit", "pruned": 0, "scanned": 0}
+        for i, sub_json in enumerate(body["query"]["hybrid"]["queries"]):
             if deadline.expired():
                 break            # partial: combine what completed
-            plan, bind = compile_query(subq, self.ctx, scored=True)
-            rows, tot, _mx, _lb = self._topk(plan, bind, plan.arrays(),
-                                             k_want, None,
-                                             deadline=deadline)
+            prof = QueryProfiler() if profiled else None
+            sub_ia = {"pruned": 0, "scanned": 0}
+            with _tracer().start_span("hybrid.subquery", {"i": i}) as span:
+                (plan, bind), ckey = self.compiled(
+                    sub_json, scored=True, with_key=True, prof=prof,
+                    iattrs=sub_ia)
+                span.set_attribute("type", _plan_kind(plan))
+                rows, tot, _mx, _lb = self._topk(
+                    plan, bind, plan.arrays(), k_want, None,
+                    deadline=deadline, ckey=ckey, prof=prof,
+                    iattrs=sub_ia)
+            _metrics().counter("search.hybrid.subqueries").inc()
             per_query_rows.append(rows)
             max_total = max(max_total, int(tot))
-        combined = conf.apply(per_query_rows, k_want)
+            if sub_ia.get("plan_cache") != "hit":
+                ia["plan_cache"] = "miss"
+            if sub_ia.get("execution_path") == "host":
+                ia["execution_path"] = "host"
+            ia["pruned"] += sub_ia["pruned"]
+            ia["scanned"] += sub_ia["scanned"]
+            if prof is not None:
+                sections.append(prof.shard_section(
+                    self.index_name, self.shard_id,
+                    plan_type=type(plan).__name__,
+                    description=describe_plan(plan, bind),
+                    total_segments=len(self.segments)))
+        t_norm = time.monotonic()
+        with _tracer().start_span("hybrid.normalize",
+                                  {"subqueries": len(per_query_rows)}):
+            combined, n_union = conf.apply(per_query_rows, k_want)
+        _metrics().counter("search.hybrid.requests").inc()
+        _metrics().counter("search.hybrid.candidates").inc(n_union)
         rows = combined[from_: from_ + size]
-        hits = self._hits_from_rows(rows, body.get("_source"),
-                                    fetch_extras)
+        t_fetch = time.monotonic()
+        with _tracer().start_span("fetch_phase",
+                                  {"index": self.index_name,
+                                   "hits": len(rows)}), \
+                _metrics().time_ms("search.fetch_ms"):
+            hits = self._hits_from_rows(rows, body.get("_source"),
+                                        fetch_extras)
+        t_done = time.monotonic()
         insights.emit(
             signature=insights.canonical_query(body.get("query")),
             scored=True,
-            took_ms=(time.monotonic() - t0) * 1000,
-            execution_path="device", plan_cache="miss",
+            took_ms=(t_done - t0) * 1000,
+            execution_path=ia.get("execution_path", "device"),
+            plan_cache=ia["plan_cache"],
+            pruned=ia["pruned"], scanned=ia["scanned"],
             timed_out=deadline.timed_out)
         # per-sub-query top-k truncation means the union is a lower
         # bound beyond the largest sub-query's exact count
-        return {
-            "took": int((time.monotonic() - t0) * 1000),
+        resp = {
+            "took": int((t_done - t0) * 1000),
             "timed_out": deadline.timed_out,
             "_shards": shards_section(1),
             "hits": {"total": {"value": max_total, "relation": "gte"},
@@ -695,6 +748,27 @@ class ShardSearcher:
                                    else None),
                      "hits": hits},
         }
+        if profiled:
+            # one ``searches`` entry a sub-query, in request order; the
+            # shard's ``engine`` block says where the whole request ran
+            # and carries each sub-query's own attribution
+            resp["profile"] = {"shards": [{
+                "id": f"[{self.index_name}][{self.shard_id}]",
+                "searches": [s["searches"][0] for s in sections],
+                "engine": {
+                    "execution_path": ia.get("execution_path", "device"),
+                    "request_cache": "bypass",
+                    "hybrid": {
+                        "sub_queries": [s["engine"] for s in sections],
+                        "normalization": conf.normalization,
+                        "combination": conf.combination,
+                        "candidates": n_union,
+                        "normalize_time_in_nanos": int(
+                            (t_fetch - t_norm) * 1e9),
+                        "fetch_time_in_nanos": int(
+                            (t_done - t_fetch) * 1e9)}},
+            }]}
+        return resp
 
     def msearch(self, bodies: list) -> list[dict]:
         """Multi-search (the ``_msearch`` analog): bodies that compile to a

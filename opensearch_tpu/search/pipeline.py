@@ -8,8 +8,9 @@ named in SURVEY §2.1 as "the hook the neural-search hybrid normalization
 processor uses".  A pipeline is a named JSON document; the one
 phase-results processor implemented is ``normalization-processor``:
 
-- normalization: ``min_max`` (per sub-query: (s-min)/(max-min), 1.0 on
-  a degenerate range) or ``l2`` (s / ||scores||);
+- normalization: ``min_max`` (per sub-query: (s-min)/(max-min), the
+  list's lowest candidate ``MIN_SCORE`` instead of 0, 1.0 on a
+  degenerate range) or ``l2`` (s / ||scores||);
 - combination: ``arithmetic_mean`` / ``geometric_mean`` /
   ``harmonic_mean`` with optional per-sub-query ``weights``.
 
@@ -39,6 +40,10 @@ class PipelineMissingError(OpenSearchTpuError):
 
 DEFAULT_NORMALIZATION = {"technique": "min_max"}
 DEFAULT_COMBINATION = {"technique": "arithmetic_mean"}
+# the plugin's MinMaxScoreNormalizationTechnique: a candidate that
+# normalises to exactly 0 (the list's lowest) is given MIN_SCORE, so that
+# being on a sub-query's list at all ranks above being absent from it
+MIN_SCORE = 0.001
 
 
 def normalize_scores(scores: np.ndarray, technique: str) -> np.ndarray:
@@ -48,7 +53,8 @@ def normalize_scores(scores: np.ndarray, technique: str) -> np.ndarray:
         lo, hi = float(scores.min()), float(scores.max())
         if hi - lo < 1e-12:
             return np.ones_like(scores)
-        return (scores - lo) / (hi - lo)
+        norm = (scores - lo) / (hi - lo)
+        return np.where(norm == 0.0, MIN_SCORE, norm)
     if technique == "l2":
         norm = float(np.sqrt((scores * scores).sum()))
         return scores / norm if norm > 1e-12 else np.ones_like(scores)
@@ -105,9 +111,11 @@ class NormalizationConfig:
                     "combination weights must be non-negative numbers "
                     "with a positive sum")
 
-    def apply(self, per_query_rows: list[list[dict]], k: int) -> list[dict]:
+    def apply(self, per_query_rows: list[list[dict]],
+              k: int) -> tuple[list[dict], int]:
         """``per_query_rows``: one row list per sub-query (rows carry
-        seg/local/score).  Returns the combined, re-sorted row list."""
+        seg/local/score).  Returns the combined, re-sorted row list cut
+        at ``k``, and how many distinct candidates were combined."""
         nq = len(per_query_rows)
         weights = self.weights or [1.0] * nq
         if len(weights) != nq:
@@ -128,7 +136,7 @@ class NormalizationConfig:
                 "seg": seg, "local": local,
                 "score": combine_scores(per_q, weights, self.combination)})
         combined.sort(key=lambda r: (-r["score"], r["seg"], r["local"]))
-        return combined[:k]
+        return combined[:k], len(combined)
 
 
 _KNOWN_PROCESSORS = ("normalization-processor",)

@@ -54,7 +54,8 @@ def call(node, method, path, body=None):
 
 def test_normalize_and_combine_units():
     s = np.asarray([1.0, 3.0, 5.0])
-    assert normalize_scores(s, "min_max").tolist() == [0.0, 0.5, 1.0]
+    # the list's lowest candidate is floored at the plugin's MIN_SCORE
+    assert normalize_scores(s, "min_max").tolist() == [0.001, 0.5, 1.0]
     l2 = normalize_scores(s, "l2")
     assert l2 @ l2 * (s @ s) == pytest.approx((s @ s))
     assert normalize_scores(np.asarray([2.0, 2.0]),
@@ -143,3 +144,112 @@ def test_hybrid_rejects_sort_aggs_and_bad_pipeline(node):
     code, _ = call(node, "PUT", "/_search/pipeline/bad2", {
         "phase_results_processors": [{"not-a-processor": {}}]})
     assert code == 400
+
+
+@pytest.mark.parametrize("scores,want", [
+    ([7.5], [1.0]),                        # a single candidate
+    ([2.0, 2.0, 2.0], [1.0, 1.0, 1.0]),    # a degenerate range
+    ([4.0, 1.0], [1.0, 0.001]),            # only the exact zero is raised
+    ([1.0, 1.0005, 2.0], [0.001, 0.0005, 1.0]),
+])
+def test_min_max_floor_and_single_candidate(scores, want):
+    got = normalize_scores(np.asarray(scores, np.float64), "min_max")
+    assert got.tolist() == pytest.approx(want, rel=1e-9)
+
+
+def test_a_listed_candidate_outranks_an_absent_one():
+    """The floor's purpose: the last of one list (0.001 / 2) still beats
+    nothing, and the union's size comes back beside the rows."""
+    rows_a = [{"seg": 0, "local": i, "score": s}
+              for i, s in enumerate([3.0, 2.0, 1.0])]
+    rows_b = [{"seg": 0, "local": 7, "score": 9.0}]
+    combined, n_union = NormalizationConfig().apply([rows_a, rows_b], 10)
+    assert n_union == 4 and len(combined) == 4
+    by_doc = {r["local"]: r["score"] for r in combined}
+    assert by_doc[2] == pytest.approx(0.0005)
+    assert by_doc[7] == pytest.approx(0.5) and by_doc[0] == pytest.approx(0.5)
+    assert NormalizationConfig().apply([rows_a, rows_b], 2)[1] == 4
+
+
+def _hybrid_body(node, **extra):
+    return {"query": {"hybrid": {"queries": [
+        {"match": {"text": "alpha"}},
+        {"knn": {"vec": {"vector": node._test_vecs[4].tolist(), "k": 10}}},
+    ]}}, "size": 10, **extra}
+
+
+def test_profiled_hybrid_request_carries_a_shard_section(node):
+    code, plain = call(node, "POST", "/hyb/_search", _hybrid_body(node))
+    assert code == 200 and "profile" not in plain
+    code, resp = call(node, "POST", "/hyb/_search",
+                      _hybrid_body(node, profile=True))
+    assert code == 200
+    shard = resp["profile"]["shards"][0]
+    assert shard["id"] == "[hyb][0]"
+    assert shard["engine"]["execution_path"] in ("device", "host")
+    hybrid = shard["engine"]["hybrid"]
+    assert len(shard["searches"]) == len(hybrid["sub_queries"]) == 2
+    assert hybrid["normalization"] == "min_max"
+    assert hybrid["combination"] == "arithmetic_mean"
+    assert hybrid["candidates"] >= len(resp["hits"]["hits"])
+    for search, engine in zip(shard["searches"], hybrid["sub_queries"]):
+        assert search["query"][0]["breakdown"]["dispatch_count"] >= 1
+        assert engine["segments"]["scanned"] >= 1
+    assert [s["query"][0]["type"] for s in shard["searches"]] == [
+        "TermBagPlan", "ScoredMaskPlan"]
+    # profiling changes nothing a user sees
+    assert ([(h["_id"], h["_score"]) for h in resp["hits"]["hits"]]
+            == [(h["_id"], h["_score"]) for h in plain["hits"]["hits"]])
+
+
+def test_hybrid_spans_and_counters_once_a_subquery_and_once_a_request(node):
+    def counters():
+        _, stats = call(node, "GET", "/_nodes/stats")
+        c = next(iter(stats["nodes"].values()))["telemetry"]["counters"]
+        return {k: c.get(f"search.hybrid.{k}", 0)
+                for k in ("requests", "subqueries", "candidates")}
+
+    before = counters()
+    _, resp = call(node, "POST", "/hyb/_search", _hybrid_body(node))
+    after = counters()
+    assert after["requests"] - before["requests"] == 1
+    assert after["subqueries"] - before["subqueries"] == 2
+    n_union = after["candidates"] - before["candidates"]
+    assert len(resp["hits"]["hits"]) <= n_union <= 20
+
+    _, trace = call(node, "GET", "/_nodes/trace?size=200")
+    spans = next(iter(trace["nodes"].values()))["spans"]
+    phase = next(s for s in spans if s["name"] == "shard.query_phase")
+    mine = [s for s in spans if s["trace_id"] == phase["trace_id"]]
+    subs = sorted((s for s in mine if s["name"] == "hybrid.subquery"),
+                  key=lambda s: s["attributes"]["i"])
+    assert [(s["attributes"]["i"], s["attributes"]["type"])
+            for s in subs] == [(0, "term_bag"), (1, "knn")]
+    assert all(s["parent_span_id"] == phase["span_id"] for s in subs)
+    names = [s["name"] for s in mine]
+    assert names.count("hybrid.normalize") == 1
+    assert names.count("fetch_phase") == 1
+    # each sub-query planned under its own span, inside hybrid.subquery
+    plans = [s for s in mine if s["name"] == "query.plan"]
+    assert sorted(s["parent_span_id"] for s in plans) == sorted(
+        s["span_id"] for s in subs)
+
+
+def test_breaker_limits_are_dynamic_cluster_settings(node):
+    from opensearch_tpu.common.breakers import breaker_service
+
+    svc = breaker_service()
+    built = (svc.fielddata.limit, svc.parent.limit)
+    try:
+        code, _ = call(node, "PUT", "/_cluster/settings", {"transient": {
+            "breaker.fielddata.limit": "20gb",
+            "breaker.total.limit": "24gb"}})
+        assert code == 200
+        assert (svc.fielddata.limit, svc.parent.limit) == (20 << 30, 24 << 30)
+        code, _ = call(node, "PUT", "/_cluster/settings", {"transient": {
+            "breaker.fielddata.limit": None, "breaker.total.limit": None}})
+        assert code == 200
+        assert (svc.fielddata.limit, svc.parent.limit) == built
+    finally:
+        svc.set_limit("fielddata", 0)
+        svc.set_limit("total", 0)
