@@ -104,6 +104,8 @@ class KernelCompileRegistry:
     # bootstrap are the same reason profile.py resolved them lazily)
     _DEFAULTS = (
         ("plan.run_topk", "opensearch_tpu.search.plan", "run_topk"),
+        ("plan.run_topk_parts", "opensearch_tpu.search.plan",
+         "run_topk_parts"),
         ("plan.run_full", "opensearch_tpu.search.plan", "run_full"),
         ("plan.topk_from_scores", "opensearch_tpu.search.plan",
          "topk_from_scores"),
@@ -251,7 +253,7 @@ class DeviceResidencyLedger:
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
-            "fetch": {"bytes": 0, "ops": 0, "seconds": 0.0}}
+            "fetch": {"bytes": 0, "ops": 0, "arrays": 0, "seconds": 0.0}}
 
     # -- group lifecycle ---------------------------------------------------
 
@@ -383,13 +385,17 @@ class DeviceResidencyLedger:
                 group.dispatches += 1
                 group.last_dispatch_tick = next(self._tick)
 
-    def record_fetch(self, nbytes: int, seconds: float) -> None:
-        """Device→host result readback (the sync regions of the query
-        path and the mesh merge)."""
+    def record_fetch(self, nbytes: int, seconds: float, *,
+                     arrays: int = 1) -> None:
+        """Device→host result readback: one sync region of the query
+        path or the mesh merge (``ops``), in which ``arrays`` device
+        arrays were read, each a round trip of its own unless its copy
+        was started at launch."""
         with self._lock:
             t = self._transfers["fetch"]
             t["bytes"] += int(nbytes)
             t["ops"] += 1
+            t["arrays"] += int(arrays)
             t["seconds"] += seconds
         _metrics().counter("device.transfer.fetch.bytes").inc(int(nbytes))
         _metrics().counter("device.transfer.fetch.ops").inc()
@@ -485,7 +491,7 @@ class DeviceResidencyLedger:
         with self._lock:
             groups = list(self._groups.values())
             transfers = {
-                side: {"bytes": t["bytes"], "ops": t["ops"],
+                side: {**{k: v for k, v in t.items() if k != "seconds"},
                        "time_ms": round(t["seconds"] * 1000.0, 3)}
                 for side, t in self._transfers.items()}
             budget = self.budget_bytes
@@ -585,8 +591,8 @@ class DeviceResidencyLedger:
             self.slice_gather_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
-                t["bytes"] = t["ops"] = 0
-                t["seconds"] = 0.0
+                for key in t:
+                    t[key] = 0.0 if key == "seconds" else 0
         device_pager().reset()
 
 

@@ -374,8 +374,8 @@ class MeshSearcher:
                                      live=shard.ctx.live_jnp(seg, dseg))
                     dims, ins = plan.prepare(bind, seg, dseg, shard.ctx)
                     kk = min(k, dseg.n_pad)
-                    vals, idx, tot, _mx = planmod.run_topk(plan, dims, kk,
-                                                           A, ins, ms)
+                    vals, idx, tot, _mx = planmod.run_topk_parts(
+                        plan, dims, kk, A, ins, ms)
                     if kk < k:                       # pad to common k
                         pad = k - kk
                         vals = jnp.concatenate(

@@ -867,6 +867,10 @@ def _c_knn(q, ctx, scored):
         else:
             vals, idx = knn_topk_auto(vcol["values"], valid, qvec_j,
                                       space=space, k=kk)
+        # both copies queue behind the program, so phase 2's two reads
+        # a segment find their arrays on the host
+        vals.copy_to_host_async()
+        idx.copy_to_host_async()
         pending.append((seg_order, vals, idx))
         ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
     # phase 2: one host sync for all segments' top-k
@@ -881,7 +885,8 @@ def _c_knn(q, ctx, scored):
                 keep = (vals > -np.inf) & (idx >= 0)
                 for v, i in zip(vals[keep], idx[keep]):
                     candidates.append((float(v), seg_order, int(i)))
-        ledger.record_fetch(fetched_bytes, time.monotonic() - t_sync)
+        ledger.record_fetch(fetched_bytes, time.monotonic() - t_sync,
+                            arrays=2 * len(pending))
     candidates.sort(key=lambda t: (-t[0], t[1], t[2]))
     winners: dict[int, list[tuple[int, float]]] = {}
     for score, seg_order, local in candidates[: q.k]:

@@ -1074,7 +1074,9 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
-        launched = []              # [si, vals, idx, tot, mx, synced_vals]
+        # [si, out]: a host path's (vals, idx, tot, mx), or the device's
+        # packed result (P.run_topk), its copy to the host under way
+        launched = []
         kth = None                 # running k-th best (harvested, host)
         total_is_lower_bound = False
         for si, seg in enumerate(self.segments):
@@ -1138,10 +1140,9 @@ class ShardSearcher:
                 if use_host:
                     if not host_fast:
                         _ledger().record_host_fallback()
-                    vals, idx, tot, mx = plan.host_topk(  # engine-ok: host fast-path backend
+                    launched.append([si, plan.host_topk(  # engine-ok: host fast-path backend
                         bind, seg, self.ctx.lives[id(seg)],
-                        min(k_want, seg.n_docs), min_score)
-                    launched.append([si, vals, idx, tot, mx, vals])
+                        min(k_want, seg.n_docs), min_score)])
                 elif not device_ok:
                     raise DeviceDegradedError(
                         "device circuit breaker open: plan "
@@ -1151,9 +1152,11 @@ class ShardSearcher:
                         dseg, dims, ins, A = self._segment_inputs(
                             plan, bind, seg, needed, ckey, prof)
                         k = min(k_want, dseg.n_pad)
-                        launched.append([si, *P.run_topk(plan, dims, k,
-                                                         A, ins, ms),
-                                         None])
+                        packed = P.run_topk(plan, dims, k, A, ins, ms)
+                        # queued behind the program: phase 2 finds the
+                        # result on the host instead of asking for it
+                        packed.copy_to_host_async()
+                        launched.append([si, packed])
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims))
@@ -1172,10 +1175,9 @@ class ShardSearcher:
                         # host impact-table path; the breaker decides
                         # whether later segments even try the device
                         _ledger().record_host_fallback()
-                        vals, idx, tot, mx = plan.host_topk(  # engine-ok: host degrade backend
+                        launched.append([si, plan.host_topk(  # engine-ok: host degrade backend
                             bind, seg, self.ctx.lives[id(seg)],
-                            min(k_want, seg.n_docs), min_score)
-                        launched.append([si, vals, idx, tot, mx, vals])
+                            min(k_want, seg.n_docs), min_score)])
             if iattrs is not None:
                 iattrs["scanned"] += 1
             if prof is not None:
@@ -1196,18 +1198,20 @@ class ShardSearcher:
         per_seg = []
         total = 0
         max_score = -np.inf
-        fetched_bytes = 0
+        fetched_bytes = fetched_arrays = 0
         # the span only where a device result is read back: the host
-        # paths above left theirs in ``synced``
+        # paths above left numpy tuples
         with (_tracer().start_span("device.sync", {"site": "topk"})
-              if any(entry[5] is None for entry in launched)
+              if any(not isinstance(out, tuple) for _si, out in launched)
               else contextlib.nullcontext()):
-            for si, vals, idx, tot, mx, synced in launched:
-                if synced is None:                 # device result: D2H fetch
+            for si, out in launched:
+                if isinstance(out, tuple):
+                    vals, idx, tot, mx = out
+                else:                  # device result: ONE D2H read
                     seg = self.segments[si]
                     try:
-                        vals = np.asarray(vals)
-                        idx = np.asarray(idx)
+                        packed = np.asarray(out)
+                        vals, idx, tot, mx = P.unpack_topk(packed)
                         bad = check_finite(vals)
                     except Exception as exc:       # fault surfaced at sync
                         if not is_device_error(exc):
@@ -1234,23 +1238,22 @@ class ShardSearcher:
                         vals, idx, tot, mx = plan.host_topk(  # engine-ok: poison-recompute backend
                             bind, seg, self.ctx.lives[id(seg)],
                             min(k_want, seg.n_docs), min_score)
-                        vals = np.asarray(vals)
-                        idx = np.asarray(idx)
                     else:
                         health.record_success("dispatch")
-                        fetched_bytes += vals.nbytes + idx.nbytes + 16
-                else:
-                    vals = synced
-                    idx = np.asarray(idx)
+                        fetched_bytes += packed.nbytes
+                        fetched_arrays += 1
+                vals = np.asarray(vals)
+                idx = np.asarray(idx)
                 keep = vals > -np.inf
                 per_seg.append((vals[keep],
                                 np.full(int(keep.sum()), si, _I32),
                                 idx[keep]))
                 total += int(tot)
                 max_score = max(max_score, float(mx))
-        if fetched_bytes:
+        if fetched_arrays:
             _ledger().record_fetch(fetched_bytes,
-                                   time.monotonic() - t_sync)
+                                   time.monotonic() - t_sync,
+                                   arrays=fetched_arrays)
         rows, total, max_score = self._merge_topk(per_seg, k_want, total,
                                                   max_score)
         if prof is not None:
@@ -1321,11 +1324,16 @@ class ShardSearcher:
         threshold, fed at async-dispatch granularity)."""
         ready = []
         for entry in launched:
-            if entry[5] is None and getattr(entry[1], "is_ready",
-                                            lambda: False)():
-                entry[5] = np.asarray(entry[1])      # sync-ok (is_ready)
-            if entry[5] is not None:
-                ready.append(entry[5])
+            out = entry[1]
+            if isinstance(out, tuple):             # a host path's result
+                ready.append(np.asarray(out[0]))
+                continue
+            if not isinstance(out, np.ndarray):
+                if not out.is_ready():
+                    continue
+                # read once; phase 2's np.asarray of it is then free
+                out = entry[1] = np.asarray(out)   # sync-ok (is_ready)
+            ready.append(P.unpack_topk(out)[0])
         if not ready:
             return kth
         vals = np.concatenate(ready).ravel()
@@ -1340,24 +1348,32 @@ class ShardSearcher:
         if prof is not None:
             with prof.phase("reduce"):
                 return self._topk_from_views(views, k_want)
+        if k_want == 0:
+            return [], sum(int(np.asarray(matched).sum())
+                           for _seg, _dseg, _scores, matched in views), None
+        launched = []              # a packed result a view, its copy under way
+        for _seg, dseg, scores, matched in views:
+            packed = P.topk_from_scores(scores, min(k_want, dseg.n_pad),
+                                        matched)
+            packed.copy_to_host_async()
+            launched.append(packed)
         per_seg = []
         total = 0
         max_score = -np.inf
-        for si, (seg, dseg, scores, matched) in enumerate(views):
-            if k_want == 0:
-                total += int(np.asarray(matched).sum())
-                continue
-            k = min(k_want, dseg.n_pad)
-            vals, idx, tot, mx = P.topk_from_scores(scores, k, matched)
-            vals = np.asarray(vals)
-            idx = np.asarray(idx)
+        t_sync = time.monotonic()
+        fetched_bytes = 0
+        for si, packed in enumerate(launched):
+            packed = np.asarray(packed)
+            vals, idx, tot, mx = P.unpack_topk(packed)
+            fetched_bytes += packed.nbytes
             keep = vals > -np.inf
             per_seg.append((vals[keep], np.full(int(keep.sum()), si, _I32),
                             idx[keep]))
-            total += int(tot)
-            max_score = max(max_score, float(mx))
-        if k_want == 0:
-            return [], total, None
+            total += tot
+            max_score = max(max_score, mx)
+        if launched:
+            _ledger().record_fetch(fetched_bytes, time.monotonic() - t_sync,
+                                   arrays=len(launched))
         return self._merge_topk(per_seg, k_want, total, max_score)
 
     def _sort_key_columns(self, seg, spec, scores_np):

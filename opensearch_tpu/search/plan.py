@@ -1799,26 +1799,63 @@ def _edit_distance_le(a: str, b: str, k: int) -> bool:
 # ---------------------------------------------------------------------------
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def run_topk(plan: Plan, dims, k: int, A, ins, min_score):
-    """(top_scores[k], top_local_ids[k], total_matched, max_score).
-    top_k's lower-index tie-break == Lucene's ascending-doc-id tie-break.
-    ``min_score`` (-inf when unset) excludes docs from hits AND total,
-    matching MinimumScoreCollector semantics."""
-    scores, matched = plan.eval(A, dims, ins)
-    matched = matched & A["live"] & (scores >= min_score)
-    key = jnp.where(matched, scores, -jnp.inf)
+def _key_topk(key, k: int, matched):
+    """(top_scores[k], top_local_ids[k], total_matched, max_score) of a
+    key that is -inf where nothing matched.  top_k's lower-index
+    tie-break == Lucene's ascending-doc-id tie-break."""
     vals, idx = lax.top_k(key, k)
     return vals, idx, matched.sum(), jnp.max(key)
+
+
+def _pack_topk(vals, idx, tot, mx):
+    """The four results as ONE ``int32[2k + 2]``, so that a segment
+    program's answer crosses to the host in one read: the scores' bits,
+    the local ids, the matched count (at most ``n_pad`` < 2**31; int64
+    only because x64 is on) and the max's bits.  A bit cast moves every
+    float32 bit for bit (-inf, NaN payloads); ``unpack_topk`` splits."""
+    return jnp.concatenate([
+        lax.bitcast_convert_type(vals, jnp.int32), idx.astype(jnp.int32),
+        tot.astype(jnp.int32)[None],
+        lax.bitcast_convert_type(mx, jnp.int32)[None]])
+
+
+def unpack_topk(packed: np.ndarray):
+    """Host side of ``_pack_topk``: (scores f32[k], local_ids i32[k],
+    total_matched int, max_score float) as views of the one array."""
+    k = (len(packed) - 2) // 2
+    return (packed[:k].view(np.float32), packed[k:2 * k],
+            int(packed[2 * k]), float(packed[2 * k + 1:].view(np.float32)[0]))
+
+
+def _run_topk(plan: Plan, dims, k: int, A, ins, min_score):
+    scores, matched = plan.eval(A, dims, ins)
+    matched = matched & A["live"] & (scores >= min_score)
+    return _key_topk(jnp.where(matched, scores, -jnp.inf), k, matched)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def run_topk(plan: Plan, dims, k: int, A, ins, min_score):
+    """One segment's top-k, packed (``unpack_topk`` on the host gives
+    (top_scores[k], top_local_ids[k], total_matched, max_score)).
+    ``min_score`` (-inf when unset) excludes docs from hits AND total,
+    matching MinimumScoreCollector semantics."""
+    return _pack_topk(*_run_topk(plan, dims, k, A, ins, min_score))
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def run_topk_parts(plan: Plan, dims, k: int, A, ins, min_score):
+    """``run_topk``'s four results as four device arrays, for a caller
+    that goes on with them on the device (the mesh's shard-local merge)."""
+    return _run_topk(plan, dims, k, A, ins, min_score)
 
 
 @partial(jax.jit, static_argnums=(1,))
 def topk_from_scores(scores, k: int, matched):
-    """Top-k over an already-computed (scores, matched) pair — used when a
-    full-scores pass already ran for aggregations."""
-    key = jnp.where(matched, scores, -jnp.inf)
-    vals, idx = lax.top_k(key, k)
-    return vals, idx, matched.sum(), jnp.max(key)
+    """Packed top-k (as ``run_topk``) over an already-computed (scores,
+    matched) pair — used when a full-scores pass already ran for
+    aggregations."""
+    return _pack_topk(*_key_topk(
+        jnp.where(matched, scores, -jnp.inf), k, matched))
 
 
 @partial(jax.jit, static_argnums=(0, 1))

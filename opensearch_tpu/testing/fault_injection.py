@@ -687,17 +687,20 @@ class DeviceFaultInjector:
         led.stage = stage
         led.device_put = device_put
 
-        def wrap_kernel(mod, attr):
+        def wrap_kernel(mod, attr, name=None):
             real = getattr(mod, attr)
+            name = name or attr
 
             def kernel(*args, **kwargs):
-                inj._check("dispatch", (attr,))
+                inj._check("dispatch", (name,))
                 out = real(*args, **kwargs)
-                return inj._maybe_poison(attr, out)
+                return inj._maybe_poison(name, out)
             self._saved.append((mod, attr, real))
             setattr(mod, attr, kernel)
 
         wrap_kernel(plan_mod, "run_topk")
+        # the mesh's four-array form of the same kernel: same rules
+        wrap_kernel(plan_mod, "run_topk_parts", name="run_topk")
         wrap_kernel(plan_mod, "run_full")
         wrap_kernel(batch_mod, "batch_impact_union_topk")
 
@@ -756,12 +759,16 @@ class DeviceFaultInjector:
 
     def _maybe_poison(self, kernel: str, out):
         """NaN-poison the score component of a top-k kernel result (the
-        first array of the tuple) — the silent-corruption failure shape
-        the result-sanity guard exists to catch."""
+        first array of the tuple; the first ``k`` lanes of ``run_topk``'s
+        packed ``int32[2k + 2]``, as bits) — the silent-corruption
+        failure shape the result-sanity guard exists to catch."""
         rule = self._match("poison", (kernel,))
         if rule is None:
             return out
         import jax.numpy as jnp
+        if not isinstance(out, tuple):
+            k = (out.shape[0] - 2) // 2
+            return out.at[:k].set(0x7FC00000)      # float32 NaN's bits
         vals = out[0]
         return (jnp.full_like(vals, jnp.nan), *out[1:])
 
