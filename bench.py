@@ -21,9 +21,7 @@ reported on its own phase line and the remaining phases still run, but
 the exit code is then non-zero.
 
 Env knobs: OSTPU_BENCH_DOCS (default 100000), OSTPU_BENCH_QUERIES (200),
-OSTPU_BENCH_BATCH (64), OSTPU_BENCH_PHASES (phases file path),
-OSTPU_BENCH_SCALE_DOCS (default 1000000; the quantized paged-index
-phase), OSTPU_BENCH_SCALE_10M=1 (the 10M-doc point).
+OSTPU_BENCH_BATCH (64), OSTPU_BENCH_PHASES (phases file path).
 """
 
 from __future__ import annotations
@@ -362,12 +360,6 @@ def main():
     # insights: always-on attribution overhead + workload coalescability
     later_phase("insights", run_insights_phase, searcher, queries, seq_n,
                 platform, batch)
-    # device: residency ledger, transfer split, forced budget eviction
-    later_phase("device", run_device_phase, searcher, queries, seq_n,
-                platform)
-    # device_faults: breaker trip -> degraded qps -> probe recovery
-    later_phase("device_faults", run_devfaults_phase, searcher, queries,
-                seq_n, platform, gate="DEVFAULTS")
     # tier: search-only replica fleet over the remote store
     later_phase("tier", run_tier_phase, platform, gate="TIER")
     # qos: noisy-neighbor tenant isolation + adaptive control
@@ -380,9 +372,6 @@ def main():
     # pressure, drain-safe retirement when idle
     later_phase("autoscale", run_autoscale_phase, platform,
                 gate="AUTOSCALE")
-    # scale: 1M-doc quantized paged index — footprint vs qps vs rank
-    # parity under a halved device budget, + open-loop sweep
-    later_phase("scale", run_scale_phase, platform, gate="SCALE")
     # soak: chaos SLO scenario over a 3-node cluster (runs LAST so a
     # failure here cannot cost the phases above)
     later_phase("soak", run_soak_phase, platform, gate="SOAK")
@@ -614,145 +603,6 @@ def run_insights_phase(searcher, queries, seq_n: int,
         "top_signatures": coalesc["top_signatures"][:3],
         "slowest_signature": top[0]["signature"] if top else None,
     })
-
-
-def run_device_phase(searcher, queries, seq_n: int, platform: str):
-    """Device-memory budget line (ROADMAP item 5): how many bytes the
-    query path keeps device-resident, what the host↔device transfer
-    traffic looks like split stage vs fetch-back, and what happens when
-    a ``device.memory.budget_bytes`` smaller than the footprint forces
-    LRU-dispatch eviction — footprint vs qps measured, not asserted.
-    Runs the DEVICE kernels even on the CPU backend (host fast-path off
-    for the phase) so the staged footprint and eviction machinery are
-    exercised everywhere the bench runs.  Returns the reported dict."""
-    from opensearch_tpu.common.device_ledger import device_ledger
-    from opensearch_tpu.ops import bm25 as bm25_ops
-
-    led = device_ledger()
-    prev_budget = led.budget_bytes
-    prev_host = bm25_ops.HOST_SCORING
-    bm25_ops.HOST_SCORING = False
-    try:
-        sample = queries[: min(seq_n, 50)]
-        for q in sample:                       # stage + warm
-            searcher.search(q)
-        stats0 = led.stats()
-        resident = stats0["resident_bytes"]
-        t0 = time.monotonic()
-        for q in sample:
-            searcher.search(q)
-        unconstrained_s = time.monotonic() - t0
-        # force the budget below the footprint: the LRU-dispatch segment
-        # unstages and scored term-bags degrade to the host tables
-        led.set_budget(max(1, resident // 2))
-        t0 = time.monotonic()
-        for q in sample:
-            searcher.search(q)
-        constrained_s = time.monotonic() - t0
-        stats1 = led.stats()
-        data = {
-            "platform": platform,
-            "n_queries": len(sample),
-            "resident_bytes": resident,
-            "resident_segments": stats0["resident_segments"],
-            "budget_bytes": stats1["budget"]["budget_bytes"],
-            "evictions": stats1["budget"]["evictions"],
-            "evicted_bytes": stats1["budget"]["evicted_bytes"],
-            "restages": stats1["budget"]["restages"],
-            "host_fallbacks": stats1["budget"]["host_fallbacks"],
-            "transfer_stage_bytes": stats1["transfers"]["stage"]["bytes"],
-            "transfer_stage_ops": stats1["transfers"]["stage"]["ops"],
-            "transfer_fetch_bytes": stats1["transfers"]["fetch"]["bytes"],
-            "transfer_fetch_ops": stats1["transfers"]["fetch"]["ops"],
-            "qps_unconstrained": round(
-                len(sample) / unconstrained_s, 1) if unconstrained_s
-            else 0.0,
-            "qps_budget_constrained": round(
-                len(sample) / constrained_s, 1) if constrained_s
-            else 0.0,
-            "xla_kernels": stats1["compile_registry"]["kernels"],
-            "compile_unavailable":
-                stats1["compile_registry"]["unavailable"],
-        }
-        phase_report("device", data)
-        return data
-    finally:
-        bm25_ops.HOST_SCORING = prev_host
-        led.set_budget(prev_budget)
-
-
-def run_devfaults_phase(searcher, queries, seq_n: int, platform: str):
-    """Accelerator fault-tolerance line: the same zipf sample runs (a)
-    healthy on the device kernels, (b) under a sticky injected dispatch
-    fault — the per-kernel circuit breaker trips and scored term-bags
-    degrade byte-identically to the host impact tables — and (c) after
-    the heal, where half-open probes re-close the breaker.  The line
-    records qps-under-trip, the degradation latency delta, and the
-    probe-recovery count, so 'what does a sick accelerator cost' is
-    measured, not asserted."""
-    from opensearch_tpu.common.device_health import device_health
-    from opensearch_tpu.common.telemetry import metrics
-    from opensearch_tpu.ops import bm25 as bm25_ops
-    from opensearch_tpu.testing.fault_injection import \
-        DeviceFaultInjector
-
-    dh = device_health()
-    prev_dh = (dh.enabled, dh.failure_threshold, dh.open_interval_s)
-    prev_host = bm25_ops.HOST_SCORING
-    bm25_ops.HOST_SCORING = False
-    dh.reset()
-    dh.set_failure_threshold(2)
-    dh.set_open_interval_s(0.0)
-    try:
-        sample = queries[: min(seq_n, 50)]
-        for q in sample:                    # stage + warm the kernels
-            searcher.search(q)
-        t0 = time.monotonic()
-        for q in sample:
-            searcher.search(q)
-        healthy_s = time.monotonic() - t0
-
-        trips0 = metrics().counter("device.breaker.trips").value
-        inj = DeviceFaultInjector(seed=1234)
-        inj.dispatch_error()                # sticky: every dispatch dies
-        with inj:
-            t0 = time.monotonic()
-            for q in sample:
-                searcher.search(q)
-            tripped_s = time.monotonic() - t0
-        trips = metrics().counter("device.breaker.trips").value - trips0
-
-        closes0 = metrics().counter("device.breaker.closes").value
-        t0 = time.monotonic()
-        for q in sample:                    # healed: probes re-close
-            searcher.search(q)
-        healed_s = time.monotonic() - t0
-        recoveries = metrics().counter(
-            "device.breaker.closes").value - closes0
-
-        n = len(sample)
-        data = {
-            "platform": platform,
-            "n_queries": n,
-            "qps_healthy": round(n / healthy_s, 1) if healthy_s else 0.0,
-            "qps_under_trip": round(n / tripped_s, 1) if tripped_s
-            else 0.0,
-            "qps_healed": round(n / healed_s, 1) if healed_s else 0.0,
-            "degradation_delta_ms": round(
-                (tripped_s - healthy_s) / n * 1000.0, 3) if n else 0.0,
-            "breaker_trips": int(trips),
-            "probe_recoveries": int(recoveries),
-            "breaker_states": device_health().breaker_states(),
-            "host_fallbacks": int(metrics().counter(
-                "device.host_fallback").value),
-            "poisoned_results": dh.stats()["poisoned_results"],
-        }
-        phase_report("device_faults", data)
-        return data
-    finally:
-        bm25_ops.HOST_SCORING = prev_host
-        dh.reset()
-        dh.enabled, dh.failure_threshold, dh.open_interval_s = prev_dh
 
 
 def run_tier_phase(platform: str):
@@ -1047,211 +897,6 @@ def run_autoscale_phase(platform: str):
         "unexpected_errors": len(chaos["unexpected_errors"]),
     })
     return report
-
-
-def _scale_load_point(searcher, queries, rate_qps: float,
-                      duration_s: float) -> list:
-    """One open-loop offered-load point against an in-process searcher:
-    every request fires at its scheduled Poisson arrival and latency is
-    charged from that SCHEDULED instant (absolute, fixed before the
-    dispatch loop), so queue delay under overload counts against the
-    request that suffered it — no coordinated omission."""
-    import threading
-
-    from opensearch_tpu.testing.loadgen import arrival_schedule
-
-    sched = arrival_schedule(rate_qps, duration_s, seed=42)
-    lats, lock, threads = [], threading.Lock(), []
-    base = time.monotonic() + 0.01
-
-    def fire(scheduled_abs, q):
-        delay = scheduled_abs - time.monotonic()
-        if delay > 0:
-            time.sleep(delay)
-        searcher.search(dict(q))
-        with lock:
-            lats.append(time.monotonic() - scheduled_abs)
-
-    for i, off in enumerate(sched):
-        th = threading.Thread(
-            target=fire, args=(base + off, queries[i % len(queries)]),
-            daemon=True)
-        th.start()
-        threads.append(th)
-    for th in threads:
-        th.join(timeout=duration_s + 60)
-    return lats
-
-
-def run_scale_phase(platform: str):
-    """Quantized paged device index at the 1M-doc scale (ROADMAP item
-    2): footprint vs qps vs rank parity for the int8 + bit-packed
-    lowering (index/codec.py), measured under two device budgets — one
-    that fits the quantized tables but NOT the f32 tables, and one at
-    HALF the quantized footprint so the pager demonstrably pages
-    (misses/evictions/prefetches all nonzero).  The latency story is an
-    open-loop offered-qps sweep (``arrival_schedule``; latency charged
-    from the SCHEDULED arrival, so it is coordinated-omission-free like
-    the latency_under_load phase, pointed at this corpus instead of the
-    node-scale one).  ``OSTPU_BENCH_SCALE_DOCS`` sizes the corpus
-    (default 1M); ``OSTPU_BENCH_SCALE_10M=1`` runs the 10M point."""
-    import threading
-
-    from opensearch_tpu.common.device_ledger import (device_ledger,
-                                                     device_pager)
-    from opensearch_tpu.index import codec
-    from opensearch_tpu.mapping.mapper import DocumentMapper
-    from opensearch_tpu.ops import bm25 as bm25_ops
-    from opensearch_tpu.search.executor import ShardSearcher
-    from opensearch_tpu.testing.loadgen import arrival_schedule
-
-    n_docs = int(os.environ.get("OSTPU_BENCH_SCALE_DOCS", 1_000_000))
-    if os.environ.get("OSTPU_BENCH_SCALE_10M") == "1":
-        n_docs = 10_000_000
-    n_segments = int(os.environ.get("OSTPU_BENCH_SCALE_SEGMENTS", 8))
-    n_q = int(os.environ.get("OSTPU_BENCH_SCALE_QUERIES", 40))
-
-    t0 = time.monotonic()
-    raw = build_raw_corpus(n_docs, seed=42)
-    segs = make_segments(raw, n_segments)
-    mapper = DocumentMapper({"properties": {"body": {"type": "text"}}})
-    searcher = ShardSearcher(segs, mapper, index_name="bench_scale")
-    pairs = gen_query_terms(n_q, seed=11)
-    queries = [{"query": {"match": {"body": f"t{a} t{b}"}}, "size": 10}
-               for a, b in pairs]
-    build_s = time.monotonic() - t0
-    log(f"scale corpus: {n_docs} docs, {len(raw['doc_ids'])} postings, "
-        f"{n_segments} segments, {build_s:.1f}s")
-
-    led = device_ledger()
-    pager = device_pager()
-    # earlier phases (device, device_faults) leave residency and
-    # counters behind; the budget geometry below must describe THIS
-    # corpus only, so start from a forgotten ledger (their searchers
-    # are dead objects by now — nothing re-dispatches those groups)
-    led.reset()
-    prev_budget = led.budget_bytes
-    prev_host = bm25_ops.HOST_SCORING
-    prev_mode = codec.QUANTIZED_MODE
-    try:
-        # f32 reference ranking: host lowering with quantization off —
-        # computed from the f32 impact tables, no device staging at all
-        # (the host path never constructs a DeviceSegment), so the f32
-        # tables never have to fit on the device to get the reference
-        codec.QUANTIZED_MODE = "off"
-        bm25_ops.HOST_SCORING = True
-        ref = [[h["_id"] for h in
-                searcher.search(dict(q))["hits"]["hits"]]
-               for q in queries]
-
-        # the production "auto" policy quantizes segments at/above
-        # QUANTIZED_MIN_DOCS; force "on" only when an env-shrunk corpus
-        # drops below it (so small smoke runs still exercise the path)
-        codec.QUANTIZED_MODE = ("auto" if n_docs // n_segments
-                                >= codec.QUANTIZED_MIN_DOCS else "on")
-        avgdl = searcher.ctx.field_stats("body").avgdl
-        t0 = time.monotonic()
-        agg = {k: 0 for k in ("f32_bytes", "quant_bytes", "terms",
-                              "postings", "exact_terms",
-                              "exact_postings")}
-        width = 0
-        for seg in segs:
-            qt = seg.quantized_table("body", avgdl)
-            for k in agg:
-                agg[k] += int(qt.stats[k])
-            width = max(width, int(qt.width))
-        quantize_s = time.monotonic() - t0
-
-        bm25_ops.HOST_SCORING = False
-        for q in queries:                      # compile + stage warm
-            searcher.search(dict(q))
-        p0 = pager.stats()
-        quant_resident = int(p0["resident_bytes"])
-        total_resident = int(led.stats()["resident_bytes"])
-
-        # budget point A: exactly the quantized working set — fits the
-        # int8 tables but NOT the f32 tables (the acceptance geometry)
-        budget_fit = max(1, total_resident)
-        led.set_budget(budget_fit)
-        t0 = time.monotonic()
-        got = [[h["_id"] for h in
-                searcher.search(dict(q))["hits"]["hits"]]
-               for q in queries]
-        fit_s = time.monotonic() - t0
-        p_fit = pager.stats()
-        parity = sum(1 for a, b in zip(got, ref) if a == b)
-
-        # budget point B: half the quantized footprint — the pager must
-        # page (LRU-evict + demand-restage) to serve the same queries
-        led.set_budget(max(1, total_resident // 2))
-        t0 = time.monotonic()
-        got_half = [[h["_id"] for h in
-                     searcher.search(dict(q))["hits"]["hits"]]
-                    for q in queries]
-        half_s = time.monotonic() - t0
-        p_half = pager.stats()
-        led_half = led.stats()
-        parity_half = sum(1 for a, b in zip(got_half, ref) if a == b)
-
-        # open-loop offered-qps sweep at budget point A: every request
-        # fires at its scheduled Poisson arrival and latency is charged
-        # from that SCHEDULED instant (no coordinated omission)
-        led.set_budget(budget_fit)
-        points = [float(x) for x in os.environ.get(
-            "OSTPU_BENCH_SCALE_LOAD_QPS", "4,10,25").split(",")]
-        duration_s = float(os.environ.get(
-            "OSTPU_BENCH_SCALE_LOAD_DURATION", 4.0))
-        load = []
-        for rate in points:
-            lats = _scale_load_point(searcher, queries, rate, duration_s)
-            ms = np.asarray(lats, dtype=np.float64) * 1e3
-            load.append({
-                "offered_qps": rate, "n": len(lats),
-                "p50_ms": round(float(np.percentile(ms, 50)), 2)
-                if len(ms) else None,
-                "p99_ms": round(float(np.percentile(ms, 99)), 2)
-                if len(ms) else None,
-            })
-
-        data = {
-            "platform": platform, "n_docs": n_docs,
-            "n_segments": n_segments, "n_queries": n_q,
-            "build_s": round(build_s, 1),
-            "quantize_s": round(quantize_s, 1),
-            "dtype": codec.QUANTIZED_DTYPE, "pack_width_bits": width,
-            "f32_bytes": agg["f32_bytes"],
-            "quant_bytes": agg["quant_bytes"],
-            "compression_ratio": round(
-                agg["f32_bytes"] / agg["quant_bytes"], 2)
-            if agg["quant_bytes"] else None,
-            "quant_resident_bytes": quant_resident,
-            "device_resident_bytes": total_resident,
-            "exact_terms": agg["exact_terms"],
-            "exact_postings": agg["exact_postings"],
-            "terms": agg["terms"], "postings": agg["postings"],
-            "budget_fit_bytes": budget_fit,
-            "budget_fit_lt_f32": budget_fit < agg["f32_bytes"],
-            "qps_budget_fit": round(n_q / fit_s, 1) if fit_s else 0.0,
-            "rank_parity_fraction": round(parity / n_q, 3),
-            "budget_half_bytes": max(1, total_resident // 2),
-            "qps_budget_half": round(n_q / half_s, 1) if half_s
-            else 0.0,
-            "rank_parity_fraction_half": round(parity_half / n_q, 3),
-            "pager_prefetches": p_half["prefetches"],
-            "pager_hits": p_half["hits"],
-            "pager_misses": p_half["misses"],
-            "pager_evictions": p_half["evictions"],
-            "pager_misses_at_fit": p_fit["misses"],
-            "pager_resident_pages": p_half["resident_pages"],
-            "host_fallbacks": led_half["budget"]["host_fallbacks"],
-            "open_loop": load,
-        }
-        phase_report("scale", data)
-        return data
-    finally:
-        bm25_ops.HOST_SCORING = prev_host
-        codec.QUANTIZED_MODE = prev_mode
-        led.set_budget(prev_budget)
 
 
 def final_line(*, qps, baseline_qps, platform, extra=None):

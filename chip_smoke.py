@@ -22,8 +22,9 @@ smoke_mesh     smoke_f32's docs over 4 shards with                     65,536
 
 Checks: smoke_f32 top-10 against an independent numpy BM25; ``_msearch``
 members against their sequential answers; smoke_quant device answers
-against the host path over the same ``.quant`` tables, again at half the
-device budget with the pager missing; every agg bucket against numpy;
+against the host scorer's over the same ``.quant`` tables (asked for
+through an open ``dispatch`` breaker, the product's own degradation
+route), again at half the device budget with the pager missing; every agg bucket against numpy;
 kNN recall@10 = 1.0 against a float32 numpy scan; the compiled Pallas
 kNN kernel against numpy at [262144, 128]; and, read from the client's
 side of ``GET /_nodes/stats``, proof that the DEVICE did the work — the
@@ -231,6 +232,7 @@ class Smoke:
         self.sizes = sizes
         self.platform = platform
         self.loaded: dict = {}
+        self.asked_fallbacks = 0     # host answers ``degraded`` asked for
 
     # .. REST helpers ..
 
@@ -263,8 +265,9 @@ class Smoke:
         if dev["health"]["poisoned_results"]:
             bad.append(f"poisoned_results="
                        f"{dev['health']['poisoned_results']}")
-        if dev["budget"]["host_fallbacks"]:
-            bad.append(f"host_fallbacks={dev['budget']['host_fallbacks']}")
+        if dev["budget"]["host_fallbacks"] != self.asked_fallbacks:
+            bad.append(f"host_fallbacks={dev['budget']['host_fallbacks']}"
+                       f" (asked for: {self.asked_fallbacks})")
         if dev["backend"].get("platform") != self.platform:
             bad.append(f"backend={dev['backend']}")
         for line in bad:
@@ -332,6 +335,46 @@ class Smoke:
             out.append(hit_rows(resp))
         return out
 
+    def degraded(self, index: str, corpus: TextCorpus,
+                 queries: list) -> list:
+        """The same requests through the product's own degradation
+        route: with the ``dispatch`` breaker open every segment is
+        recovered by the host impact-table scorer.  Each response must
+        say so, and the ledger must count the fallbacks; the breaker's
+        books are put back, and ``assert_device_clean`` expects the
+        fallbacks asked for here from now on."""
+        from opensearch_tpu.common.device_health import device_health
+
+        health = device_health()
+        saved = (health.enabled, health.failure_threshold,
+                 health.open_interval_s)
+        before = self.device_stats()["device"]["budget"]["host_fallbacks"]
+        health.set_failure_threshold(1)
+        health.set_open_interval_s(3600.0)
+        health.record_failure("dispatch", RuntimeError(
+            "chip_smoke: breaker opened for the host ranking"))
+        try:
+            out = []
+            for terms in queries:
+                resp = self.search(index,
+                                   {**corpus.body(terms), "profile": True})
+                engine = resp["profile"]["shards"][0]["engine"]
+                require(engine["execution_path"] == "host",
+                        f"{index}: under an open breaker a request ran "
+                        f"on [{engine['execution_path']}]: {engine}")
+                out.append(hit_rows(resp))
+        finally:
+            health.reset()
+            health.enabled, health.failure_threshold, \
+                health.open_interval_s = saved
+        moved = (self.device_stats()["device"]["budget"]["host_fallbacks"]
+                 - before)
+        require(moved >= len(queries),
+                f"{index}: {len(queries)} degraded requests counted "
+                f"{moved} host fallbacks")
+        self.asked_fallbacks += moved
+        return out
+
     def msearch(self, index: str, corpus: TextCorpus, queries: list) -> list:
         lines = []
         for terms in queries:
@@ -371,8 +414,6 @@ class Smoke:
     def lexical_quant(self, corpus: TextCorpus, queries: list) -> tuple:
         """Device answers against the host path's; returns the result and
         the host rows, which the half-budget step compares with again."""
-        from opensearch_tpu.ops import bm25 as bm25_ops
-
         s = self.sizes
         seq_q = queries[:s.seq_queries]
         device = self.sequential("smoke_quant", corpus, seq_q)
@@ -382,15 +423,10 @@ class Smoke:
                 and pager["hits"] + pager["misses"] > 0,
                 f"smoke_quant did not take the quantized lowering: "
                 f"pager={pager}")
-        # the same requests on the host path over the same .quant tables
-        # (the idiom of tests/test_quantized.py)
-        prev = bm25_ops.HOST_SCORING
-        bm25_ops.HOST_SCORING = True
-        try:
-            host = [hit_rows(self.search("smoke_quant", corpus.body(t)))
-                    for t in seq_q]
-        finally:
-            bm25_ops.HOST_SCORING = prev
+        # the same requests recovered on the host over the same .quant
+        # tables
+        host = self.degraded("smoke_quant", corpus, seq_q)
+        self.assert_device_clean("smoke_quant degraded")
         self.require_parity(device, host, "smoke_quant device vs host")
         f32_share = np.mean([
             [i for i, _ in device[qi]] == [str(d) for d in np.argsort(
@@ -683,7 +719,9 @@ def run_smoke(sizes: Sizes, seed: int, platform: str,
             results["device"] = {
                 "backend": dev["backend"], "dispatches": dev["dispatches"],
                 "programs": kernels,
-                "host_fallbacks": dev["budget"]["host_fallbacks"],
+                "host_fallbacks": (dev["budget"]["host_fallbacks"]
+                                   - smoke.asked_fallbacks),
+                "asked_fallbacks": smoke.asked_fallbacks,
                 "breakers": {k: {"failures": b["failures"],
                                  "trips": b["trips"]}
                              for k, b in dev["health"]["breakers"].items()},

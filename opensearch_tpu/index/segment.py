@@ -594,7 +594,7 @@ class DeviceSegment:
         exactly like ``postings[field]["tfs"]`` (padded slots are 0).
 
         Staged from the HOST impact table (``Segment.impact_table``) so
-        the device scoring path and the CPU-backend host fast path read
+        the device scoring path and its host recovery (``host_topk``) read
         bit-identical impacts, and cached per (field, avgdl).  avgdl is
         the only query-time input: a refresh/merge that changes it does
         so through the reader-generation bump (new searcher, new

@@ -709,7 +709,7 @@ class IndexService:
 
     def _execute_search(self, body: dict, agg_partials: bool) -> dict:
         # ONE engine entry for every backend: the mesh router, the
-        # continuous batcher, the host fast path and the device kernels
+        # continuous batcher, the device kernels and their host recovery
         # are decisions inside QueryEngine.execute, not separately-wired
         # code paths here (search/engine.py; tools/check_execution_paths
         # keeps new paths from bypassing it)

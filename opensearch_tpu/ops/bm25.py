@@ -46,26 +46,9 @@ from jax import lax
 K1_DEFAULT = 1.2
 B_DEFAULT = 0.75
 
-# Backend-specialized lowering for the scored term-bag hot path.
-# XLA:CPU lowers scatter-add to a scalar loop (~50ns/update measured on
-# avx512 hosts whose tuning carries prefer-no-scatter), which makes the
-# per-posting score accumulation 10-25x slower than the same placement
-# as a vectorized host fancy-index add.  On the CPU backend the term-bag
-# top-k therefore runs host-side over the SAME precomputed impact table
-# (Segment.impact_table — bit-identical to the staged device column);
-# accelerator backends keep the XLA kernels.  None = decide from the
-# active backend; tests force True/False to exercise either path.
-HOST_SCORING = None
-_HOST_AUTO = None
-
-
-def host_scoring_enabled() -> bool:
-    if HOST_SCORING is not None:
-        return bool(HOST_SCORING)
-    global _HOST_AUTO
-    if _HOST_AUTO is None:
-        _HOST_AUTO = jax.default_backend() == "cpu"
-    return _HOST_AUTO
+# Nothing reads this.  tests/benchmarks_harness/conftest.py (a benchmark
+# file) still sets it; it goes with that line (ROADMAP D12).
+HOST_SCORING = False
 
 
 def idf(df: int, n_docs: int) -> float:

@@ -69,7 +69,7 @@ def prepare_match_query(segments: list, field: str, terms: list[str]):
     leftover): instead of staging raw tfs + doc_lens and recomputing the
     BM25 norm per query on every device, each shard stages its
     PRECOMPUTED per-posting impact column (``Segment.impact_table`` at
-    the GLOBAL avgdl — bit-identical to what the host fast path and the
+    the GLOBAL avgdl — bit-identical to what the host scorer and the
     device kernels read), so the mesh query degenerates to the same
     gather + idf-weighted scatter the unified engine lowers everywhere
     else.  Byte-parity with the host path is pinned in

@@ -118,9 +118,11 @@ class BatchGroup:
         self.idfs: list[np.ndarray] = []
         self.weights: list[np.ndarray] = []
         self.required: list[int] = []
-        # group-level scanned/pruned counts of the last run() — shared
-        # by every member's insight record (one pass served the group)
-        self.last_stats = {"pruned": 0, "scanned": 0}
+        # group-level scanned/pruned counts of the last run() and the
+        # backend that served it — shared by every member's insight
+        # record (one pass served the group)
+        self.last_stats = {"pruned": 0, "scanned": 0,
+                           "path": "device_batched"}
 
     def add(self, pos: int, bind: dict):
         self.positions.append(pos)
@@ -242,17 +244,20 @@ class BatchGroup:
                 "required": self.required[qi], "avgdl": self.avgdl}
 
     def _run_host(self, searcher, prof=None) -> dict:
-        """CPU-backend batch execution: every query scores host-side
-        via ``TermBagPlan.host_topk`` over the shared per-segment impact
-        tables — byte-identical to the sequential path by construction
-        (same function, same accumulation order).  See ops/bm25.py
-        ``host_scoring_enabled`` for why XLA:CPU scatter loses to the
-        host here."""
+        """The group's recovery backend, never its first choice: ``run``
+        comes here when the ``batch`` or ``staging`` breaker is open,
+        when the device program raised a device error, and when its
+        result was not finite.  Every query scores host-side via
+        ``TermBagPlan.host_topk`` over the shared per-segment impact
+        tables — byte-identical to the device group and to the
+        sequential path by construction (same accumulation order).
+        Counted as one host fallback a group."""
         import time
 
         from opensearch_tpu.common.tasks import check_current
         from opensearch_tpu.search.plan import TermBagPlan
 
+        _device_ledger().record_host_fallback()
         if prof is not None:
             prof.set("execution_path", "host_batched")
         plan = TermBagPlan(field=self.field, scored=True)
@@ -260,77 +265,40 @@ class BatchGroup:
                for pos in self.positions}
         pruned = 0
         scanned = 0
-        if prof is not None:
-            # profiled groups keep the serial segment-outer loop so the
-            # per-segment dispatch attribution includes scoring time
-            for seg_order, seg in enumerate(searcher.segments):
-                check_current()    # cancellation point per segment
-                t_seg = time.monotonic()
-                pf = seg.postings.get(self.field)
-                if pf is None:
-                    continue
-                if not any(pf.term_id(t) >= 0
-                           for terms in self.terms for t in terms):
-                    pruned += 1
+        for seg_order, seg in enumerate(searcher.segments):
+            check_current()    # cancellation point per segment
+            t_seg = time.monotonic() if prof is not None else 0.0
+            pf = seg.postings.get(self.field)
+            if pf is None:
+                continue
+            if not any(pf.term_id(t) >= 0
+                       for terms in self.terms for t in terms):
+                pruned += 1    # no query term here: skip scoring
+                if prof is not None:
                     prof.seg_pruned(seg.seg_id, "pruned_can_match",
                                     time.monotonic() - t_seg)
-                    continue
-                live = searcher.ctx.lives[id(seg)]
-                for qi, pos in enumerate(self.positions):
-                    vals, idx, tot, mx = plan.host_topk(  # engine-ok: batch host backend
-                        self._bind(qi), seg, live,
-                        min(self.k, seg.n_docs), None)
-                    a = acc[pos]
-                    a["v"].append(vals)
-                    a["s"].append(np.full(len(vals), seg_order, _I32))
-                    a["l"].append(idx)
-                    a["tot"] += int(tot)
-                    a["mx"] = max(a["mx"], float(mx))
-                scanned += 1
+                continue
+            live = searcher.ctx.lives[id(seg)]
+            for qi, pos in enumerate(self.positions):
+                vals, idx, tot, mx = plan.host_topk(  # engine-ok: the batch recovery backend
+                    self._bind(qi), seg, live,
+                    min(self.k, seg.n_docs), None)
+                a = acc[pos]
+                a["v"].append(vals)
+                a["s"].append(np.full(len(vals), seg_order, _I32))
+                a["l"].append(idx)
+                a["tot"] += int(tot)
+                a["mx"] = max(a["mx"], float(mx))
+            scanned += 1
+            if prof is not None:
+                # the per-segment dispatch attribution includes scoring
                 prof.seg_scanned(seg.seg_id, time.monotonic() - t_seg)
-        else:
-            surviving = []         # (seg_order, seg, live)
-            for seg_order, seg in enumerate(searcher.segments):
-                check_current()    # cancellation point per segment
-                pf = seg.postings.get(self.field)
-                if pf is None:
-                    continue
-                if not any(pf.term_id(t) >= 0
-                           for terms in self.terms for t in terms):
-                    pruned += 1    # no query term here: skip scoring
-                    continue
-                surviving.append((seg_order, seg,
-                                  searcher.ctx.lives[id(seg)]))
-                scanned += 1
-
-            def score_member(qi):
-                bindq = self._bind(qi)
-                a = acc[self.positions[qi]]
-                for seg_order, seg, live in surviving:
-                    vals, idx, tot, mx = plan.host_topk(  # engine-ok: batch host backend
-                        bindq, seg, live, min(self.k, seg.n_docs), None)
-                    a["v"].append(vals)
-                    a["s"].append(np.full(len(vals), seg_order, _I32))
-                    a["l"].append(idx)
-                    a["tot"] += int(tot)
-                    a["mx"] = max(a["mx"], float(mx))
-
-            if len(self.positions) > 1 and surviving:
-                # members are independent: fan the per-member scoring
-                # loop across the engine threadpool (the batched-group
-                # analog of the executor's multi-segment host fan-out)
-                from opensearch_tpu.search.engine import query_engine
-                query_engine().pool.run_all(
-                    [(lambda qi=qi: score_member(qi))
-                     for qi in range(len(self.positions))])
-            else:
-                for qi in range(len(self.positions)):
-                    score_member(qi)
         if pruned:
             _metrics().counter("search.segments_pruned").inc(pruned)
         # group-level attribution the msearch member insight records
         # carry (shared by construction — ONE pass served the group)
-        self.last_stats = {"pruned": pruned, "scanned": scanned}
+        self.last_stats = {"pruned": pruned, "scanned": scanned,
+                           "path": "host_batched"}
         t_red = time.monotonic() if prof is not None else 0.0
         out = {}
         for pos in self.positions:
@@ -354,21 +322,18 @@ class BatchGroup:
         """Execute against every segment; returns {pos: (rows, total,
         max_score)} in the sequential path's row format.
 
-        On the CPU backend the whole batch scores host-side
-        (``_run_host``).  Otherwise: device handles per segment LAUNCH;
-        host-synced once at the end (4 D2H transfers per segment, not 4
-        per query per segment).  ``prof`` is the shared GROUP profiler
-        (see ShardSearcher.msearch)."""
+        Device handles per segment LAUNCH; host-synced once at the end
+        (4 D2H transfers per segment, not 4 per query per segment).
+        ``_run_host`` is the recovery from an open breaker, a device
+        error or a poisoned result.  ``prof`` is the shared GROUP
+        profiler (see ShardSearcher.msearch)."""
         from opensearch_tpu.common.device_health import (device_health,
                                                          is_device_error)
 
         health = device_health()
-        if bm25_ops.host_scoring_enabled():
-            return self._run_host(searcher, prof=prof)
         if not (health.allow("batch") and health.allow("staging")):
             # open device breaker: the whole group scores on the host
             # impact tables — byte-identical (the PR-5 invariant)
-            _device_ledger().record_host_fallback()
             return self._run_host(searcher, prof=prof)
         try:
             return self._run_device(searcher, health, prof=prof)
@@ -379,7 +344,6 @@ class BatchGroup:
             # identical host path serves the group instead of failing
             # the whole msearch/continuous batch
             health.record_failure("batch", exc)
-            _device_ledger().record_host_fallback()
             return self._run_host(searcher, prof=prof)
 
     def _run_device(self, searcher, health, prof=None) -> dict:
@@ -415,7 +379,7 @@ class BatchGroup:
                     prof.seg_pruned(seg.seg_id, "pruned_can_match", 0.0)
         self.last_stats = {
             "pruned": len(searcher.segments) - len(prep["segs"]),
-            "scanned": len(prep["segs"])}
+            "scanned": len(prep["segs"]), "path": "device_batched"}
         launches = []             # (seg_order, vals[Q,k], idx, tot, mx)
         for seg_order, sp in prep["segs"]:
             check_current()    # cancellation point per segment program
@@ -468,7 +432,6 @@ class BatchGroup:
                     kernel="batch_impact_union_topk",
                     segment=seg.seg_id, index=searcher.index_name,
                     shard=searcher.shard_id, bad=bad)
-                _device_ledger().record_host_fallback()
                 return self._run_host(searcher, prof=prof)
         health.record_success("batch")
         out = {}

@@ -1,10 +1,9 @@
 """One query engine: the single scoring entry every caller routes through.
 
-Before this module, four execution paths coexisted and were wired
-separately at each call site: the sequential per-query path
-(``ShardSearcher.search``), the msearch-batched kernel
-(``search/batch.py``), the CPU host fast path (``ops/bm25.py
-HOST_SCORING``) and the 8-device mesh (``parallel/dist_search.py``).
+Before this module, the execution paths were wired separately at each
+call site: the sequential per-query path (``ShardSearcher.search``), the
+msearch-batched kernel (``search/batch.py``) and the 8-device mesh
+(``parallel/dist_search.py``).
 Only clients that happened to speak ``_msearch`` reached the batched
 kernel; independent REST requests each paid their own XLA dispatch even
 when the insights coalescability report said most zipf-head arrivals
@@ -15,7 +14,11 @@ the cluster data-node query phase, and the mesh router all call it) and
 the kernels are backend decisions inside the one lowering pipeline
 (parse -> plan cache -> prepare -> kernel choice); the tier-1 lint
 ``tools/check_execution_paths.py`` keeps it that way — scoring kernels
-may only be invoked from the engine's sanctioned lowering sites.
+may only be invoked from the engine's sanctioned lowering sites.  A
+scored term bag has ONE lowering on every backend (``plan.run_topk``,
+``batch_impact_union_topk``); the host impact-table scorer
+(``TermBagPlan.host_topk``) is the recovery from a device fault and the
+parity reference, never chosen from the platform.
 
 On top of the unified entry sit the two serving-scale pieces:
 
@@ -36,11 +39,10 @@ On top of the unified entry sit the two serving-scale pieces:
   sequential path instead of queueing.
 
 - ``SearchThreadpool`` — a bounded pool of explicitly named daemon
-  workers that parallelizes the single-threaded host fast path across
-  cores for non-coalescable traffic (msearch fallback bodies, the
-  per-segment host scoring loop).  Overflow work runs on the caller's
-  thread (never queued unboundedly, never deadlocks), and ``stop()`` is
-  an idempotent bounded join wired into ``Node.stop()`` /
+  workers that runs the non-coalescable bodies of an ``_msearch`` (its
+  sequential fallback) side by side.  Overflow work runs on the
+  caller's thread (never queued unboundedly, never deadlocks), and
+  ``stop()`` is an idempotent bounded join wired into ``Node.stop()`` /
   ``ClusterNode.stop()``.
 
 Accounting: ``search.batcher.{batched,bypass,window_waits,dispatches}``
@@ -134,10 +136,10 @@ class SearchThreadpool:
         caller's thread after every callable finished.
 
         Called FROM a pool worker, everything runs inline instead:
-        nested fan-out (a pooled msearch-fallback search whose own host
-        fast path fans out) must never park a worker waiting on
-        subtasks only another worker can run — with all workers waiting,
-        the queue would deadlock."""
+        nested fan-out (a pooled msearch-fallback search that fans out
+        itself) must never park a worker waiting on subtasks only
+        another worker can run — with all workers waiting, the queue
+        would deadlock."""
         if getattr(self._tls, "in_worker", False):
             self.inline_runs += len(fns)
             return [fn() for fn in fns]
@@ -209,7 +211,7 @@ class _Member:
         self.group_size = 1
         self.wait_s = 0.0
         self.stats = {"pruned": 0, "scanned": 0}
-        self.path = "host_batched"
+        self.path = "device_batched"
         self.gprof = None
 
 
@@ -414,11 +416,10 @@ class ContinuousBatcher:
     def _run_group(self, searcher, field: str, k: int,
                    members: list[_Member]):
         """ONE batched dispatch for the whole group (the leader's
-        thread).  Reuses the msearch BatchGroup machinery — host or
-        device backend chosen exactly like msearch, results
+        thread).  Reuses the msearch BatchGroup machinery — the device
+        program, recovered on the host exactly like msearch, results
         byte-identical to the sequential path by the PR-5 invariant.
         Every member shares (field, k) by group-key construction."""
-        from opensearch_tpu.ops import bm25 as bm25_ops
         from opensearch_tpu.search.batch import BatchGroup
 
         gprof = None
@@ -434,8 +435,6 @@ class ContinuousBatcher:
                                 "queries": len(members),
                                 "continuous": True})
         out = group.run(searcher, prof=gprof)
-        path = ("host_batched" if bm25_ops.host_scoring_enabled()
-                else "device_batched")
         _metrics().counter("search.batcher.dispatches").inc()
         _metrics().counter("search.batcher.batched").inc(len(members))
         for i, m in enumerate(members):
@@ -443,7 +442,7 @@ class ContinuousBatcher:
             m.rows, m.total, m.max_score = rows, total, mx
             m.group_size = len(members)
             m.stats = dict(group.last_stats)
-            m.path = path
+            m.path = m.stats["path"]
             m.gprof = gprof
 
     def _render(self, searcher, member: _Member, t0: float) -> dict:
@@ -520,8 +519,8 @@ class QueryEngine:
     """The unified entry.  Callers hand it a point-in-time
     ``ShardSearcher`` (and, at the REST edge, the owning
     ``IndexService``); backends — mesh collective, continuous batch,
-    host fast path, device kernels — are decisions inside, never
-    separately-wired code paths."""
+    device kernels and their host recovery — are decisions inside,
+    never separately-wired code paths."""
 
     def __init__(self):
         self.pool = SearchThreadpool()

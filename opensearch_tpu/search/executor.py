@@ -32,7 +32,6 @@ from opensearch_tpu.index.segment import (
     DeviceSegment,
     Segment,
 )
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search import insights
 from opensearch_tpu.search import plan as P
 from opensearch_tpu.search.compiler import ShardContext, compile_query
@@ -265,6 +264,21 @@ def _plan_kind(plan) -> str:
 def _health():
     from opensearch_tpu.common.device_health import device_health
     return device_health()
+
+
+def _host_capable(plan) -> bool:
+    """True for a plan the host impact-table scorer can recover."""
+    return (getattr(plan, "scored", False)
+            and getattr(plan, "host_topk", None) is not None)
+
+
+def _degraded(plan, why: str, exc: Optional[BaseException] = None):
+    """The typed error of a device fault on a plan no host scorer can
+    recover: the caller turns it into partial ``_shards.failures[]``."""
+    from opensearch_tpu.common.device_health import DeviceDegradedError
+    cause = f" ({type(exc).__name__}: {exc})" if exc is not None else ""
+    return DeviceDegradedError(
+        f"{why}: plan [{type(plan).__name__}] has no host fallback{cause}")
 
 
 class ShardSearcher:
@@ -605,12 +619,7 @@ class ShardSearcher:
             signature=ckey[0] if ckey is not None else None,
             scored=needs_scores,
             took_ms=(time.monotonic() - t0) * 1000,
-            execution_path=ia.get(
-                "execution_path",
-                "host" if (bm25_ops.host_scoring_enabled()
-                           and getattr(plan, "scored", False)
-                           and getattr(plan, "host_topk", None)
-                           is not None) else "device"),
+            execution_path=ia.get("execution_path", "device"),
             plan_cache=ia["plan_cache"],
             pruned=ia["pruned"], scanned=ia["scanned"],
             transfer_bytes=(xfer1[0] - xfer0[0]) + (xfer1[1] - xfer0[1]),
@@ -834,10 +843,7 @@ class ShardSearcher:
                         body.get("query")),
                     scored=True,
                     took_ms=(time.monotonic() - t0) * 1000,
-                    execution_path=(
-                        "host_batched"
-                        if bm25_ops.host_scoring_enabled()
-                        else "device_batched"),
+                    execution_path=g.last_stats["path"],
                     plan_cache="batched",
                     pruned=g.last_stats["pruned"],
                     scanned=g.last_stats["scanned"],
@@ -854,9 +860,8 @@ class ShardSearcher:
                             total_segments=len(self.segments))]}
         if len(fallback) > 1:
             # non-coalescable members fan out over the engine's bounded
-            # search threadpool — the sequential host fast path
-            # parallelizes across cores instead of serializing behind
-            # one request thread (overflow runs inline, same semantics)
+            # search threadpool instead of serializing behind one
+            # request thread (overflow runs inline, same semantics)
             from opensearch_tpu.search.engine import query_engine
             outs = query_engine().pool.run_all(
                 [(lambda b=bodies[pos]: self.search(b))
@@ -913,6 +918,49 @@ class ShardSearcher:
 
     # -- internals --------------------------------------------------------
 
+    def _pruned(self, plan, bind, seg, ms_host, kth, iattrs, prof, t_seg):
+        """Why ``seg`` gets no program, or None where it must be
+        scored: no query term occurs in it (``pruned_can_match``); its
+        block-max bound is under ``min_score`` (``pruned_min_score``,
+        exact: docs below min_score never count in totals); its bound
+        cannot beat the running k-th score (``pruned_kth``: the k-th
+        holder dispatched earlier, so it wins any tie at exactly the
+        bound by the seg-asc tie-break, and totals become a lower
+        bound).  A pruned segment is counted here, for every caller."""
+        reason = None
+        if not plan.can_match(bind, seg):
+            reason = "pruned_can_match"
+        elif ms_host is not None or kth is not None:
+            bound = plan.max_score_bound(bind, seg)
+            if ms_host is not None and bound < ms_host:
+                reason = "pruned_min_score"
+            elif kth is not None and bound <= kth:
+                reason = "pruned_kth"
+        if reason is not None:
+            _metrics().counter("search.segments_pruned").inc()
+            if iattrs is not None:
+                iattrs["pruned"] += 1
+            if prof is not None:
+                prof.seg_pruned(seg.seg_id, reason,
+                                time.monotonic() - t_seg)
+        return reason
+
+    def _score_on_host(self, plan, bind, seg, k_want, min_score, why,
+                       exc=None):
+        """The one recovery route of a segment the device cannot serve
+        (``why``: breaker open, segment evicted, a device error, a
+        non-finite result): the host impact-table scorer, byte-identical
+        to the device kernel (the PR-5 invariant), so a fault never
+        changes results, only where they are computed.  Counted as one
+        host fallback.  A plan without a host scorer degrades into the
+        caller's partial ``_shards.failures[]`` instead."""
+        if not _host_capable(plan):
+            raise _degraded(plan, why, exc) from exc
+        _ledger().record_host_fallback()
+        return plan.host_topk(  # engine-ok: the recovery backend
+            bind, seg, self.ctx.lives[id(seg)],
+            min(k_want, seg.n_docs), min_score)
+
     def _run_full(self, plan, bind, needed, min_score,
                   can_match_skip=False, deadline=None, ckey=None,
                   prof=None, iattrs=None):
@@ -921,8 +969,7 @@ class ShardSearcher:
         self.segments and must see every segment).  An expired
         ``deadline`` stops the scan at the next segment boundary — the
         same granularity as cancellation."""
-        from opensearch_tpu.common.device_health import (
-            DeviceDegradedError, is_device_error)
+        from opensearch_tpu.common.device_health import is_device_error
         from opensearch_tpu.common.tasks import check_current
 
         health = _health()
@@ -931,22 +978,16 @@ class ShardSearcher:
             # breaker is open they degrade into PR-2-style partial
             # _shards.failures[] at the caller instead of dispatching
             # onto a failing accelerator (or returning a 500)
-            raise DeviceDegradedError(
-                "device circuit breaker open: full-scores plan "
-                f"[{type(plan).__name__}] has no host fallback")
+            raise _degraded(plan, "device circuit breaker open, "
+                                  "full-scores pass")
         ms = _min_score_scalar(min_score)
         for seg in self.segments:
             check_current()        # cancellation point per segment program
             if deadline is not None and deadline.expired():
                 return
             t_seg = time.monotonic() if prof is not None else 0.0
-            if can_match_skip and not plan.can_match(bind, seg):
-                _metrics().counter("search.segments_pruned").inc()
-                if iattrs is not None:
-                    iattrs["pruned"] += 1
-                if prof is not None:
-                    prof.seg_pruned(seg.seg_id, "pruned_can_match",
-                                    time.monotonic() - t_seg)
+            if can_match_skip and self._pruned(plan, bind, seg, None, None,
+                                               iattrs, prof, t_seg):
                 continue
             # phases stay disjoint: prepare time is measured inside
             # _prepared, so the dispatch share is the remainder
@@ -966,9 +1007,9 @@ class ShardSearcher:
                     # counted via record_failure -> device.errors (and
                     # device.restage_failures at the staging site)
                     health.record_failure("dispatch", exc)
-                    raise DeviceDegradedError(
-                        f"device failure on segment [{seg.seg_id}]: "
-                        f"{type(exc).__name__}: {exc}") from exc
+                    raise _degraded(
+                        plan, f"device failure on segment [{seg.seg_id}], "
+                              "full-scores pass", exc) from exc
             health.record_success("dispatch")
             _ledger().record_dispatch(
                 getattr(dseg, "_ledger_group", None),
@@ -1010,9 +1051,16 @@ class ShardSearcher:
         segments that can't beat the running k-th score are skipped too
         — the k-th score is harvested opportunistically from programs
         that already finished, never blocking the async dispatch
-        pipeline."""
-        from opensearch_tpu.common.device_health import (
-            DeviceDegradedError, is_device_error)
+        pipeline.
+
+        Every backend runs the one lowering, ``P.run_topk``.  A segment
+        leaves it for ``_score_on_host`` only on what this loop
+        observes: the device breaker not allowing, the segment evicted
+        under the device budget, a device error at dispatch or at sync,
+        a non-finite result.  ``execution_path`` reports what served the
+        request: ``host`` where every scored segment was recovered."""
+        from opensearch_tpu.common.device_health import (check_finite,
+                                                         is_device_error)
         from opensearch_tpu.common.tasks import check_current
 
         health = _health()
@@ -1044,28 +1092,7 @@ class ShardSearcher:
         # slice threads)
         ms = _min_score_scalar(min_score)
         ms_host = None if min_score is None else float(min_score)
-        # CPU-backend fast path: scored term bags run host-side over the
-        # precomputed impact tables (see ops/bm25.py host_scoring_enabled)
-        host_capable = (getattr(plan, "scored", False)
-                        and getattr(plan, "host_topk", None) is not None)
-        host_fast = bm25_ops.host_scoring_enabled() and host_capable
-        if iattrs is not None:
-            iattrs["execution_path"] = "host" if host_fast else "device"
-        if prof is not None:
-            prof.set("execution_path", "host" if host_fast else "device")
-        if (host_fast and prof is None and not allow_kth_prune
-                and (deadline is None or deadline._deadline is None)
-                and len(self.segments) > 1):
-            # multi-segment host fast path: per-segment scoring is pure
-            # host work with no async-dispatch overlap to exploit, so it
-            # fans out across cores on the engine threadpool instead of
-            # serializing on this thread.  Gated off the paths whose
-            # semantics are scan-order-dependent (k-th-score pruning,
-            # deadlines) and off profiled requests (exact per-phase
-            # attribution) — those keep the sequential loop below.
-            return self._topk_host_parallel(plan, bind, k_want,
-                                            min_score, ms_host, iattrs)
-        if not host_fast and hasattr(plan, "prefetch_quantized"):
+        if hasattr(plan, "prefetch_quantized"):
             # pager prefetch oracle: best-bound-first staging of
             # quantized pages into FREE capacity before the dispatch
             # loop.  Best-effort by construction — a prefetch failure
@@ -1074,9 +1101,11 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
-        # [si, out]: a host path's (vals, idx, tot, mx), or the device's
-        # packed result (P.run_topk), its copy to the host under way
+        # [si, out]: a recovered segment's (vals, idx, tot, mx), or the
+        # device's packed result (P.run_topk), its copy to the host
+        # under way
         launched = []
+        recovered = 0              # of them, scored on the host
         kth = None                 # running k-th best (harvested, host)
         total_is_lower_bound = False
         for si, seg in enumerate(self.segments):
@@ -1084,37 +1113,11 @@ class ShardSearcher:
             if deadline is not None and deadline.expired():
                 break              # partial top-k; response flags timed_out
             t_seg = time.monotonic() if prof is not None else 0.0
-            if not plan.can_match(bind, seg):
-                _metrics().counter("search.segments_pruned").inc()
-                if iattrs is not None:
-                    iattrs["pruned"] += 1
-                if prof is not None:
-                    prof.seg_pruned(seg.seg_id, "pruned_can_match",
-                                    time.monotonic() - t_seg)
-                continue           # can-match skip: no staging, no program
-            if ms_host is not None or kth is not None:
-                bound = plan.max_score_bound(bind, seg)
-                if ms_host is not None and bound < ms_host:
-                    # exact: docs below min_score never count in totals
-                    _metrics().counter("search.segments_pruned").inc()
-                    if iattrs is not None:
-                        iattrs["pruned"] += 1
-                    if prof is not None:
-                        prof.seg_pruned(seg.seg_id, "pruned_min_score",
-                                        time.monotonic() - t_seg)
-                    continue
-                if kth is not None and bound <= kth:
-                    # the k-th holder dispatched earlier, so it wins any
-                    # tie at exactly `bound` (seg-asc tie-break); totals
-                    # become a lower bound
-                    _metrics().counter("search.segments_pruned").inc()
-                    if iattrs is not None:
-                        iattrs["pruned"] += 1
-                    if prof is not None:
-                        prof.seg_pruned(seg.seg_id, "pruned_kth",
-                                        time.monotonic() - t_seg)
-                    total_is_lower_bound = True
-                    continue
+            reason = self._pruned(plan, bind, seg, ms_host, kth, iattrs,
+                                  prof, t_seg)
+            if reason is not None:     # no staging, no program
+                total_is_lower_bound |= reason == "pruned_kth"
+                continue
             if prof is not None:
                 # decision cost so far is can_match; the dispatch share
                 # starts here and excludes _prepared's own prepare phase
@@ -1125,38 +1128,24 @@ class ShardSearcher:
                     "segment.dispatch",
                     {"segment": seg.seg_id, "index": self.index_name,
                      "shard": self.shard_id}):
-                # budget-evicted segments — and segments behind an OPEN
-                # device circuit breaker (common/device_health.py) —
-                # degrade to the SAME host impact-table scoring the CPU
-                # fast path uses: byte-identical to the device kernel
-                # (the PR-5 invariant), so eviction/breaker-open never
-                # changes results, only where they are computed
                 device_ok = (health.allow("dispatch")
                              and health.allow("staging"))
-                use_host = host_fast or (
-                    host_capable
-                    and (getattr(seg, "_device_evicted", False)
-                         or not device_ok))
-                if use_host:
-                    if not host_fast:
-                        _ledger().record_host_fallback()
-                    launched.append([si, plan.host_topk(  # engine-ok: host fast-path backend
-                        bind, seg, self.ctx.lives[id(seg)],
-                        min(k_want, seg.n_docs), min_score)])
-                elif not device_ok:
-                    raise DeviceDegradedError(
-                        "device circuit breaker open: plan "
-                        f"[{type(plan).__name__}] has no host fallback")
+                if not device_ok or (getattr(seg, "_device_evicted", False)
+                                     and _host_capable(plan)):
+                    # an evicted segment of a plan without a host scorer
+                    # restages below; behind an open breaker it degrades
+                    out = self._score_on_host(
+                        plan, bind, seg, k_want, min_score,
+                        "device circuit breaker open")
                 else:
                     try:
                         dseg, dims, ins, A = self._segment_inputs(
                             plan, bind, seg, needed, ckey, prof)
                         k = min(k_want, dseg.n_pad)
-                        packed = P.run_topk(plan, dims, k, A, ins, ms)
+                        out = P.run_topk(plan, dims, k, A, ins, ms)
                         # queued behind the program: phase 2 finds the
                         # result on the host instead of asking for it
-                        packed.copy_to_host_async()
-                        launched.append([si, packed])
+                        out.copy_to_host_async()
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims))
@@ -1164,20 +1153,16 @@ class ShardSearcher:
                         if not is_device_error(exc):
                             raise
                         # counted: record_failure -> device.errors (the
-                        # staging site also counts restage_failures)
-                        health.record_failure("dispatch", exc)
-                        if not host_capable:
-                            raise DeviceDegradedError(
-                                "device failure on segment "
-                                f"[{seg.seg_id}]: "
-                                f"{type(exc).__name__}: {exc}") from exc
-                        # degrade THIS segment to the byte-identical
-                        # host impact-table path; the breaker decides
+                        # staging site also counts restage_failures);
+                        # THIS segment degrades, the breaker decides
                         # whether later segments even try the device
-                        _ledger().record_host_fallback()
-                        launched.append([si, plan.host_topk(  # engine-ok: host degrade backend
-                            bind, seg, self.ctx.lives[id(seg)],
-                            min(k_want, seg.n_docs), min_score)])
+                        health.record_failure("dispatch", exc)
+                        out = self._score_on_host(
+                            plan, bind, seg, k_want, min_score,
+                            f"device failure on segment [{seg.seg_id}]",
+                            exc)
+                recovered += isinstance(out, tuple)
+                launched.append([si, out])
             if iattrs is not None:
                 iattrs["scanned"] += 1
             if prof is not None:
@@ -1192,56 +1177,47 @@ class ShardSearcher:
         # poison (a misbehaving accelerator, not a query property);
         # they are discarded, recomputed on the host byte-identically,
         # and filed as flight-recorder evidence
-        from opensearch_tpu.common.device_health import check_finite
         t_sync = time.monotonic()
         t_red = t_sync if prof is not None else 0.0
         per_seg = []
         total = 0
         max_score = -np.inf
         fetched_bytes = fetched_arrays = 0
-        # the span only where a device result is read back: the host
-        # paths above left numpy tuples
+        # the span only where a device result is read back: a recovered
+        # segment left numpy arrays
         with (_tracer().start_span("device.sync", {"site": "topk"})
-              if any(not isinstance(out, tuple) for _si, out in launched)
+              if recovered < len(launched)
               else contextlib.nullcontext()):
             for si, out in launched:
-                if isinstance(out, tuple):
-                    vals, idx, tot, mx = out
-                else:                  # device result: ONE D2H read
+                if not isinstance(out, tuple):   # device result: ONE D2H read
                     seg = self.segments[si]
+                    bad, fault = 0, None
                     try:
                         packed = np.asarray(out)
-                        vals, idx, tot, mx = P.unpack_topk(packed)
-                        bad = check_finite(vals)
+                        out = P.unpack_topk(packed)
+                        bad = check_finite(out[0])
                     except Exception as exc:       # fault surfaced at sync
                         if not is_device_error(exc):
                             raise
                         health.record_failure("dispatch", exc)
-                        if not host_capable:
-                            raise DeviceDegradedError(
-                                "device failure syncing segment "
-                                f"[{seg.seg_id}]: "
-                                f"{type(exc).__name__}: {exc}") from exc
-                        bad = -1                   # recompute below
+                        fault = exc
                     if bad:
-                        if bad > 0:
-                            health.record_poison(
-                                kernel="run_topk", segment=seg.seg_id,
-                                index=self.index_name, shard=self.shard_id,
-                                bad=bad)
-                            if not host_capable:
-                                raise DeviceDegradedError(
-                                    "non-finite device scores on segment "
-                                    f"[{seg.seg_id}] and the plan has no "
-                                    "host fallback")
-                        _ledger().record_host_fallback()
-                        vals, idx, tot, mx = plan.host_topk(  # engine-ok: poison-recompute backend
-                            bind, seg, self.ctx.lives[id(seg)],
-                            min(k_want, seg.n_docs), min_score)
+                        health.record_poison(
+                            kernel="run_topk", segment=seg.seg_id,
+                            index=self.index_name, shard=self.shard_id,
+                            bad=bad)
+                    if bad or fault is not None:
+                        out = self._score_on_host(
+                            plan, bind, seg, k_want, min_score,
+                            ("non-finite device scores on" if bad
+                             else "device failure syncing")
+                            + f" segment [{seg.seg_id}]", fault)
+                        recovered += 1
                     else:
                         health.record_success("dispatch")
                         fetched_bytes += packed.nbytes
                         fetched_arrays += 1
+                vals, idx, tot, mx = out
                 vals = np.asarray(vals)
                 idx = np.asarray(idx)
                 keep = vals > -np.inf
@@ -1254,67 +1230,17 @@ class ShardSearcher:
             _ledger().record_fetch(fetched_bytes,
                                    time.monotonic() - t_sync,
                                    arrays=fetched_arrays)
+        path = ("host" if launched and recovered == len(launched)
+                else "device")
+        if iattrs is not None:
+            iattrs["execution_path"] = path
+        if prof is not None:
+            prof.set("execution_path", path)
         rows, total, max_score = self._merge_topk(per_seg, k_want, total,
                                                   max_score)
         if prof is not None:
             prof.add("reduce", time.monotonic() - t_red)
         return rows, total, max_score, total_is_lower_bound
-
-    def _topk_host_parallel(self, plan, bind, k_want, min_score,
-                            ms_host, iattrs):
-        """Host fast path over many segments, scored concurrently on the
-        engine threadpool.  Pruning decisions (can-match, min_score
-        block-max) run up front on this thread — they are cheap and
-        deterministic per segment — then each surviving segment's
-        ``host_topk`` runs as one pool task; the merge is the same
-        ``_merge_topk`` the sequential path uses, so results are
-        byte-identical to a sequential scan."""
-        from opensearch_tpu.common.tasks import check_current
-        from opensearch_tpu.search.engine import query_engine
-
-        cand = []
-        for si, seg in enumerate(self.segments):
-            check_current()        # cancellation point per segment
-            if not plan.can_match(bind, seg):
-                _metrics().counter("search.segments_pruned").inc()
-                if iattrs is not None:
-                    iattrs["pruned"] += 1
-                continue
-            if ms_host is not None \
-                    and plan.max_score_bound(bind, seg) < ms_host:
-                _metrics().counter("search.segments_pruned").inc()
-                if iattrs is not None:
-                    iattrs["pruned"] += 1
-                continue
-            cand.append((si, seg))
-            if iattrs is not None:
-                iattrs["scanned"] += 1
-        def score_one(seg):
-            with _tracer().start_span(
-                    "segment.dispatch",
-                    {"segment": seg.seg_id, "index": self.index_name,
-                     "shard": self.shard_id}):
-                return plan.host_topk(  # engine-ok: host fast-path backend
-                    bind, seg, self.ctx.lives[id(seg)],
-                    min(k_want, seg.n_docs), min_score)
-
-        outs = query_engine().pool.run_all(
-            [(lambda seg=seg: score_one(seg)) for _si, seg in cand])
-        per_seg = []
-        total = 0
-        max_score = -np.inf
-        for (si, _seg), (vals, idx, tot, mx) in zip(cand, outs):
-            vals = np.asarray(vals)
-            idx = np.asarray(idx)
-            keep = vals > -np.inf
-            per_seg.append((vals[keep],
-                            np.full(int(keep.sum()), si, _I32),
-                            idx[keep]))
-            total += int(tot)
-            max_score = max(max_score, float(mx))
-        rows, total, max_score = self._merge_topk(per_seg, k_want,
-                                                  total, max_score)
-        return rows, total, max_score, False
 
     @staticmethod
     def _harvest_kth(launched, k_want, kth):

@@ -211,17 +211,19 @@ class TermBagPlan(Plan):
         return total * _BOUND_MARGIN
 
     def host_topk(self, bind, seg, live, k: int, min_score=None):
-        """CPU-backend fast path: score this bag host-side from the
-        segment's precomputed impact table (``Segment.impact_table``)
-        and return ``(vals f32 [m<=k], idx i32 [m], total, max_score)``
-        with ``run_topk``'s exact semantics — float32 contributions in
-        the same multiply order as the device kernel, in-order per-term
-        accumulation, live/min_score masking excluded from totals, and
-        ``lax.top_k``'s tie-break (score desc, then LOWER doc id).
+        """The degradation backend and the parity reference: score this
+        bag host-side from the segment's precomputed impact table
+        (``Segment.impact_table``) and return ``(vals f32 [m<=k], idx
+        i32 [m], total, max_score)`` with ``run_topk``'s exact semantics
+        — float32 contributions in the same multiply order as the device
+        kernel, in-order per-term accumulation, live/min_score masking
+        excluded from totals, and ``lax.top_k``'s tie-break (score desc,
+        then LOWER doc id).
 
-        Used instead of a device dispatch when
-        ``bm25_ops.host_scoring_enabled()`` — see ops/bm25.py on why
-        scatter-heavy scoring is lowered host-side on XLA:CPU."""
+        Never a first choice on any backend: ``ShardSearcher._topk`` and
+        ``BatchGroup.run`` come here for a segment the device cannot
+        serve (breaker open, segment evicted, device error, non-finite
+        result)."""
         n = seg.n_docs
         pf = seg.postings.get(self.field)
         if pf is None:

@@ -11,7 +11,8 @@ host-side stages around those programs:
     compile     plan-tree construction (toQuery/Weight build analog)
     prepare     per-(plan, segment) bindings staging (incl. H2D)
     can_match   can-match + block-max pruning decisions per segment
-    dispatch    device program launches / host fast-path scoring
+    dispatch    device program launches (and a recovered segment's
+                host scoring)
     reduce      host sync + cross-segment top-k merge (collector analog)
     fetch       source materialization, highlight, docvalues
 
@@ -104,8 +105,8 @@ class QueryProfiler:
     # -- per-segment decisions ---------------------------------------------
 
     def seg_scanned(self, seg_id: str, seconds: float) -> None:
-        """A segment that actually dispatched (device program launched
-        or host fast path scored)."""
+        """A segment that actually dispatched (device program launched,
+        or recovered by the host scorer)."""
         self.add("dispatch", seconds)
         self._seg(seg_id, "scanned", seconds)
 

@@ -143,8 +143,7 @@ class SoakConfig:
         if self.search_replicas and not self.searcher_ids:
             raise ValueError(
                 "search_replicas > 0 requires searcher_ids")
-        # accelerator fault class: the pass forces device kernels on
-        # (bm25_ops.HOST_SCORING=False) and the schedule gains the
+        # accelerator fault class: the schedule gains the
         # device_oom / device_poison / device_slow / device_mesh_loss /
         # device_heal directives (testing/fault_injection.py
         # DeviceFaultInjector + common/device_health.py breakers)
@@ -1264,24 +1263,19 @@ class SoakRunner:
                       for k in ("search", "msearch", "bulk", "agg",
                                 "scroll")},
         }
-        host_scoring_saved = None
         dh_saved = None
         if cfg.device_faults:
-            # both passes run the DEVICE kernels (control included, so
-            # convergence compares like with like) on a freshly-reset
-            # health service with a snappy breaker: threshold 2, zero
-            # cooldown (open -> half-open probe on the next request —
-            # wall-clock-free, so verdicts stay deterministic)
+            # both passes run on a freshly-reset health service with a
+            # snappy breaker: threshold 2, zero cooldown (open ->
+            # half-open probe on the next request — wall-clock-free, so
+            # verdicts stay deterministic)
             from opensearch_tpu.common.device_health import device_health
-            from opensearch_tpu.ops import bm25 as bm25_ops
             dh = device_health()
             dh_saved = (dh.enabled, dh.failure_threshold,
                         dh.open_interval_s)
             dh.reset()
             dh.set_failure_threshold(2)
             dh.set_open_interval_s(0.0)
-            host_scoring_saved = bm25_ops.HOST_SCORING
-            bm25_ops.HOST_SCORING = False
         before = self._counter_snapshot()
         workload = MixedWorkload(cfg)
         schedule = ((cfg.schedule if cfg.schedule is not None
@@ -1450,8 +1444,6 @@ class SoakRunner:
             if cfg.device_faults:
                 from opensearch_tpu.common.device_health import \
                     device_health
-                from opensearch_tpu.ops import bm25 as bm25_ops
-                bm25_ops.HOST_SCORING = host_scoring_saved
                 dh = device_health()
                 dh.reset()
                 if dh_saved is not None:

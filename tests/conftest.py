@@ -50,3 +50,27 @@ def tmp_data_dir(tmp_path):
     d = tmp_path / "data"
     d.mkdir()
     return d
+
+
+@pytest.fixture
+def host_recovery():
+    """The state in which the product itself answers every scored term
+    bag from ``TermBagPlan.host_topk``: the ``dispatch`` and ``batch``
+    breakers open, as sustained device errors leave them.  Tests take
+    the host side of a byte-parity check through it, so it is for
+    queries that are term bags (a plan without a host scorer degrades
+    under an open breaker).  Yields the health service: ``reset()``
+    closes the breakers again mid-test.  Health and ledger are reset
+    after."""
+    from opensearch_tpu.common.device_health import device_health
+    from opensearch_tpu.common.device_ledger import device_ledger
+
+    health = device_health()
+    health.reset()
+    health.set_failure_threshold(1)
+    health.set_open_interval_s(3600.0)
+    for kind in ("dispatch", "batch"):
+        health.record_failure(kind)
+    yield health
+    health.reset()
+    device_ledger().reset()

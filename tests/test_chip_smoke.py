@@ -1,4 +1,4 @@
-"""chip_smoke.py's body at a tiny size on CPU XLA kernels, so the command
+"""chip_smoke.py's body at a tiny size on the CPU backend, so the command
 is debugged here and chip time is not spent on typos — plus the two ways
 the smoke must FAIL: without a TPU, and when a device fault was answered
 from the host."""
@@ -12,7 +12,6 @@ import chip_smoke
 from opensearch_tpu.common.device_health import device_health
 from opensearch_tpu.common.device_ledger import device_ledger
 from opensearch_tpu.index import codec
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.testing.fault_injection import DeviceFaultInjector
 
 TINY = chip_smoke.Sizes(
@@ -23,9 +22,8 @@ TINY = chip_smoke.Sizes(
 
 @pytest.fixture
 def device_kernels(monkeypatch):
-    """Force the XLA kernels on the CPU backend, let a 2k-doc segment
-    quantize, and start from clean process-global device books."""
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
+    """Let a 2k-doc segment quantize, and start from clean
+    process-global device books."""
     monkeypatch.setattr(codec, "QUANTIZED_MIN_DOCS", 1024)
     device_health().reset()
     device_ledger().reset()
@@ -45,6 +43,9 @@ def test_smoke_body_runs_every_step_on_cpu_kernels(device_kernels):
     assert out["loaded"]["smoke_f32"]["segments"] == 4
     assert out["loaded"]["smoke_quant"]["segments"] == 1
     assert out["device"]["host_fallbacks"] == 0
+    # the device-versus-host parity asked the degradation route for its
+    # side: one recovered segment a query
+    assert out["device"]["asked_fallbacks"] == TINY.seq_queries
     assert out["device"]["programs"]["plan.run_topk"] >= 1
     assert out["knn"]["recall_at_10"] == 1.0
 

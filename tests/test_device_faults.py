@@ -10,8 +10,7 @@ QoS controller's device-duress adaptation, the ``device_oom`` /
 ``device_poison`` / ``device_slow`` / ``device_mesh_loss`` /
 ``device_heal`` soak directives with their SLOs and two-run
 determinism, the ``_nodes/stats`` ``device.health`` / ``/_metrics``
-surfaces, the bench ``device_faults`` phase, and the
-``tools/check_degraded_paths.py`` tier-1 lint.
+surfaces, and the ``tools/check_degraded_paths.py`` tier-1 lint.
 """
 
 import json
@@ -31,7 +30,6 @@ from opensearch_tpu.common.device_ledger import device_ledger
 from opensearch_tpu.common.telemetry import flight_recorder, metrics
 from opensearch_tpu.mapping.mapper import DocumentMapper
 from opensearch_tpu.index.segment import SegmentWriter
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search.executor import ShardSearcher
 from opensearch_tpu.testing.fault_injection import (DeviceFaultInjector,
                                                     InjectedDeviceError,
@@ -55,13 +53,11 @@ class FakeClock:
 
 @pytest.fixture(autouse=True)
 def _clean_device_state():
-    """Health service, ledger, and host-scoring override are
-    process-global: reset them around every test."""
+    """Health service and ledger are process-global: reset them around
+    every test."""
     device_health().reset()
     device_ledger().reset()
-    prev = bm25_ops.HOST_SCORING
     yield
-    bm25_ops.HOST_SCORING = prev
     device_health().reset()
     device_ledger().reset()
 
@@ -195,7 +191,6 @@ def test_injector_rule_matching_and_bounds():
 # -- byte-identity of the degraded paths ------------------------------------
 
 def test_tripped_breaker_host_results_byte_identical():
-    bm25_ops.HOST_SCORING = False
     s = _searcher()
     clean = s.search(dict(BODY))
     assert clean["hits"]["hits"]
@@ -222,7 +217,6 @@ def test_tripped_breaker_host_results_byte_identical():
 
 
 def test_poison_recompute_byte_identical_with_capture():
-    bm25_ops.HOST_SCORING = False
     s = _searcher()
     clean = s.search(dict(BODY))
     inj = DeviceFaultInjector(seed=3)
@@ -239,7 +233,6 @@ def test_poison_recompute_byte_identical_with_capture():
 
 
 def test_staging_oom_marks_evicted_and_falls_back():
-    bm25_ops.HOST_SCORING = False
     s = _searcher()
     clean = s.search(dict(BODY))
     led = device_ledger()
@@ -265,7 +258,6 @@ def test_staging_oom_marks_evicted_and_falls_back():
 
 def test_non_fallbackable_plan_degrades_partial_not_500(tmp_path):
     from opensearch_tpu.indices.service import IndicesService
-    bm25_ops.HOST_SCORING = False
     svc = IndicesService(str(tmp_path))
     svc.create("ix", {"settings": {"number_of_shards": 1},
                       "mappings": MAPPING})
@@ -304,7 +296,6 @@ def test_non_fallbackable_plan_degrades_partial_not_500(tmp_path):
 
 
 def test_batch_group_device_fault_falls_back_byte_identical():
-    bm25_ops.HOST_SCORING = False
     s = _searcher()
     bodies = [{"query": {"match": {"t": "alpha"}}, "size": 4},
               {"query": {"match": {"t": "gamma delta"}}, "size": 4}]
@@ -472,8 +463,7 @@ def test_device_soak_slos(tmp_path):
     assert dev["mesh_fallbacks"] >= 1
     assert dev["breaker_states"]["staging"] == "closed"
     assert dev["breaker_states"]["dispatch"] == "closed"
-    # the injector's patches are gone and the globals restored
-    assert bm25_ops.HOST_SCORING is None
+    # the injector's patches are gone
     assert "stage" not in device_ledger().__dict__
 
 
@@ -535,7 +525,6 @@ def test_nodes_stats_health_metrics_and_dynamic_settings(tmp_path):
 
 def test_insight_outcome_device_degraded(tmp_path):
     from opensearch_tpu.node import Node
-    bm25_ops.HOST_SCORING = False
     node = Node(str(tmp_path / "node"), port=0)
     try:
         def call(method, path, body=None, ndjson=None):
@@ -574,29 +563,6 @@ def test_insight_outcome_device_degraded(tmp_path):
         assert outcomes.get("device_degraded", 0) >= 1
     finally:
         node.stop()
-
-
-# -- bench phase ------------------------------------------------------------
-
-def test_bench_devfaults_phase(tmp_path, monkeypatch):
-    import bench
-    monkeypatch.setenv("OSTPU_BENCH_PHASES",
-                       str(tmp_path / "phases.jsonl"))
-    s = _searcher()
-    queries = [dict(BODY), {"query": {"match": {"t": "beta"}},
-                            "size": 5}] * 4
-    data = bench.run_devfaults_phase(s, queries, len(queries), "cpu")
-    assert data["qps_healthy"] > 0
-    assert data["qps_under_trip"] > 0
-    assert data["breaker_trips"] >= 1
-    assert data["probe_recoveries"] >= 1
-    assert data["breaker_states"]["dispatch"] == "closed"
-    lines = [json.loads(ln) for ln in
-             (tmp_path / "phases.jsonl").read_text().splitlines()]
-    assert any(ln["phase"] == "device_faults" for ln in lines)
-    # the phase restored the process-global state
-    assert device_health().failure_threshold == 3
-    assert bm25_ops.HOST_SCORING is None
 
 
 # -- tier-1 lint ------------------------------------------------------------

@@ -5,8 +5,8 @@ parity with the actually staged arrays, LRU-dispatch budget eviction
 with byte-identical host-fallback results, the `_nodes/stats` ``device``
 section / `_cat/segments` footprint columns / `/_metrics` gauges, the
 version-tolerant compile registry, the insights transfer attribution,
-the bench ``device`` phase, the client additions, and the
-``tools/check_device_staging.py`` tier-1 lint.
+the client additions, and the ``tools/check_device_staging.py`` tier-1
+lint.
 """
 
 import gc
@@ -26,7 +26,6 @@ from opensearch_tpu.common.device_ledger import (GroupCloser,
 from opensearch_tpu.mapping.mapper import DocumentMapper
 from opensearch_tpu.index.segment import SegmentWriter
 from opensearch_tpu.node import Node
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search.executor import ShardSearcher
 
 TOOLS = os.path.join(os.path.dirname(os.path.dirname(
@@ -36,12 +35,10 @@ TOOLS = os.path.join(os.path.dirname(os.path.dirname(
 @pytest.fixture(autouse=True)
 def _clean_ledger():
     """The ledger is process-global (like breakers/metrics): reset it
-    and the host-scoring override around every test."""
+    around every test."""
     led = device_ledger()
     led.reset()
-    prev = bm25_ops.HOST_SCORING
     yield
-    bm25_ops.HOST_SCORING = prev
     led.reset()
 
 
@@ -144,7 +141,6 @@ def test_host_footprint_is_the_single_size_source():
 # -- budget eviction --------------------------------------------------------
 
 def test_budget_eviction_is_byte_identical_via_host_fallback():
-    bm25_ops.HOST_SCORING = False          # force the device kernels
     s = _searcher(n_segs=2)
     led = device_ledger()
     body = {"query": {"match": {"t": "alpha beta"}}, "size": 5}
@@ -165,7 +161,6 @@ def test_budget_eviction_is_byte_identical_via_host_fallback():
 
 def test_budget_eviction_releases_breaker_charge():
     from opensearch_tpu.common.breakers import breaker_service
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=1)
     breaker = breaker_service().fielddata
     used0 = breaker.used
@@ -183,7 +178,6 @@ def test_budget_eviction_releases_breaker_charge():
 
 
 def test_eviction_order_is_least_recently_dispatched():
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=2)
     led = device_ledger()
     for seg in s.segments:
@@ -200,7 +194,6 @@ def test_eviction_order_is_least_recently_dispatched():
 
 
 def test_restage_counted_when_no_host_fallback_exists():
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=1)
     led = device_ledger()
     body = {"query": {"match": {"t": "alpha"}}, "size": 2,
@@ -216,7 +209,6 @@ def test_restage_counted_when_no_host_fallback_exists():
 
 
 def test_msearch_batched_path_survives_budget():
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=2)
     bodies = [{"query": {"match": {"t": "alpha"}}, "size": 3},
               {"query": {"match": {"t": "beta"}}, "size": 3}]
@@ -228,7 +220,6 @@ def test_msearch_batched_path_survives_budget():
 
 
 def test_transfer_counters_split_stage_and_fetch():
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=1)
     led = device_ledger()
     s.search({"query": {"match": {"t": "alpha"}}, "size": 3})
@@ -242,7 +233,6 @@ def test_transfer_counters_split_stage_and_fetch():
 # -- compile registry -------------------------------------------------------
 
 def test_compile_registry_counts_query_kernels():
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=1)
     s.search({"query": {"match": {"t": "alpha"}}, "size": 3})
     counts = kernel_registry().counts()
@@ -309,7 +299,6 @@ def test_profiler_xla_compiles_survives_missing_introspection(
 def test_insights_rollups_carry_transfer_bytes():
     from opensearch_tpu.search import insights as insights_mod
     from opensearch_tpu.search.insights import QueryInsightsService
-    bm25_ops.HOST_SCORING = False
     s = _searcher(n_segs=1)
     svc = QueryInsightsService(node_id="t")
     body = {"query": {"match": {"t": "alpha"}}, "size": 3}
@@ -357,7 +346,6 @@ def _seed(node, index="devix", docs=12):
 
 
 def test_nodes_stats_device_section_and_budget_setting(node):
-    bm25_ops.HOST_SCORING = False
     _seed(node)
     body = {"query": {"match": {"t": "alpha"}}, "size": 5}
     s, r1 = call(node, "POST", "/devix/_search", body)
@@ -397,7 +385,6 @@ def test_nodes_stats_device_section_and_budget_setting(node):
 
 
 def test_cat_segments_footprint_columns(node):
-    bm25_ops.HOST_SCORING = False
     _seed(node)
     s, _ = call(node, "POST", "/devix/_search",
                 {"query": {"match": {"t": "alpha"}}, "size": 3})
@@ -429,7 +416,6 @@ def test_cat_fielddata_uses_host_footprint(node):
 
 
 def test_metrics_exposition_has_device_series(node):
-    bm25_ops.HOST_SCORING = False
     _seed(node)
     s, _ = call(node, "POST", "/devix/_search",
                 {"query": {"match": {"t": "alpha"}}, "size": 3})
@@ -445,35 +431,10 @@ def test_metrics_exposition_has_device_series(node):
     assert "device_transfer_fetch_bytes_total" in text
 
 
-# -- bench phase ------------------------------------------------------------
-
-def test_bench_device_phase_reports_nonzero_line():
-    sys.path.insert(0, os.path.dirname(TOOLS))
-    try:
-        import bench
-    finally:
-        sys.path.pop(0)
-    s = _searcher(n_segs=2)
-    queries = [{"query": {"match": {"t": t}}, "size": 5}
-               for t in ("alpha", "beta", "alpha beta", "gamma")]
-    data = bench.run_device_phase(s, queries, seq_n=4, platform="cpu")
-    assert data["resident_bytes"] > 0
-    assert data["transfer_stage_bytes"] > 0
-    assert data["transfer_fetch_bytes"] > 0
-    assert data["evictions"] >= 1
-    assert data["budget_bytes"] < data["resident_bytes"]
-    assert data["qps_unconstrained"] > 0
-    assert data["qps_budget_constrained"] > 0
-    # the phase restores global state
-    assert device_ledger().budget_bytes is None
-    assert bm25_ops.HOST_SCORING is None
-
-
 # -- client -----------------------------------------------------------------
 
 def test_client_cat_segments_and_device_stats(tmp_path):
     from opensearch_tpu.client import OpenSearch
-    bm25_ops.HOST_SCORING = False
     node = Node(str(tmp_path / "cnode"), port=0).start()
     try:
         client = OpenSearch(hosts=[{"host": "127.0.0.1",

@@ -97,13 +97,12 @@ def hit_scores(searcher, resp):
 
 
 @pytest.fixture(params=["host", "device"])
-def scoring_path(request, monkeypatch):
-    """Run the parity suite over BOTH lowerings of the term-bag hot
-    path: the CPU-backend host fast path and the XLA kernels (what an
-    accelerator backend executes).  They must be byte-identical."""
-    from opensearch_tpu.ops import bm25 as bm25_ops
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING",
-                        request.param == "host")
+def scoring_path(request):
+    """Run the parity suite over the XLA kernels (what every backend
+    executes) AND over the host scorer that recovers them (through the
+    open breakers of ``host_recovery``).  They must be byte-identical."""
+    if request.param == "host":
+        request.getfixturevalue("host_recovery")
     return request.param
 
 

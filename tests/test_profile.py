@@ -39,7 +39,6 @@ from opensearch_tpu.common.telemetry import (
 )
 from opensearch_tpu.index.segment import SegmentWriter
 from opensearch_tpu.mapping.mapper import DocumentMapper
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search.executor import ShardSearcher
 
 TOOLS = os.path.join(os.path.dirname(os.path.dirname(
@@ -154,15 +153,12 @@ def test_min_score_pruning_attribution():
 # -- byte-identical hits ----------------------------------------------------
 
 @pytest.mark.parametrize("host_scoring", [True, False])
-def test_hits_byte_identical_sequential(host_scoring):
+def test_hits_byte_identical_sequential(host_scoring, request):
     s = build_searcher()
-    saved = bm25_ops.HOST_SCORING
-    bm25_ops.HOST_SCORING = host_scoring
-    try:
-        plain = s.search(dict(Q))
-        profiled = s.search(dict(Q, profile=True))
-    finally:
-        bm25_ops.HOST_SCORING = saved
+    if host_scoring:
+        request.getfixturevalue("host_recovery")
+    plain = s.search(dict(Q))
+    profiled = s.search(dict(Q, profile=True))
     assert hits_bytes(plain) == hits_bytes(profiled)
     assert "profile" not in plain
     path = profiled["profile"]["shards"][0]["engine"]["execution_path"]

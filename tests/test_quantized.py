@@ -47,9 +47,11 @@ def _clean_pager_state():
 
 
 @pytest.fixture(params=["host", "device"])
-def scoring_path(request, monkeypatch):
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING",
-                        request.param == "host")
+def scoring_path(request):
+    """The XLA kernels, or the host scorer that recovers them (through
+    the open breakers of ``host_recovery``)."""
+    if request.param == "host":
+        request.getfixturevalue("host_recovery")
     return request.param
 
 
@@ -273,7 +275,6 @@ def test_quantized_mesh_search_rank_parity(monkeypatch):
     rng = np.random.default_rng(9)
     docs = zipf_corpus(rng, 240)
     body = {"query": {"match": {"body": "w0 w4"}}, "size": 240}
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
 
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "off")
     shards_f = [build_searcher(docs[i * 60:(i + 1) * 60], [60],
@@ -289,7 +290,7 @@ def test_quantized_mesh_search_rank_parity(monkeypatch):
     assert got["hits"]["total"]["value"] == ref["hits"]["total"]["value"]
 
 
-def test_quantized_host_device_byte_identical(monkeypatch):
+def test_quantized_host_device_byte_identical(monkeypatch, host_recovery):
     """On a quantized segment the host fallback computes scores from
     the SAME dequantized f32 column in the same op order as the device
     kernel — byte-identical, like the f32 path's host/device parity."""
@@ -298,12 +299,12 @@ def test_quantized_host_device_byte_identical(monkeypatch):
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "on")
     body = {"query": {"match": {"body": "w0 w3"}}, "size": 260}
 
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", True)
     s_host, _ = build_searcher(docs, [130, 130], prefix="hb")
     host = ranked_hits(s_host.search(dict(body)))
+    assert device_ledger().stats()["budget"]["host_fallbacks"] == 2
 
     device_ledger().reset()
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
+    host_recovery.reset()                    # breakers closed: the device
     s_dev, _ = build_searcher(docs, [130, 130], prefix="db")
     dev = ranked_hits(s_dev.search(dict(body)))
     assert host == dev    # ids AND float32 scores, bit-for-bit
@@ -323,7 +324,6 @@ def test_filter_phrase_on_quantized_segments(monkeypatch):
          "size": 200},
         {"query": {"match_phrase": {"body": "w0 w1"}}, "size": 200},
     ]
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
 
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "off")
     s_f32, _ = build_searcher(docs, [100, 100], prefix="ff")
@@ -405,7 +405,6 @@ def test_pager_eviction_is_invisible_to_results(monkeypatch):
     rng = np.random.default_rng(41)
     docs = zipf_corpus(rng, 240)
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "on")
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     s, _ = build_searcher(docs, [80, 80, 80], prefix="ev")
     body = {"query": {"match": {"body": "w0 w2"}}, "size": 240}
     ref = ranked_hits(s.search(dict(body)))
@@ -424,7 +423,6 @@ def test_prefetch_oracle_runs_ahead_of_dispatch(monkeypatch):
     rng = np.random.default_rng(53)
     docs = zipf_corpus(rng, 210)
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "on")
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     s, _ = build_searcher(docs, [70, 70, 70], prefix="po")
     s.search({"query": {"match": {"body": "w1"}}, "size": 10})
     st = device_pager().stats()
@@ -435,7 +433,6 @@ def test_prefetch_oracle_runs_ahead_of_dispatch(monkeypatch):
 
 def test_pager_stats_in_ledger_and_metrics(monkeypatch):
     monkeypatch.setattr(codec, "QUANTIZED_MODE", "on")
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     rng = np.random.default_rng(61)
     s, _ = build_searcher(zipf_corpus(rng, 90), [90], prefix="st")
     s.search({"query": {"match": {"body": "w0"}}, "size": 5})

@@ -11,8 +11,7 @@ Pins the PR's contract:
   ``queue_wait_ms`` on the insight records and a ``queue`` phase in
   profiled members' breakdowns;
 - non-batchable bodies and serial traffic bypass with no window wait;
-- the multi-segment host fast path fans out over the engine's bounded,
-  named threadpool with byte-identical results, and engine shutdown is
+- the engine's threadpool is bounded and named, and engine shutdown is
   an idempotent bounded join (Node.stop / ClusterNode.stop);
 - the insights coalescability report's prediction brackets realized
   batch occupancy on a zipf arrival schedule (the batcher-sizing loop);
@@ -32,7 +31,6 @@ import pytest
 
 from opensearch_tpu.common.telemetry import metrics
 from opensearch_tpu.indices.service import IndexService
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search import engine as engine_mod
 from opensearch_tpu.search import insights as insights_mod
 from opensearch_tpu.search.engine import ContinuousBatcher, query_engine
@@ -47,12 +45,10 @@ MAPPING = {"properties": {"body": {"type": "text"},
 @pytest.fixture(autouse=True)
 def _restore_engine_globals():
     saved = (engine_mod.BATCHER_ENABLED, engine_mod.BATCHER_WINDOW_MS,
-             engine_mod.BATCHER_MAX_BATCH, engine_mod.AUTO_WINDOW_MS,
-             bm25_ops.HOST_SCORING)
+             engine_mod.BATCHER_MAX_BATCH, engine_mod.AUTO_WINDOW_MS)
     yield
     (engine_mod.BATCHER_ENABLED, engine_mod.BATCHER_WINDOW_MS,
-     engine_mod.BATCHER_MAX_BATCH, engine_mod.AUTO_WINDOW_MS,
-     bm25_ops.HOST_SCORING) = saved
+     engine_mod.BATCHER_MAX_BATCH, engine_mod.AUTO_WINDOW_MS) = saved
 
 
 def build_service(tmp_path, name="qe", n_docs=80, seed=5):
@@ -378,42 +374,6 @@ def test_coalescability_report_brackets_realized_occupancy():
     assert realized <= predicted + 1e-9
     # ...and on bursty zipf traffic it stays a tight one (brackets)
     assert realized >= 1.0 + (predicted - 1.0) / 3.0
-
-
-# -- host fast path over the threadpool --------------------------------------
-
-def test_host_parallel_multi_segment_byte_identity(tmp_path):
-    """The pooled multi-segment host fast path returns exactly what the
-    sequential per-segment loop returns (the profiled request pins the
-    sequential loop; profiling never changes hits)."""
-    svc = build_service(tmp_path, n_docs=120)
-    # several refreshes -> several segments
-    rng = np.random.default_rng(9)
-    vocab = [f"w{i}" for i in range(20)]
-    for wave in range(2):
-        for i in range(40):
-            svc.index_doc(f"x{wave}-{i}", {
-                "body": " ".join(rng.choice(vocab,
-                                            size=int(rng.integers(3, 10)))),
-                "n": int(rng.integers(0, 50))})
-        svc.refresh()
-    searcher = svc.searcher()
-    assert len(searcher.segments) >= 2
-    bm25_ops.HOST_SCORING = True
-    engine_mod.BATCHER_ENABLED = False
-    pool0 = query_engine().pool.submitted
-    body = {"query": {"match": {"body": "w0 w2"}}, "size": 8}
-    par = svc.search(dict(body))
-    assert query_engine().pool.submitted > pool0   # actually fanned out
-    seq = svc.search(dict(body, profile=True))     # sequential loop
-    assert json.dumps(par["hits"], sort_keys=True) \
-        == json.dumps(seq["hits"], sort_keys=True)
-    # min_score block-max pruning is still exact on the parallel path
-    ms_body = dict(body, min_score=0.5)
-    assert json.dumps(svc.search(dict(ms_body))["hits"],
-                      sort_keys=True) \
-        == json.dumps(svc.search(dict(ms_body, profile=True))["hits"],
-                      sort_keys=True)
 
 
 # -- threadpool / shutdown ---------------------------------------------------

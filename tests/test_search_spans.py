@@ -14,7 +14,6 @@ import pytest
 from opensearch_tpu.common.device_ledger import device_ledger
 from opensearch_tpu.common.telemetry import gc_timer, tracer
 from opensearch_tpu.node import Node
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search import engine
 
 TEXT, VECTORS = "spans_text", "spans_vectors"
@@ -98,7 +97,6 @@ def node(tmp_path_factory):
 def device_path(monkeypatch):
     """The XLA kernels on the CPU backend, one request a program (what
     the benchmark's cells run), and a clean ring."""
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     monkeypatch.setattr(engine, "BATCHER_ENABLED", False)
     _empty_ring()
     yield

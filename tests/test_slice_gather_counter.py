@@ -38,7 +38,6 @@ def searcher():
 
 @pytest.fixture(autouse=True)
 def device_path(monkeypatch):
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     monkeypatch.setattr(engine, "BATCHER_ENABLED", False)
 
 

@@ -20,7 +20,6 @@ from opensearch_tpu.common.telemetry import metrics
 from opensearch_tpu.index.segment import SegmentWriter
 from opensearch_tpu.mapping.mapper import DocumentMapper
 from opensearch_tpu.node import Node
-from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search import engine
 from opensearch_tpu.search import plan as P
 from opensearch_tpu.search.executor import ShardSearcher, build_arrays
@@ -72,7 +71,6 @@ N_SEG = 60
 
 @pytest.fixture(autouse=True)
 def device_path(monkeypatch):
-    monkeypatch.setattr(bm25_ops, "HOST_SCORING", False)
     monkeypatch.setattr(engine, "BATCHER_ENABLED", False)
 
 

@@ -1,11 +1,13 @@
 #!/usr/bin/env python
 """Lint: scoring kernels may only be invoked via the unified query engine.
 
-PR "one query engine" collapsed the four execution paths (sequential,
-msearch-batched, CPU host fast path, device mesh) into backend decisions
-inside ``search/engine.py``'s single entry.  The refactor only stays
-collapsed if no NEW code path starts calling the scoring kernels
-directly — that is exactly how the four paths grew in the first place.
+PR "one query engine" collapsed the execution paths (sequential,
+msearch-batched, device mesh) into backend decisions inside
+``search/engine.py``'s single entry; a scored term bag has one lowering
+on every backend, and ``host_topk`` is its recovery and its parity
+reference.  The refactor only stays collapsed if no NEW code path
+starts calling the scoring kernels directly — that is exactly how the
+paths grew in the first place.
 
 Therefore: any call of a scoring-kernel function —
 
