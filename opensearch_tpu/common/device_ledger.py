@@ -253,7 +253,8 @@ class DeviceResidencyLedger:
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
-            "fetch": {"bytes": 0, "ops": 0, "arrays": 0, "seconds": 0.0}}
+            "fetch": {"bytes": 0, "ops": 0, "arrays": 0, "seconds": 0.0},
+            "input": {"bytes": 0, "arrays": 0}}
 
     # -- group lifecycle ---------------------------------------------------
 
@@ -313,6 +314,24 @@ class DeviceResidencyLedger:
         self._record(group, (kind, field, name),
                      int(getattr(host_array, "nbytes", None)
                          or out.nbytes), dt)
+        return out
+
+    def stage_input(self, host_array):
+        """Transfer-only staging of ONE per-query input (term ids, a
+        query vector, a scalar): the ``jnp.asarray`` and a count under
+        ``transfers.input``, the outbound mirror of ``record_fetch``'s
+        ``arrays``.  Nothing becomes resident here and no group owns
+        it: the prepared-bindings cache holds the result for as long as
+        the query may come again.  Untimed: the copy is asynchronous and
+        two clock reads would cost what a small one does."""
+        import jax.numpy as jnp
+
+        out = jnp.asarray(host_array)      # staging-ok: the ledger itself
+        with self._lock:
+            t = self._transfers["input"]
+            t["bytes"] += int(getattr(host_array, "nbytes", None)
+                              or out.nbytes)
+            t["arrays"] += 1
         return out
 
     def device_put(self, group: Optional[_Group], value, sharding=None,
@@ -491,8 +510,9 @@ class DeviceResidencyLedger:
         with self._lock:
             groups = list(self._groups.values())
             transfers = {
-                side: {**{k: v for k, v in t.items() if k != "seconds"},
-                       "time_ms": round(t["seconds"] * 1000.0, 3)}
+                side: ({**{k: v for k, v in t.items() if k != "seconds"},
+                        "time_ms": round(t["seconds"] * 1000.0, 3)}
+                       if "seconds" in t else dict(t))
                 for side, t in self._transfers.items()}
             budget = self.budget_bytes
             ev, evb = self.evictions, self._evicted_bytes

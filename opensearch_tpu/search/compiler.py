@@ -823,7 +823,7 @@ def _c_knn(q, ctx, scored):
     if q.filter is not None:
         filter_state = compile_query(q.filter, ctx, scored=False)
 
-    qvec_j = jnp.asarray(qvec)  # staging-ok: per-query input
+    qvec_j = ledger.stage_input(qvec)
     # phase 1: dispatch every segment's device program, keep DEVICE arrays
     pending = []             # (seg_order, vals_dev, idx_dev)
     for seg_order, seg in enumerate(ctx.segments):

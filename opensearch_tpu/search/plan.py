@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from opensearch_tpu.common.device_ledger import device_ledger
 from opensearch_tpu.index.segment import (LONG_MISSING_MAX, pad_bucket,
                                            pad_pow2)
 from opensearch_tpu.ops import bm25 as bm25_ops
@@ -49,15 +50,51 @@ _I32 = np.int32
 _F32 = np.float32
 
 
+def _stage_input(host_array):
+    """One per-query input onto the device, counted (``_nodes/stats``
+    ``device.transfers.input``).  The prepared-bindings cache owns what
+    comes back."""
+    return device_ledger().stage_input(host_array)
+
+
 def _scalar(x, dtype):
-    return jnp.asarray(np.asarray(x, dtype=dtype))  # staging-ok: per-query input (prep-cache owned)
+    return _stage_input(np.asarray(x, dtype=dtype))
 
 
 def _pad_np(arr, size, fill, dtype):
     out = np.full(size, fill, dtype=dtype)
     a = np.asarray(arr, dtype=dtype)
     out[: len(a)] = a
-    return jnp.asarray(out)  # staging-ok: per-query input (prep-cache owned)
+    return _stage_input(out)
+
+
+def _pack_term_inputs(tids, active, idfs, weights, required) -> np.ndarray:
+    """A term bag's per-query inputs as ONE host ``int32[4 * t_pad +
+    1]``: ``[tids | active as 0/1 | idfs' bits | weights' bits |
+    required]``, so that they cross to the device in one copy a segment
+    program (``_pack_topk``'s mirror on the way out).  ``idfs`` and
+    ``weights`` may be shorter than ``t_pad`` (zero bits pad them, as
+    0.0 did) or absent (the filter lowering reads neither)."""
+    t_pad = len(tids)
+    out = np.zeros(4 * t_pad + 1, dtype=_I32)
+    out[:t_pad] = tids
+    out[t_pad:2 * t_pad] = active
+    for at, vals in ((2 * t_pad, idfs), (3 * t_pad, weights)):
+        if vals is not None:
+            bits = np.asarray(vals, dtype=_F32).view(_I32)
+            out[at:at + len(bits)] = bits
+    out[4 * t_pad] = required
+    return out
+
+
+def _unpack_term_inputs(packed, t_pad: int):
+    """Traced side of ``_pack_term_inputs``: (tids i32[t_pad], active
+    bool[t_pad], idfs f32[t_pad], weights f32[t_pad], required i32) by
+    static slices; the bit cast gives back every float32 bit for bit."""
+    def f32(at):
+        return lax.bitcast_convert_type(packed[at:at + t_pad], jnp.float32)
+    return (packed[:t_pad], packed[t_pad:2 * t_pad] != 0,
+            f32(2 * t_pad), f32(3 * t_pad), packed[4 * t_pad])
 
 
 # ---------------------------------------------------------------------------
@@ -290,11 +327,13 @@ class TermBagPlan(Plan):
                 active[i] = True
                 budget += int(pf.df[tid])
         if not self.scored:
-            ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
-                   _scalar(bind["required"], _I32))
-            return (t_pad, pad_bucket(budget), False), ins
+            packed = _stage_input(_pack_term_inputs(
+                tids, active, None, None, bind["required"]))
+            return (t_pad, pad_bucket(budget), False), (packed,)
         idfs = np.asarray(bind["idfs"], _F32)
         weights = np.asarray(bind["weights"], _F32)
+        packed = _stage_input(_pack_term_inputs(
+            tids, active, idfs, weights, bind["required"]))
         # fast path: a plain OR bag with positive idf*weight scores > 0
         # exactly on matched docs, so the matched-count scatter (half the
         # kernel's scatter traffic) is skipped entirely
@@ -309,19 +348,12 @@ class TermBagPlan(Plan):
             # programs distinct from quantized ones.
             qarrs = dseg.quantized(self.field, bind["avgdl"])
             qt = seg.quantized_table(self.field, bind["avgdl"])
-            ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
-                   _pad_np(idfs, t_pad, 0.0, _F32),
-                   _pad_np(weights, t_pad, 0.0, _F32),
-                   qarrs["qvals"], qarrs["scales"],
+            ins = (packed, qarrs["qvals"], qarrs["scales"],
                    qarrs["exact_vals"], qarrs["exact_offsets"],
-                   qarrs["packed"], qarrs["base"],
-                   _scalar(bind["required"], _I32))
+                   qarrs["packed"], qarrs["base"])
             return (t_pad, pad_bucket(budget), fast, int(qt.width)), ins
-        ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
-               _pad_np(idfs, t_pad, 0.0, _F32),
-               _pad_np(weights, t_pad, 0.0, _F32),
-               dseg.impacts(self.field, bind["avgdl"]),  # quantize-ok: f32 lowering (non-quantized segments)
-               _scalar(bind["required"], _I32))
+        ins = (packed,
+               dseg.impacts(self.field, bind["avgdl"]))  # quantize-ok: f32 lowering (non-quantized segments)
         return (t_pad, pad_bucket(budget), fast), ins
 
     def skip_arrays(self, dims) -> frozenset:
@@ -361,10 +393,11 @@ class TermBagPlan(Plan):
     def eval(self, A, dims, ins):
         p = A["postings"][self.field]
         n_pad = A["live"].shape[0]
+        tids, active, idfs, weights, required = _unpack_term_inputs(
+            ins[0], dims[0])
         if self.scored and len(dims) == 4:
             t_pad, budget, fast, width = dims
-            (tids, active, idfs, weights, qvals, scales, exact_vals,
-             exact_offsets, packed, base, required) = ins
+            qvals, scales, exact_vals, exact_offsets, packed, base = ins[1:]
             if fast:
                 scores = quantized_ops.quantized_impact_scores(  # engine-ok: TermBag quantized lowering
                     p["offsets"], packed, base, qvals, scales,
@@ -381,12 +414,11 @@ class TermBagPlan(Plan):
             return jnp.where(matched, scores, 0.0), matched
         t_pad, budget, fast = dims
         if not self.scored:
-            tids, active, required = ins
             count = bm25_ops.match_count(  # engine-ok: TermBag filter lowering
                 p["offsets"], p["doc_ids"], p["tfs"], tids, active,
                 n_pad=n_pad, budget=budget)
             return jnp.zeros(n_pad, jnp.float32), count >= required
-        tids, active, idfs, weights, impacts, required = ins
+        impacts = ins[1]
         if fast:
             scores = bm25_ops.impact_scores(  # engine-ok: TermBag scored lowering
                 p["offsets"], p["doc_ids"], impacts, tids, active,
@@ -441,8 +473,8 @@ class PhrasePlan(Plan):
                 e0, e1 = int(pf.offsets[tid]), int(pf.offsets[tid + 1])
                 count = int(pf.pos_offsets[e1] - pf.pos_offsets[e0])
             budgets.append(pad_bucket(count, minimum=1024))
-        ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
-               jnp.asarray(np.asarray(bind["positions"], _I32)),  # staging-ok: per-query input (prep-cache owned)
+        ins = (_stage_input(tids), _stage_input(active),
+               _stage_input(np.asarray(bind["positions"], _I32)),
                _scalar(bind["idf_sum"], _F32),
                _scalar(bind["boost"], _F32),
                _scalar(bind["avgdl"], _F32))
@@ -508,7 +540,7 @@ class SpanNearPlan(Plan):
                 e0, e1 = int(pf.offsets[tid]), int(pf.offsets[tid + 1])
                 count = int(pf.pos_offsets[e1] - pf.pos_offsets[e0])
             budgets.append(pad_bucket(count, minimum=1024))
-        ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
+        ins = (_stage_input(tids), _stage_input(active),
                _scalar(bind["slop"], _I32), _scalar(bind["end"], _I32),
                _scalar(bind["idf_sum"], _F32),
                _scalar(bind["boost"], _F32),
@@ -668,7 +700,7 @@ class PostingsMaskPlan(Plan):
                 active[i] = True
                 budget += int(pf.df[tid])
         return ((t_pad, pad_bucket(budget)),
-                (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
+                (_stage_input(tids), _stage_input(active),
                  _scalar(bind["boost"], _F32)))
 
     def slice_gathers(self, dims):
@@ -805,7 +837,7 @@ class MaskPlan(Plan):
 
     def prepare(self, bind, seg, dseg, ctx):
         mask = bind["mask_fn"](seg, dseg)
-        return (), (jnp.asarray(mask), _scalar(bind["boost"], _F32))  # staging-ok: per-query input (prep-cache owned)
+        return (), (_stage_input(mask), _scalar(bind["boost"], _F32))
 
     def eval(self, A, dims, ins):
         mask, boost = ins
@@ -822,7 +854,7 @@ class ScoredMaskPlan(Plan):
 
     def prepare(self, bind, seg, dseg, ctx):
         scores, mask = bind["fn"](seg, dseg)
-        return (), (jnp.asarray(scores), jnp.asarray(mask))  # staging-ok: per-query input (prep-cache owned)
+        return (), (_stage_input(scores), _stage_input(mask))
 
     def eval(self, A, dims, ins):
         scores, mask = ins
@@ -1327,7 +1359,7 @@ class TermsSetPlan(Plan):
                 tids[i] = tid
                 active[i] = True
                 budget += int(pf.df[tid])
-        ins = (jnp.asarray(tids), jnp.asarray(active),  # staging-ok: per-query input (prep-cache owned)
+        ins = (_stage_input(tids), _stage_input(active),
                _pad_np(bind["idfs"], t_pad, 0.0, _F32),
                _pad_np(bind["weights"], t_pad, 0.0, _F32),
                dseg.impacts(self.field, bind["avgdl"]))  # quantize-ok: TermsSet stays on the f32 lowering
@@ -1370,8 +1402,8 @@ class DistanceFeaturePlan(Plan):
     def prepare(self, bind, seg, dseg, ctx):
         if self.kind == "geo":
             lat, lon = bind["origin"]
-            origin = (jnp.asarray(np.float64(lat)),  # staging-ok: per-query input (prep-cache owned)
-                      jnp.asarray(np.float64(lon)))  # staging-ok: per-query input (prep-cache owned)
+            origin = (_stage_input(np.float64(lat)),
+                      _stage_input(np.float64(lon)))
         else:
             origin = _scalar(bind["origin"], np.float64)
         return (), (origin, _scalar(bind["pivot"], np.float64),
@@ -1408,9 +1440,9 @@ class GeoDistancePlan(Plan):
         return frozenset({("geo", self.field)})
 
     def prepare(self, bind, seg, dseg, ctx):
-        return (), (jnp.asarray(np.float64(bind["lat"])),  # staging-ok: per-query input (prep-cache owned)
-                    jnp.asarray(np.float64(bind["lon"])),  # staging-ok: per-query input (prep-cache owned)
-                    jnp.asarray(np.float64(bind["distance_m"])),  # staging-ok: per-query input (prep-cache owned)
+        return (), (_stage_input(np.float64(bind["lat"])),
+                    _stage_input(np.float64(bind["lon"])),
+                    _stage_input(np.float64(bind["distance_m"])),
                     _scalar(bind["boost"], _F32))
 
     def eval(self, A, dims, ins):
@@ -1446,7 +1478,7 @@ class GeoPolygonPlan(Plan):
         plons = np.full(v_pad, lons[-1])
         plats[: len(lats)] = lats
         plons[: len(lons)] = lons
-        return ((v_pad,), (jnp.asarray(plats), jnp.asarray(plons),  # staging-ok: per-query input (prep-cache owned)
+        return ((v_pad,), (_stage_input(plats), _stage_input(plons),
                            _scalar(bind["boost"], _F32)))
 
     def eval(self, A, dims, ins):
@@ -1480,7 +1512,7 @@ class GeoBoxPlan(Plan):
         return frozenset({("geo", self.field)})
 
     def prepare(self, bind, seg, dseg, ctx):
-        return (), tuple(jnp.asarray(np.float64(bind[k]))  # staging-ok: per-query input (prep-cache owned)
+        return (), tuple(_stage_input(np.float64(bind[k]))
                          for k in ("top", "left", "bottom", "right")) + (
             _scalar(bind["boost"], _F32),)
 
@@ -1577,7 +1609,7 @@ class FunctionScorePlan(Plan):
                 import zlib
                 fb["salt"] = float(zlib.crc32(seg.seg_id.encode()))
             params = tuple(
-                jnp.asarray(np.float64(  # staging-ok: per-query input (prep-cache owned)
+                _stage_input(np.float64(
                     fb.get(name, self._PARAM_DEFAULTS.get(name, 0.0))))
                 for name in self._param_names(spec))
             i_i.append(params)

@@ -230,6 +230,49 @@ def test_transfer_counters_split_stage_and_fetch():
     assert snap == (t["stage"]["bytes"], t["fetch"]["bytes"])
 
 
+def test_stage_input_counts_a_transfer_and_keeps_nothing_resident():
+    """A per-query input is a transfer, not residency: ``arrays`` and
+    ``bytes`` move under ``transfers.input``; no group, no entry, no
+    resident byte, and the staging side's counters stay where they were."""
+    s = _searcher(n_segs=1)
+    led = device_ledger()
+    s.search({"query": {"match": {"t": "alpha"}}, "size": 3})
+    before, rows = led.stats(), led.segments()
+    assert "time_ms" not in before["transfers"]["input"]
+    out = led.stage_input(np.arange(9, dtype=np.int32))
+    assert out.dtype == np.int32 and out.shape == (9,)
+    np.testing.assert_array_equal(np.asarray(out), np.arange(9))
+    led.stage_input(np.float32(2.5))
+    after = led.stats()
+    assert after["transfers"]["input"] == {
+        "bytes": before["transfers"]["input"]["bytes"] + 36 + 4,
+        "arrays": before["transfers"]["input"]["arrays"] + 2}
+    for key in ("resident_bytes", "resident_segments", "dispatches",
+                "indices", "budget"):
+        assert after[key] == before[key], key
+    assert after["transfers"]["stage"] == before["transfers"]["stage"]
+    assert after["transfers"]["fetch"] == before["transfers"]["fetch"]
+    assert led.segments() == rows
+    assert led.transfer_snapshot() == (
+        before["transfers"]["stage"]["bytes"],
+        before["transfers"]["fetch"]["bytes"])
+    led.reset()
+    assert led.stats()["transfers"]["input"] == {"bytes": 0, "arrays": 0}
+
+
+def test_a_search_counts_its_per_query_inputs():
+    """One packed array a term-bag segment (PR 32), none when the same
+    query comes again: the prepared-bindings cache owns what was staged."""
+    s = _searcher(n_segs=2)
+    led = device_ledger()
+    body = {"query": {"match": {"t": "alpha beta"}}, "size": 3}
+    s.search(dict(body))
+    first = led.stats()["transfers"]["input"]
+    assert first == {"bytes": 2 * 9 * 4, "arrays": 2}
+    s.search(dict(body))
+    assert led.stats()["transfers"]["input"] == first
+
+
 # -- compile registry -------------------------------------------------------
 
 def test_compile_registry_counts_query_kernels():
