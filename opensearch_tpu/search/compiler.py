@@ -10,6 +10,7 @@ reader, not per leaf), so scores are consistent across segments.
 
 from __future__ import annotations
 
+import contextlib
 import ipaddress
 import math
 import re
@@ -185,26 +186,40 @@ def _c_match_none(q, ctx, scored):
     return _none()
 
 
+def _bag_term(q, ctx):
+    """The term a ``term`` clause looks up in its field's postings, or
+    None where the clause lowers to something other than a one-term bag
+    (the ``_id`` metafield, an unmapped field, a numeric, an ip CIDR)."""
+    if q.field == "_id":
+        return None
+    ft = _require_ft(ctx, q.field, "term")
+    if ft is None:
+        return None
+    if ft.type_name == "ip":
+        return (None if "/" in str(q.value)
+                else str(ipaddress.ip_address(str(q.value))))
+    if ft.dv_kind in ("long", "double") and ft.type_name != "boolean":
+        return None
+    return ft.term_for_query(q.value)
+
+
 def _c_term(q, ctx, scored):
     if q.field == "_id":
         # term/terms on the _id metafield = an ids query
         # (IdFieldMapper.termQuery)
         return _c_ids(dsl.IdsQuery(values=[str(q.value)], boost=q.boost),
                       ctx, scored)
-    ft = _require_ft(ctx, q.field, "term")
+    term = _bag_term(q, ctx)
+    if term is not None:
+        return _term_bag(ctx, q.field, [term], 1, q.boost, scored)
+    ft = ctx.field_type(q.field)
     if ft is None:
         return _none()
     if ft.type_name == "ip":
-        if "/" in str(q.value):
-            return (P.NumericRangePlan(field=q.field, kind="long"),
-                    _ip_cidr_bind(q.value, q.boost))
-        term = str(ipaddress.ip_address(str(q.value)))
-        return _term_bag(ctx, q.field, [term], 1, q.boost, scored)
-    if ft.dv_kind in ("long", "double") and ft.type_name != "boolean":
-        return (P.NumericTermsPlan(field=q.field, kind=ft.dv_kind),
-                {"values": [ft.term_for_query(q.value)], "boost": q.boost})
-    term = ft.term_for_query(q.value)
-    return _term_bag(ctx, q.field, [term], 1, q.boost, scored)
+        return (P.NumericRangePlan(field=q.field, kind="long"),
+                _ip_cidr_bind(q.value, q.boost))
+    return (P.NumericTermsPlan(field=q.field, kind=ft.dv_kind),
+            {"values": [ft.term_for_query(q.value)], "boost": q.boost})
 
 
 def _c_terms(q, ctx, scored):
@@ -383,18 +398,47 @@ def _c_multi_match(q, ctx, scored):
                   "children": tuple(binds)}
 
 
+def _fold_filter_terms(clauses, ctx):
+    """A ``filter`` group with its ``term`` clauses on one field folded
+    into one filtering term bag with ``required`` = the number of distinct
+    terms: the conjunction the clauses spell, as ONE segment program per
+    ``(t_pad, bucket)`` and one packed input a segment, where a child a
+    clause makes the program space the product of the children's buckets
+    (a tag filter of a filtered ``knn``).  Returns compiled (plan, bind)
+    pairs; a field with one such clause, and every other clause, compiles
+    as before."""
+    bag_terms = [_bag_term(sub, ctx) if type(sub) is dsl.TermQuery else None
+                 for sub in clauses]
+    terms_of = {}
+    for sub, term in zip(clauses, bag_terms):
+        if term is not None:
+            terms_of.setdefault(sub.field, {})[term] = None
+    out, folded = [], set()
+    for sub, term in zip(clauses, bag_terms):
+        terms = terms_of[sub.field] if term is not None else ()
+        if len(terms) < 2:
+            out.append(compile_query(sub, ctx, False))
+        elif sub.field not in folded:          # the first stands for all
+            folded.add(sub.field)
+            out.append(_term_bag(ctx, sub.field, list(terms), len(terms),
+                                 1.0, False))
+    return out
+
+
 def _c_bool(q, ctx, scored):
     groups = {}
     for name, qs, sub_scored in (("must", q.must, scored),
                                  ("should", q.should, scored),
-                                 ("must_not", q.must_not, False),
-                                 ("filter", q.filter, False)):
+                                 ("must_not", q.must_not, False)):
         plans, binds = [], []
         for sub in qs:
             p, b = compile_query(sub, ctx, sub_scored)
             plans.append(p)
             binds.append(b)
         groups[name] = (tuple(plans), tuple(binds))
+    folded = _fold_filter_terms(q.filter, ctx)
+    groups["filter"] = (tuple(p for p, _b in folded),
+                        tuple(b for _p, b in folded))
     n_should = len(groups["should"][0])
     if q.minimum_should_match is not None:
         required = calc_min_should_match(n_should, q.minimum_should_match)
@@ -402,6 +446,11 @@ def _c_bool(q, ctx, scored):
             return _none()   # Lucene rewrites to MatchNoDocsQuery
     else:
         required = 0 if (q.must or q.filter) else (1 if n_should else 0)
+    if len(folded) == 1 < len(q.filter) and not (q.must or q.should
+                                                 or q.must_not):
+        # nothing but the folded bag: the bool IS that bag (scores 0, the
+        # same mask), without BoolPlan's two scalars a segment
+        return folded[0]
     plan = P.BoolPlan(must=groups["must"][0], should=groups["should"][0],
                       must_not=groups["must_not"][0],
                       filter=groups["filter"][0])
@@ -775,6 +824,39 @@ def _c_simple_query_string(q, ctx, scored):
                                  boost=q.boost), ctx, scored)
 
 
+def _knn_filter_masks(q, ctx) -> dict:
+    """{seg_order: bool[n_pad]}: a filtered ``knn``'s filter as one mask
+    program (``plan.run_full``) a segment that holds the vector field,
+    all dispatched before the first scan; nothing is read back.  Span
+    ``knn.filter`` covers the filter's compile and the dispatches."""
+    from opensearch_tpu.common.device_ledger import device_ledger
+    from opensearch_tpu.common.telemetry import metrics, tracer
+    from opensearch_tpu.search.executor import build_arrays
+
+    ledger = device_ledger()
+    f = q.filter
+    clauses = (len(f.must) + len(f.should) + len(f.must_not) + len(f.filter)
+               if isinstance(f, dsl.BoolQuery) else 1)
+    masks = {}
+    with tracer().start_span("knn.filter", {"clauses": clauses}) as span:
+        fplan, fbind = compile_query(f, ctx, scored=False)
+        min_score = ledger.stage_input(np.float32(-np.inf))
+        for seg_order, seg in enumerate(ctx.segments):
+            dseg = seg.device()
+            if dseg.vector.get(q.field) is None:
+                continue
+            A = build_arrays(dseg, fplan.arrays(), ctx.mapper)
+            dims, ins = fplan.prepare(fbind, seg, dseg, ctx)
+            _s, masks[seg_order] = P.run_full(fplan, dims, A, ins,
+                                              min_score)
+            ledger.record_dispatch(getattr(dseg, "_ledger_group", None),
+                                   slice_gather=fplan.slice_gathers(dims))
+        span.set_attribute("segments", len(masks))
+    metrics().counter("search.knn.filtered.requests").inc()
+    metrics().counter("search.knn.filter.programs").inc(len(masks))
+    return masks
+
+
 def _c_knn(q, ctx, scored):
     """knn query: per-segment vector search — exact brute force (matmul +
     top-k, ops/knn.py) or ANN when the field mapping declares a ``method``
@@ -786,8 +868,6 @@ def _c_knn(q, ctx, scored):
     the plugin's filtered exact-search rescue).  All segment programs are
     dispatched asynchronously; the host syncs ONCE per query.
     """
-    import jax.numpy as jnp
-
     from opensearch_tpu.common.device_ledger import device_ledger
     from opensearch_tpu.common.telemetry import tracer
     from opensearch_tpu.ops.ivf import IvfPqIndex, ivf_search, ivfpq_search_l2
@@ -819,60 +899,56 @@ def _c_knn(q, ctx, scored):
     ann_name = method.get("name")
     use_ann = ann_name in ("ivf", "ivf_pq")
 
-    filter_state = None
+    fmasks = {}
+    scan_span = contextlib.nullcontext()
     if q.filter is not None:
-        filter_state = compile_query(q.filter, ctx, scored=False)
+        fmasks = _knn_filter_masks(q, ctx)
+        scan_span = tracer().start_span("knn.scan")
 
     qvec_j = ledger.stage_input(qvec)
     # phase 1: dispatch every segment's device program, keep DEVICE arrays
+    # (a filter's mask programs went first, all of them)
     pending = []             # (seg_order, vals_dev, idx_dev)
-    for seg_order, seg in enumerate(ctx.segments):
-        dseg = seg.device()
-        vcol = dseg.vector.get(q.field)
-        if vcol is None:
-            continue
-        live = ctx.live_jnp(seg, dseg)
-        valid = vcol["exists"] & live
-        if filter_state is not None:
-            from opensearch_tpu.search.executor import build_arrays
-            fplan, fbind = filter_state
-            A = build_arrays(dseg, fplan.arrays(), ctx.mapper)
-            dims, ins = fplan.prepare(fbind, seg, dseg, ctx)
-            _s, fmask = P.run_full(fplan, dims, A, ins,
-                                   jnp.asarray(np.float32(-np.inf)))  # staging-ok: per-query input
-            valid = valid & fmask
-            ledger.record_dispatch(getattr(dseg, "_ledger_group", None),
-                                   slice_gather=fplan.slice_gathers(dims))
-        kk = min(q.k, dseg.n_pad)
-        ann = (seg.ann_index(q.field, method)
-               if use_ann and filter_state is None else None)
-        if ann is not None:
-            nprobe = min(int(method.get("nprobe", 0))
-                         or max(1, ann.nlist // 8), ann.nlist)
-            # the probed candidate pool is nprobe*c_pad rows — top_k past
-            # that is a compile error
-            kk = min(kk, nprobe * ann.c_pad)
-            staged = dseg.ann_staged(ann)
-            if isinstance(ann, IvfPqIndex) and space == "l2":
-                vals, idx = ivfpq_search_l2(*staged, qvec_j, valid,
-                                            k=kk, nprobe=nprobe)
+    with scan_span:
+        for seg_order, seg in enumerate(ctx.segments):
+            dseg = seg.device()
+            vcol = dseg.vector.get(q.field)
+            if vcol is None:
+                continue
+            live = ctx.live_jnp(seg, dseg)
+            valid = vcol["exists"] & live
+            if seg_order in fmasks:
+                valid = valid & fmasks[seg_order]
+            kk = min(q.k, dseg.n_pad)
+            ann = (seg.ann_index(q.field, method)
+                   if use_ann and q.filter is None else None)
+            if ann is not None:
+                nprobe = min(int(method.get("nprobe", 0))
+                             or max(1, ann.nlist // 8), ann.nlist)
+                # the probed candidate pool is nprobe*c_pad rows — top_k past
+                # that is a compile error
+                kk = min(kk, nprobe * ann.c_pad)
+                staged = dseg.ann_staged(ann)
+                if isinstance(ann, IvfPqIndex) and space == "l2":
+                    vals, idx = ivfpq_search_l2(*staged, qvec_j, valid,
+                                                k=kk, nprobe=nprobe)
+                else:
+                    # IvfIndex, or IVF-PQ in a non-l2 space (ADC tables are
+                    # l2-residual based; probe the flat layout instead)
+                    if isinstance(ann, IvfPqIndex):
+                        ann = seg.ann_index(q.field, {**method, "name": "ivf"})
+                        staged = dseg.ann_staged(ann)
+                    vals, idx = ivf_search(*staged, qvec_j, valid,
+                                           space=space, k=kk, nprobe=nprobe)
             else:
-                # IvfIndex, or IVF-PQ in a non-l2 space (ADC tables are
-                # l2-residual based; probe the flat layout instead)
-                if isinstance(ann, IvfPqIndex):
-                    ann = seg.ann_index(q.field, {**method, "name": "ivf"})
-                    staged = dseg.ann_staged(ann)
-                vals, idx = ivf_search(*staged, qvec_j, valid,
-                                       space=space, k=kk, nprobe=nprobe)
-        else:
-            vals, idx = knn_topk_auto(vcol["values"], valid, qvec_j,
-                                      space=space, k=kk)
-        # both copies queue behind the program, so phase 2's two reads
-        # a segment find their arrays on the host
-        vals.copy_to_host_async()
-        idx.copy_to_host_async()
-        pending.append((seg_order, vals, idx))
-        ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
+                vals, idx = knn_topk_auto(vcol["values"], valid, qvec_j,
+                                          space=space, k=kk)
+            # both copies queue behind the program, so phase 2's two reads
+            # a segment find their arrays on the host
+            vals.copy_to_host_async()
+            idx.copy_to_host_async()
+            pending.append((seg_order, vals, idx))
+            ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
     # phase 2: one host sync for all segments' top-k
     candidates = []          # (score, seg_order, local)
     if pending:
