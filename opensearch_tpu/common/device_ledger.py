@@ -250,6 +250,7 @@ class DeviceResidencyLedger:
         self.restages = 0
         self.host_fallbacks = 0
         self.slice_gather_programs = 0
+        self.block_topk_programs = 0
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
@@ -392,14 +393,18 @@ class DeviceResidencyLedger:
     # -- dispatch + fetch-back accounting ----------------------------------
 
     def record_dispatch(self, group: Optional[_Group], *,
-                        slice_gather: bool = False) -> None:
+                        slice_gather: bool = False,
+                        block_topk: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
         signal budget eviction orders by.  ``slice_gather``: the
         program's static shape took ``gather_postings``'s contiguous-
         slice lowering (``ops/bm25.py::slice_lowering``, asked by the
-        caller as the kernel asks it)."""
+        caller as the kernel asks it).  ``block_topk``: its top-k took
+        the two stages of ``ops/topk.py`` (``block_size`` of the
+        program's ``n_pad`` and ``k``, asked the same way)."""
         with self._lock:
             self.slice_gather_programs += bool(slice_gather)
+            self.block_topk_programs += bool(block_topk)
             if group is not None:
                 group.dispatches += 1
                 group.last_dispatch_tick = next(self._tick)
@@ -518,6 +523,7 @@ class DeviceResidencyLedger:
             ev, evb = self.evictions, self._evicted_bytes
             rs, hf = self.restages, self.host_fallbacks
             slice_gathers = self.slice_gather_programs
+            block_topks = self.block_topk_programs
         per_index: dict[str, dict] = {}
         resident = 0
         dispatches = 0
@@ -535,6 +541,7 @@ class DeviceResidencyLedger:
             "resident_segments": len(groups),
             "dispatches": dispatches,
             "slice_gather_programs": slice_gathers,
+            "block_topk_programs": block_topks,
             "budget": {
                 "budget_bytes": budget or 0,
                 "evictions": ev,
@@ -608,7 +615,7 @@ class DeviceResidencyLedger:
             self._groups.clear()
             self.budget_bytes = None
             self.evictions = self.restages = self.host_fallbacks = 0
-            self.slice_gather_programs = 0
+            self.slice_gather_programs = self.block_topk_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
                 for key in t:

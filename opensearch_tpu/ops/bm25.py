@@ -6,7 +6,8 @@ internal/ContextIndexSearcher.java:318).  On TPU the same work is a
 data-parallel program over the whole segment:
 
     CSR gather of the query terms' postings  ->  BM25 per posting
-    ->  scatter-add into a dense per-doc score vector  ->  lax.top_k
+    ->  scatter-add into a dense per-doc score vector  ->  exact top-k
+        (``ops/topk.py``: block maxima, then the k winning blocks)
 
 The gather (``gather_postings``) lays each term's postings run, one
 contiguous stretch of the staged columns, into a flat ``budget``-sized
@@ -302,9 +303,3 @@ def match_count(offsets, doc_ids, tfs, term_ids, term_active, *,
         offsets, doc_ids, tfs, term_ids, term_active,
         budget=budget, pad_doc=n_pad - 1)
     return jnp.zeros(n_pad, jnp.int32).at[d].add(valid.astype(jnp.int32))
-
-
-def topk(scores, k: int):
-    """Top-k by score; XLA's top_k breaks ties by lower index, which is
-    exactly Lucene's ascending-doc-id tie-break."""
-    return lax.top_k(scores, k)

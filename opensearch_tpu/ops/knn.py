@@ -4,7 +4,10 @@ The reference ecosystem's FAISS/nmslib C++ engines plug in via the k-NN
 plugin SPI (ref server/src/main/java/org/opensearch/plugins/
 SearchPlugin.java:151); on TPU the exact path IS the friendly one — a
 [n_docs, dim] x [dim] (or [dim, q]) matmul feeds the MXU directly, and
-``top_k`` replaces the heap.  Score translations match the opensearch-knn
+an exact top-k over the scores replaces the heap: ``ops/topk.py``'s block
+maxima and k winning blocks for one query (a whole-segment ``lax.top_k``
+is a sort of every row on the TPU), ``lax.top_k`` a row for a batch of
+queries.  Score translations match the opensearch-knn
 plugin's space definitions so scores are drop-in comparable:
 
 - l2:            1 / (1 + ||v - q||^2)
@@ -20,6 +23,8 @@ import opensearch_tpu.common.jaxenv  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from opensearch_tpu.ops.topk import topk_exact
 
 SPACES = ("l2", "cosinesimil", "innerproduct")
 
@@ -59,7 +64,7 @@ def knn_scores(vectors, valid, query, *, space: str):
 @partial(jax.jit, static_argnames=("space", "k"))
 def knn_topk(vectors, valid, query, *, space: str, k: int):
     scores = knn_scores(vectors, valid, query, space=space)
-    return lax.top_k(scores, k)
+    return topk_exact(scores, k)
 
 
 def knn_topk_auto(vectors, valid, query, *, space: str, k: int):
@@ -76,7 +81,7 @@ def knn_topk_auto(vectors, valid, query, *, space: str, k: int):
             interpret = jax.default_backend() == "cpu"
             scores = knn_scores_pallas(vectors, valid, query, space=space,
                                        interpret=interpret)
-            return lax.top_k(scores, k)
+            return topk_exact(scores, k)
     return knn_topk(vectors, valid, query, space=space, k=k)
 
 

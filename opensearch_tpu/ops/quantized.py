@@ -1,7 +1,8 @@
 """Quantized-impact scoring kernels (the device half of index/codec.py).
 
 Same composition as the f32 impact kernels in ops/bm25.py — CSR gather,
-weighted scatter-add, lax.top_k downstream — but the gather decodes
+weighted scatter-add, the exact top-k of ops/topk.py downstream — but
+the gather decodes
 bit-packed doc-id deltas in-lane and the impact column dequantizes
 int8/int16 codes against per-term scales, with an in-kernel override
 for terms the exact-rank-parity guard stored as sparse f32

@@ -872,6 +872,7 @@ def _c_knn(q, ctx, scored):
     from opensearch_tpu.common.telemetry import tracer
     from opensearch_tpu.ops.ivf import IvfPqIndex, ivf_search, ivfpq_search_l2
     from opensearch_tpu.ops.knn import knn_topk_auto
+    from opensearch_tpu.ops.topk import block_size
 
     ledger = device_ledger()
     ft = ctx.field_type(q.field)
@@ -948,7 +949,9 @@ def _c_knn(q, ctx, scored):
             vals.copy_to_host_async()
             idx.copy_to_host_async()
             pending.append((seg_order, vals, idx))
-            ledger.record_dispatch(getattr(dseg, "_ledger_group", None))
+            ledger.record_dispatch(
+                getattr(dseg, "_ledger_group", None),
+                block_topk=ann is None and block_size(dseg.n_pad, kk))
     # phase 2: one host sync for all segments' top-k
     candidates = []          # (score, seg_order, local)
     if pending:

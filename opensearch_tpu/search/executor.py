@@ -32,6 +32,7 @@ from opensearch_tpu.index.segment import (
     DeviceSegment,
     Segment,
 )
+from opensearch_tpu.ops import topk as topk_ops
 from opensearch_tpu.search import insights
 from opensearch_tpu.search import plan as P
 from opensearch_tpu.search.compiler import ShardContext, compile_query
@@ -1148,7 +1149,8 @@ class ShardSearcher:
                         out.copy_to_host_async()
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
-                            slice_gather=plan.slice_gathers(dims))
+                            slice_gather=plan.slice_gathers(dims),
+                            block_topk=topk_ops.block_size(dseg.n_pad, k))
                     except Exception as exc:
                         if not is_device_error(exc):
                             raise

@@ -45,6 +45,7 @@ from opensearch_tpu.ops import filters as filter_ops
 from opensearch_tpu.ops import phrase as phrase_ops
 from opensearch_tpu.ops import quantized as quantized_ops
 from opensearch_tpu.ops import span as span_ops
+from opensearch_tpu.ops import topk as topk_ops
 
 _I32 = np.int32
 _F32 = np.float32
@@ -1835,10 +1836,11 @@ def _edit_distance_le(a: str, b: str, k: int) -> bool:
 
 def _key_topk(key, k: int, matched):
     """(top_scores[k], top_local_ids[k], total_matched, max_score) of a
-    key that is -inf where nothing matched.  top_k's lower-index
-    tie-break == Lucene's ascending-doc-id tie-break."""
-    vals, idx = lax.top_k(key, k)
-    return vals, idx, matched.sum(), jnp.max(key)
+    key that is -inf where nothing matched.  ``lax.top_k``'s lower-index
+    tie-break == Lucene's ascending-doc-id tie-break, and ``ops/topk.py``
+    keeps it without sorting the segment."""
+    vals, idx, mx = topk_ops.topk_and_max(key, k)
+    return vals, idx, matched.sum(), mx
 
 
 def _pack_topk(vals, idx, tot, mx):

@@ -273,6 +273,45 @@ def test_a_search_counts_its_per_query_inputs():
     assert led.stats()["transfers"]["input"] == first
 
 
+def test_record_dispatch_counts_block_topk_programs():
+    """``block_topk`` is what ``ops/topk.py::block_size`` said of the
+    program's ``(n_pad, k)``: a block size counts one, 0 and the default
+    none; the group's dispatches move as before; ``reset`` zeroes it."""
+    from opensearch_tpu.ops.topk import block_size
+
+    led = device_ledger()
+    s = _searcher(n_segs=1)
+    s.search({"query": {"match": {"t": "alpha"}}, "size": 3})
+    g = s.segments[0].device()._ledger_group
+    before = led.stats()
+    assert before["block_topk_programs"] == 0     # n_pad 8: ``lax.top_k``
+    assert before["dispatches"] == 1
+    led.record_dispatch(g, block_topk=block_size(1048576, 10))
+    led.record_dispatch(g, block_topk=block_size(131072, 10000))
+    led.record_dispatch(g, slice_gather=True)
+    led.record_dispatch(None, block_topk=True)
+    after = led.stats()
+    assert after["block_topk_programs"] == 2
+    assert after["slice_gather_programs"] == before[
+        "slice_gather_programs"] + 1
+    assert after["dispatches"] == 4
+    led.reset()
+    assert led.stats()["block_topk_programs"] == 0
+
+
+@pytest.mark.parametrize("size", [3, 10000])
+def test_a_narrow_segment_search_takes_no_block_topk(size):
+    """A tier-1 segment is narrower than the two stages pay for: every
+    program is ``lax.top_k`` and the counter says so, whatever ``size``."""
+    s = _searcher(n_segs=2)
+    led = device_ledger()
+    resp = s.search({"query": {"match": {"t": "alpha beta"}}, "size": size})
+    assert resp["hits"]["total"]["value"] == 5
+    stats = led.stats()
+    assert stats["dispatches"] == 2
+    assert stats["block_topk_programs"] == 0
+
+
 # -- compile registry -------------------------------------------------------
 
 def test_compile_registry_counts_query_kernels():
@@ -402,6 +441,8 @@ def test_nodes_stats_device_section_and_budget_setting(node):
     assert dev["transfers"]["stage"]["bytes"] > 0
     assert dev["transfers"]["fetch"]["bytes"] > 0
     assert dev["compile_registry"]["total"] >= 1
+    assert dev["slice_gather_programs"] >= 1
+    assert dev["block_topk_programs"] == 0      # a segment of 40 rows
     # what jax actually runs on, so a node that came up on the wrong
     # backend says so from the client's side
     assert dev["backend"]["platform"] == "cpu"

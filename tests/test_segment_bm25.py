@@ -14,6 +14,7 @@ import pytest
 from opensearch_tpu.mapping.mapper import DocumentMapper
 from opensearch_tpu.index.segment import SegmentWriter
 from opensearch_tpu.ops import bm25
+from opensearch_tpu.ops.topk import topk_exact
 
 K1, B = 1.2, 0.75
 
@@ -80,7 +81,8 @@ def run_kernel(segment, corpus, terms, k=10):
         np.asarray(idfs, np.float32), np.ones(len(tids), np.float32),
         np.float32(avgdl), n_pad=dev.n_pad, budget=budget)
     scores = np.asarray(scores)
-    vals, idx = bm25.topk(np.where(np.arange(dev.n_pad) < n, scores, -np.inf), k)
+    vals, idx = topk_exact(
+        np.where(np.arange(dev.n_pad) < n, scores, -np.inf), k)
     return np.asarray(scores[:n]), np.asarray(vals), np.asarray(idx)
 
 
