@@ -55,6 +55,16 @@ def pad_bucket(n: int, minimum: int = 4096) -> int:
     return b
 
 
+def per_term_max(values: np.ndarray, offsets: np.ndarray) -> np.ndarray:
+    """float32 [T]: the largest of each term's run ``offsets[t] :
+    offsets[t + 1]`` of a per-posting column, 0 for an empty run."""
+    if not len(values):
+        return np.zeros(len(offsets) - 1, dtype=np.float32)
+    starts = np.minimum(offsets[:-1], len(values) - 1)
+    return np.where(np.diff(offsets) > 0,
+                    np.maximum.reduceat(values, starts), np.float32(0.0))
+
+
 @dataclass
 class PostingsField:
     """CSR inverted index for one field.
@@ -80,9 +90,23 @@ class PostingsField:
     # still writes a "norm entry" (Lucene FieldExistsQuery over norms
     # matches it even though docCount does not count it).
     present: np.ndarray = None       # bool [n_docs]
+    # a ``rank_features`` field: ``tfs`` holds each posting's stored
+    # feature weight (Lucene FeatureField's 9-bit float), scored as it
+    # stands; no norms, no positions (``pos_offsets`` int32 [1],
+    # ``positions`` empty), so two columns a posting on the device
+    features: bool = False
 
     def term_id(self, term: str) -> int:
         return self.terms.get(term, -1)
+
+    def max_values(self) -> np.ndarray:
+        """float32 [T]: each term's largest value-column entry (a feature
+        field's block-max table, what ``impact_table``'s ``max`` is to
+        BM25).  Computed once: a segment's postings never change."""
+        mx = getattr(self, "_max_values", None)
+        if mx is None:
+            mx = self._max_values = per_term_max(self.tfs, self.offsets)
+        return mx
 
 
 @dataclass
@@ -262,21 +286,14 @@ class Segment:
         key = (field, float(np.float32(avgdl)), k1, b)
         out = cache.get(key)
         if out is None:
-            T = len(pf.offsets) - 1
             imp = np.zeros(0, dtype=np.float32)
-            mx = np.zeros(T, dtype=np.float32)
             if len(pf.tfs):
                 dl = pf.doc_lens[pf.doc_ids]
                 norm = np.float32(k1) * (np.float32(1.0 - b)
                                          + np.float32(b) * dl
                                          / np.float32(avgdl))
                 imp = (pf.tfs / (pf.tfs + norm)).astype(np.float32)
-                lens = np.diff(pf.offsets)
-                starts = np.minimum(pf.offsets[:-1], len(imp) - 1)
-                mx = np.where(lens > 0,
-                              np.maximum.reduceat(imp, starts),
-                              np.float32(0.0))
-            out = (imp, mx)
+            out = (imp, per_term_max(imp, pf.offsets))
             cache.put(key, out)
         return out
 
@@ -817,6 +834,8 @@ class SegmentWriter:
         ordinals: dict[str, list[list[str]]] = {}
         vectors: dict[str, dict[int, list[float]]] = {}
         geos: dict[str, list[list[tuple[float, float]]]] = {}
+        # rank_features field -> feature -> [(doc, stored weight)]
+        feature_inv: dict[str, dict[str, list[tuple[int, float]]]] = {}
 
         for i, doc in enumerate(docs):
             seg.doc_ids.append(doc.doc_id)
@@ -862,6 +881,10 @@ class SegmentWriter:
                     column[fname][i].extend(vals)
             for fname, vec in doc.vectors.items():
                 vectors.setdefault(fname, {})[i] = vec
+            for fname, feats in doc.features.items():
+                finv = feature_inv.setdefault(fname, {})
+                for feature, weight in feats.items():
+                    finv.setdefault(feature, []).append((i, weight))
 
         field_present: dict[str, np.ndarray] = {}
         for i, doc in enumerate(docs):
@@ -875,6 +898,9 @@ class SegmentWriter:
                 fname, inv.get(fname, {}), n, field_doc_lens.get(fname),
                 has_norms=norms_fields.get(fname, fname in field_doc_lens),
                 present=field_present.get(fname))
+
+        for fname, finv in feature_inv.items():
+            seg.postings[fname] = self._build_features(finv, n)
 
         for fname, per_doc in longs.items():
             seg.numeric_dv[fname] = self._build_numeric(per_doc, n, "long")
@@ -976,6 +1002,29 @@ class SegmentWriter:
             total_len=float(doc_lens[doc_lens > 0].sum()) if has_norms else float(n_docs),
             docs_with_field=docs_with, has_norms=has_norms,
             present=present)
+
+    @staticmethod
+    def _build_features(finv, n_docs: int) -> PostingsField:
+        """A ``rank_features`` field's postings: terms are the features in
+        sorted order, ``tfs`` the stored weights (see ``PostingsField.
+        features``)."""
+        terms_sorted = sorted(finv)
+        df = np.asarray([len(finv[t]) for t in terms_sorted], dtype=np.int32)
+        offsets = np.zeros(len(terms_sorted) + 1, dtype=np.int32)
+        np.cumsum(df, out=offsets[1:])
+        entries = [e for t in terms_sorted for e in finv[t]]
+        doc_ids = np.asarray([d for d, _w in entries], dtype=np.int32)
+        present = np.zeros(n_docs, dtype=bool)
+        present[doc_ids] = True
+        return PostingsField(
+            terms={t: i for i, t in enumerate(terms_sorted)}, df=df,
+            offsets=offsets, doc_ids=doc_ids,
+            tfs=np.asarray([w for _d, w in entries], dtype=np.float32),
+            pos_offsets=np.zeros(1, dtype=np.int32),
+            positions=np.zeros(0, dtype=np.int32),
+            doc_lens=np.ones(n_docs, dtype=np.float32),
+            total_len=float(n_docs), docs_with_field=int(present.sum()),
+            has_norms=False, present=present, features=True)
 
     @staticmethod
     def _build_numeric(per_doc: list[list], n_docs: int, kind: str) -> NumericDV:

@@ -72,6 +72,7 @@ def _segment_encode(seg: Segment):
         meta["postings"][f] = {
             "terms": list(pf.terms), "total_len": pf.total_len,
             "docs_with_field": pf.docs_with_field, "has_norms": pf.has_norms,
+            "features": pf.features,
         }
         for k in ("df", "offsets", "doc_ids", "tfs", "pos_offsets",
                   "positions", "doc_lens", "present"):
@@ -477,7 +478,8 @@ def _segment_decode(seg_id: str, meta: dict, z, src_blob: bytes) -> Segment:
             pos_offsets=z[f"p|{f}|pos_offsets"],
             positions=z[f"p|{f}|positions"], doc_lens=z[f"p|{f}|doc_lens"],
             total_len=m["total_len"], docs_with_field=m["docs_with_field"],
-            has_norms=m["has_norms"], present=z[f"p|{f}|present"])
+            has_norms=m["has_norms"], present=z[f"p|{f}|present"],
+            features=bool(m.get("features", False)))
     for f, m in meta["numeric"].items():
         seg.numeric_dv[f] = NumericDV(
             kind=m["kind"], offsets=z[f"n|{f}|offsets"],

@@ -13,6 +13,7 @@ Output is a ``ParsedDocument`` holding, per field:
   lands in the column, matching Lucene array-field semantics)
 - ``vectors``: dense float vectors (single-valued, like Lucene KnnVectorField)
 - ``geo_points``: (lat, lon) pairs
+- ``features``: {feature: weight} of a ``rank_features`` field
 
 Metadata slots (``_seq_no`` / ``_version`` analog, assigned by the engine):
 ``seq_no`` and ``version`` fields on ParsedDocument.
@@ -61,6 +62,8 @@ class ParsedDocument:
         default_factory=dict)
     # nested path -> [per-object {child_path: ("num"|"ord", [values])}]
     nested: dict[str, list[dict]] = dc_field(default_factory=dict)
+    # rank_features field -> {feature: stored float32 weight}
+    features: dict[str, dict[str, float]] = dc_field(default_factory=dict)
 
 
 def _dynamic_type_for(value: Any) -> Optional[dict]:
@@ -354,7 +357,24 @@ class DocumentMapper:
                 f"field [{ft.name}] of type [{ft.type_name}] does not "
                 "support arrays")
         from opensearch_tpu.mapping.types import (CompletionFieldType,
-                                                  JoinFieldType)
+                                                  JoinFieldType,
+                                                  RankFeaturesFieldType)
+        if isinstance(ft, RankFeaturesFieldType):
+            # one posting a feature, its value column the stored weight;
+            # a feature given twice in one document is refused, as
+            # RankFeaturesFieldMapper refuses it
+            feats = doc.features.setdefault(ft.name, {})
+            for v in values:
+                if v is None:
+                    continue
+                for feature, weight in ft.feature_weights(v).items():
+                    if feature in feats:
+                        raise MapperParsingError(
+                            f"[rank_features] field [{ft.name}] got "
+                            f"feature [{feature}] more than once in one "
+                            "document")
+                    feats[feature] = weight
+            return
         if isinstance(ft, CompletionFieldType):
             # {"input": [...], "weight": n} | "text" | ["a", "b"]:
             # inputs land in the sorted ordinal column (the prefix

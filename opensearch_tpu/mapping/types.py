@@ -23,6 +23,8 @@ import ipaddress
 import math
 from typing import Any, Optional
 
+import numpy as np
+
 from opensearch_tpu.common.errors import IllegalArgumentError, MapperParsingError
 
 
@@ -514,6 +516,72 @@ class RankFeatureFieldType(FieldType):
         return v
 
 
+_FEATURE_MIN = 1.1754943508222875e-38      # Float.MIN_NORMAL
+
+
+def feature_bits(value: float) -> int:
+    """Lucene ``FeatureField``'s stored term frequency:
+    ``floatToIntBits(v) >>> 15``, the float32's sign, exponent and top
+    eight mantissa bits ("9 significant bits")."""
+    return int(np.float32(value).view(np.uint32)) >> 15
+
+
+def feature_value(bits: int) -> float:
+    """``FeatureField.decodeFeatureValue``: ``intBitsToFloat(freq <<
+    15)``, the float32 with its low 15 bits cleared."""
+    return float(np.uint32(int(bits) << 15).view(np.float32))
+
+
+class RankFeaturesFieldType(FieldType):
+    """Sparse feature vector (mapper-extras RankFeaturesFieldMapper): an
+    object of feature -> positive float.  Each feature is a term of the
+    field's postings whose value column is the weight as ``FeatureField``
+    stores it (``feature_bits``); no norms, no positions, no doc values.
+    Queried by ``neural_sparse`` alone."""
+
+    type_name = "rank_features"
+    dv_kind = "none"
+    indexed = False          # the mapper writes ``ParsedDocument.features``
+
+    def index_terms(self, value, analyzers):
+        return []
+
+    def term_for_query(self, value):
+        raise IllegalArgumentError(
+            f"[rank_features] field [{self.name}] does not support term "
+            "queries; use [neural_sparse]")
+
+    def feature_weights(self, value) -> dict:
+        """{feature: stored float32} of one JSON value of the field."""
+        if not isinstance(value, dict):
+            raise MapperParsingError(
+                f"[rank_features] field [{self.name}] must be an object "
+                f"of feature -> number, got [{value}]")
+        out = {}
+        for feature, raw in value.items():
+            if isinstance(raw, bool) or not isinstance(raw, (int, float)):
+                raise MapperParsingError(
+                    f"failed to parse feature [{self.name}.{feature}] of "
+                    f"type [rank_features]: [{raw}]")
+            try:
+                v = float(raw)
+            except OverflowError:                # an int past float64
+                v = math.inf
+            if not math.isfinite(v) or v <= 0:
+                raise MapperParsingError(
+                    f"[rank_features] fields only support positive "
+                    f"finite values, got [{raw}] for feature "
+                    f"[{self.name}.{feature}]")
+            with np.errstate(over="ignore"):
+                v32 = float(np.float32(v))
+            if not _FEATURE_MIN <= v32 < math.inf:
+                raise MapperParsingError(
+                    f"feature [{self.name}.{feature}] value [{raw}] is "
+                    "outside the positive normal float range")
+            out[str(feature)] = feature_value(feature_bits(v32))
+        return out
+
+
 class CompletionFieldType(FieldType):
     """Prefix completion (suggest/completion/CompletionFieldMapper).
     Inputs live in the segment's SORTED ordinal column, so a prefix is a
@@ -608,6 +676,7 @@ FIELD_TYPES = {
         DateFieldType, IpFieldType, DenseVectorFieldType, GeoPointFieldType,
         BinaryFieldType, UnsignedLongFieldType, ObjectFieldType,
         JoinFieldType, CompletionFieldType, RankFeatureFieldType,
+        RankFeaturesFieldType,
         DateNanosFieldType,
     ]
 }

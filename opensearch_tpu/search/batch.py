@@ -502,7 +502,7 @@ def plan_batches(searcher, bodies: list) -> tuple[dict, list]:
         except Exception:
             fallback.append(pos)
             continue
-        if not isinstance(plan, P.TermBagPlan) or not plan.scored:
+        if not isinstance(plan, P.TermBagPlan) or not plan.bm25_scored:
             fallback.append(pos)
             continue
         k = int(body.get("size", 10))

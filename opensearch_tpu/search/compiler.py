@@ -1404,6 +1404,39 @@ def _c_rank_feature(q, ctx, scored):
         script={"source": src}, boost=q.boost), ctx, scored)
 
 
+def _c_neural_sparse(q, ctx, scored):
+    """``neural_sparse`` with ``query_tokens``: the scored term bag over
+    the ``rank_features`` field's postings in its feature lowering
+    (``TermBagPlan.features``): idf 1, the query's token weights, any
+    token matches.  score = sum over the query's tokens of f32(weight) *
+    the stored feature weight, accumulated in float32 (the plug-in's
+    BooleanQuery of ``FeatureField.newLinearQuery`` clauses).  Tokens no
+    segment of the shard holds are dropped here.  Span ``sparse.bind``
+    covers the look-up and the bind; the ``search.neural_sparse.*``
+    counters move where the bound plan runs (``ShardSearcher._topk``)."""
+    from opensearch_tpu.common.telemetry import tracer
+
+    ft = ctx.field_type(q.field)
+    if ft is None or ft.type_name != "rank_features":
+        raise IllegalArgumentError(
+            f"[neural_sparse] query only works on [rank_features] fields, "
+            f"and [{q.field}] is "
+            f"{'unmapped' if ft is None else '[' + ft.type_name + ']'}")
+    with tracer().start_span("sparse.bind",
+                             {"tokens": len(q.tokens)}) as span:
+        known = [(t, w) for t, w in q.tokens if ctx.df(q.field, t)]
+        span.set_attribute("known", len(known))
+        if not known:
+            return _none()
+        weights = (np.asarray([w for _t, w in known], np.float32)
+                   * np.float32(q.boost))
+        bind = {"terms": tuple(t for t, _w in known),
+                "idfs": np.ones(len(known), np.float32),
+                "weights": weights, "avgdl": 1.0, "required": 1}
+        return P.TermBagPlan(field=q.field, scored=scored,
+                             features=True), bind
+
+
 # span end disabled: any analyzer position is < this (< ops.phrase
 # POS_BASE so doc*POS_BASE+pos arithmetic can't overflow)
 _SPAN_NO_END = 1 << 21
@@ -1869,6 +1902,7 @@ _COMPILERS = {
     dsl.DisMaxQuery: _c_dis_max,
     dsl.SimpleQueryStringQuery: _c_simple_query_string,
     dsl.KnnQuery: _c_knn,
+    dsl.NeuralSparseQuery: _c_neural_sparse,
     dsl.ScriptScoreQuery: _c_script_score,
     dsl.BoostingQuery: _c_boosting,
     dsl.NestedQuery: _c_nested,

@@ -315,7 +315,7 @@ class ContinuousBatcher:
         if out is None:
             return None
         plan, bind = out
-        if not isinstance(plan, P.TermBagPlan) or not plan.scored:
+        if not isinstance(plan, P.TermBagPlan) or not plan.bm25_scored:
             return None
         return plan, bind, k
 

@@ -1102,6 +1102,16 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
+        bag_postings = bag_lanes = None
+        if isinstance(plan, P.TermBagPlan) and plan.scored:
+            bag_postings = _metrics().counter("search.term_bag.postings")
+            bag_lanes = _metrics().counter("search.term_bag.budget_lanes")
+            if plan.features:
+                # where the bound plan runs, so a plan-cache hit counts
+                # too; a feature bag under a bool is not seen here
+                _metrics().counter("search.neural_sparse.requests").inc()
+                _metrics().counter("search.neural_sparse.query_tokens").inc(
+                    len(bind["terms"]))
         # [si, out]: a recovered segment's (vals, idx, tot, mx), or the
         # device's packed result (P.run_topk), its copy to the host
         # under way
@@ -1151,6 +1161,11 @@ class ShardSearcher:
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims),
                             block_topk=topk_ops.block_size(dseg.n_pad, k))
+                        if bag_postings is not None:
+                            # what the 4^k bucket rule costs in lanes:
+                            # postings gathered against lanes keyed
+                            bag_postings.inc(dims.postings)
+                            bag_lanes.inc(dims[1])
                     except Exception as exc:
                         if not is_device_error(exc):
                             raise

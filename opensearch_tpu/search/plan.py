@@ -209,16 +209,45 @@ class MatchNonePlan(Plan):
         return 0.0
 
 
+class BagDims(tuple):
+    """``TermBagPlan``'s program key ``(t_pad, bucket, fast[, width])``.
+    Beside the key, and no part of it (a tuple's hash and equality), it
+    remembers the postings the bucket was rounded up from: the summed df
+    of the terms the segment holds (``search.term_bag.postings``)."""
+
+    postings = 0
+
+    @classmethod
+    def of(cls, postings: int, t_pad: int, *rest) -> "BagDims":
+        dims = cls((t_pad, pad_bucket(postings)) + rest)
+        dims.postings = postings
+        return dims
+
+
 @dataclass(frozen=True)
 class TermBagPlan(Plan):
     """Weighted bag of terms over one field's postings: term / match /
     terms-as-should.  BM25-scored (Lucene TermQuery / BooleanQuery of term
     clauses).  bind: {terms, idfs, weights, required}; ``required`` is the
     per-doc matched-clause count needed (1 = OR, n_terms = AND,
-    minimum_should_match otherwise)."""
+    minimum_should_match otherwise).
+
+    ``features``: the field is a ``rank_features`` field (``neural_sparse``):
+    a posting's value column holds its stored feature weight, so that
+    column stands where BM25's precomputed impacts do, in ``prepare``
+    (the device), ``host_topk`` (recovery, parity) and
+    ``max_score_bound``; ``idfs`` are 1 and ``weights`` the query's
+    token weights.  Same programs, no quantized lowering."""
 
     field: str = ""
     scored: bool = True
+    features: bool = False
+
+    @property
+    def bm25_scored(self) -> bool:
+        """A scored bag whose scores are BM25 impacts: what the batched
+        union kernel (``_msearch``, the batcher) can take."""
+        return self.scored and not self.features
 
     def arrays(self):
         return frozenset({("postings", self.field)})
@@ -237,7 +266,8 @@ class TermBagPlan(Plan):
         pf = seg.postings.get(self.field)
         if pf is None:
             return 0.0
-        mi = seg.max_impacts(self.field, bind["avgdl"])
+        mi = (pf.max_values() if self.features
+              else seg.max_impacts(self.field, bind["avgdl"]))
         total = 0.0
         for t, idf_v, w in zip(bind["terms"], bind["idfs"],
                                bind["weights"]):
@@ -267,7 +297,9 @@ class TermBagPlan(Plan):
         if pf is None:
             return (np.empty(0, _F32), np.empty(0, _I32), 0, -np.inf)
         from opensearch_tpu.index import codec as codec_mod
-        if codec_mod.use_quantized(seg):
+        if self.features:
+            imp = pf.tfs
+        elif codec_mod.use_quantized(seg):
             # parity with the QUANTIZED device kernel: reconstruct
             # impacts exactly as ops/quantized.py does (q * scale,
             # exact-guard blocks overridden) so budget-eviction /
@@ -330,7 +362,7 @@ class TermBagPlan(Plan):
         if not self.scored:
             packed = _stage_input(_pack_term_inputs(
                 tids, active, None, None, bind["required"]))
-            return (t_pad, pad_bucket(budget), False), (packed,)
+            return BagDims.of(budget, t_pad, False), (packed,)
         idfs = np.asarray(bind["idfs"], _F32)
         weights = np.asarray(bind["weights"], _F32)
         packed = _stage_input(_pack_term_inputs(
@@ -340,6 +372,12 @@ class TermBagPlan(Plan):
         # kernel's scatter traffic) is skipped entirely
         fast = (int(bind["required"]) == 1
                 and bool((weights > 0).all()) and bool((idfs > 0).all()))
+        if self.features:
+            # the staged weight column itself, never a quantized table
+            p = dseg.ensure_postings(self.field)
+            ins = (packed, p["tfs"] if p is not None
+                   else _stage_input(np.zeros(8, _F32)))
+            return BagDims.of(budget, t_pad, fast), ins
         if getattr(dseg, "quantized_mode", False):
             # QUANTIZED lowering (index/codec.py): the compressed
             # columns ride in ``ins`` via the pager, the f32 posting
@@ -352,10 +390,10 @@ class TermBagPlan(Plan):
             ins = (packed, qarrs["qvals"], qarrs["scales"],
                    qarrs["exact_vals"], qarrs["exact_offsets"],
                    qarrs["packed"], qarrs["base"])
-            return (t_pad, pad_bucket(budget), fast, int(qt.width)), ins
+            return BagDims.of(budget, t_pad, fast, int(qt.width)), ins
         ins = (packed,
                dseg.impacts(self.field, bind["avgdl"]))  # quantize-ok: f32 lowering (non-quantized segments)
-        return (t_pad, pad_bucket(budget), fast), ins
+        return BagDims.of(budget, t_pad, fast), ins
 
     def skip_arrays(self, dims) -> frozenset:
         # 4-tuple dims = quantized lowering: eval only needs the
@@ -377,6 +415,8 @@ class TermBagPlan(Plan):
         capacity (never evicting residents).  Returns segments staged."""
         from opensearch_tpu.index import codec as codec_mod
         from opensearch_tpu.index.segment import prefetch_quantized
+        if self.features:
+            return 0
         ranked = []
         for seg in segments:
             if not codec_mod.use_quantized(seg):

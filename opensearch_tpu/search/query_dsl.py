@@ -14,7 +14,7 @@ import re
 from dataclasses import dataclass, field as dc_field
 from typing import Any, Optional
 
-from opensearch_tpu.common.errors import ParsingError
+from opensearch_tpu.common.errors import IllegalArgumentError, ParsingError
 
 
 @dataclass
@@ -191,6 +191,16 @@ class KnnQuery(Query):
     # per-request ANN overrides, e.g. {"nprobe": 16} (method_parameters
     # in the opensearch-knn request shape)
     method_parameters: Optional[dict] = None
+
+
+@dataclass
+class NeuralSparseQuery(Query):
+    """The neural-search plug-in's ``neural_sparse`` over a
+    ``rank_features`` field, in its ``query_tokens`` form (OpenSearch
+    2.14): the caller's own token weights, no model."""
+
+    field: str = ""
+    tokens: list = dc_field(default_factory=list)    # [(token, weight)]
 
 
 @dataclass
@@ -557,6 +567,45 @@ def _parse_knn(body):
                     filter=parse_query(v["filter"]) if v.get("filter") else None,
                     method_parameters=v.get("method_parameters"),
                     boost=_boost(v))
+
+
+_FLOAT32_MAX = 3.4028234663852886e38
+
+
+def _parse_neural_sparse(body):
+    if not isinstance(body, dict):
+        raise ParsingError("[neural_sparse] query malformed, expected an "
+                           "object keyed by field")
+    field, v = _field_kv(body, "neural_sparse")
+    if not isinstance(v, dict):
+        raise ParsingError(f"[neural_sparse] field [{field}] expects an "
+                           "object with [query_tokens]")
+    unknown = set(v) - {"query_tokens", "boost", "_name", "query_text",
+                        "model_id"}
+    if unknown:
+        raise ParsingError(
+            f"[neural_sparse] unknown parameter(s) {sorted(unknown)}")
+    for key in ("query_text", "model_id"):
+        if key in v:
+            raise IllegalArgumentError(
+                f"[neural_sparse] [{key}] needs a sparse encoding model, "
+                "and none is deployed in this system: encode the query "
+                "outside and send [query_tokens]")
+    tokens = v.get("query_tokens")
+    if not isinstance(tokens, dict) or not tokens:
+        raise IllegalArgumentError(
+            "[neural_sparse] requires a non-empty [query_tokens] object "
+            "of token -> weight")
+    pairs = []
+    for token, raw in tokens.items():
+        if isinstance(raw, bool) or not isinstance(raw, (int, float)) \
+                or not 0 < raw <= _FLOAT32_MAX:
+            raise IllegalArgumentError(
+                f"[neural_sparse] [query_tokens] weights must be positive "
+                f"finite numbers, got [{raw}] for token [{token}]")
+        pairs.append((str(token), float(raw)))
+    return NeuralSparseQuery(field=str(field), tokens=pairs,
+                             boost=_boost(v))
 
 
 def parse_geo_point(v) -> tuple[float, float]:
@@ -1167,6 +1216,7 @@ _PARSERS = {
     "constant_score": _parse_constant_score,
     "dis_max": _parse_dis_max,
     "knn": _parse_knn,
+    "neural_sparse": _parse_neural_sparse,
     "script_score": _parse_script_score,
     "hybrid": _parse_hybrid,
     "boosting": _parse_boosting,
