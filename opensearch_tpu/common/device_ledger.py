@@ -251,6 +251,7 @@ class DeviceResidencyLedger:
         self.host_fallbacks = 0
         self.slice_gather_programs = 0
         self.block_topk_programs = 0
+        self.sorted_bag_programs = 0
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
@@ -394,17 +395,23 @@ class DeviceResidencyLedger:
 
     def record_dispatch(self, group: Optional[_Group], *,
                         slice_gather: bool = False,
-                        block_topk: bool = False) -> None:
+                        block_topk: bool = False,
+                        sorted_bag: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
         signal budget eviction orders by.  ``slice_gather``: the
         program's static shape took ``gather_postings``'s contiguous-
         slice lowering (``ops/bm25.py::slice_lowering``, asked by the
         caller as the kernel asks it).  ``block_topk``: its top-k took
         the two stages of ``ops/topk.py`` (``block_size`` of the
-        program's ``n_pad`` and ``k``, asked the same way)."""
+        lanes of the program's key and ``k``, asked the same way).
+        ``sorted_bag``: a scored term bag's top-k came from its sorted
+        postings, without the dense accumulator (``Plan.sorted_topk``,
+        i.e. ``ops/bm25.py::sorted_bag``, over a segment with no
+        deleted doc)."""
         with self._lock:
             self.slice_gather_programs += bool(slice_gather)
             self.block_topk_programs += bool(block_topk)
+            self.sorted_bag_programs += bool(sorted_bag)
             if group is not None:
                 group.dispatches += 1
                 group.last_dispatch_tick = next(self._tick)
@@ -524,6 +531,7 @@ class DeviceResidencyLedger:
             rs, hf = self.restages, self.host_fallbacks
             slice_gathers = self.slice_gather_programs
             block_topks = self.block_topk_programs
+            sorted_bags = self.sorted_bag_programs
         per_index: dict[str, dict] = {}
         resident = 0
         dispatches = 0
@@ -542,6 +550,7 @@ class DeviceResidencyLedger:
             "dispatches": dispatches,
             "slice_gather_programs": slice_gathers,
             "block_topk_programs": block_topks,
+            "sorted_bag_programs": sorted_bags,
             "budget": {
                 "budget_bytes": budget or 0,
                 "evictions": ev,
@@ -616,6 +625,7 @@ class DeviceResidencyLedger:
             self.budget_bytes = None
             self.evictions = self.restages = self.host_fallbacks = 0
             self.slice_gather_programs = self.block_topk_programs = 0
+            self.sorted_bag_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
                 for key in t:

@@ -6,8 +6,24 @@ internal/ContextIndexSearcher.java:318).  On TPU the same work is a
 data-parallel program over the whole segment:
 
     CSR gather of the query terms' postings  ->  BM25 per posting
-    ->  scatter-add into a dense per-doc score vector  ->  exact top-k
+    ->  one score a doc  ->  exact top-k
         (``ops/topk.py``: block maxima, then the k winning blocks)
+
+There are two ways from the postings to one score a doc:
+
+- dense (``impact_scores`` and its siblings): scatter-add every lane of
+  the ``budget`` into a ``[n_pad]`` accumulator.  The TPU adds element
+  by element, about 9 ns a lane of ``budget`` whether it carries a
+  posting or lands on the dead slot, which was two thirds of a long
+  bag's device time.  Every caller that needs the whole vector takes it:
+  a bag under a ``bool``, aggregations and sorts (``run_full``), a
+  segment with deleted docs and its live mask.
+- sorted (``impact_topk_sorted``): where only the top-k is wanted, sort
+  the ``budget`` gathered lanes by doc id (about 1.1 ns a lane), add up
+  each doc's run of equal ids in slot order, and take the top-k over
+  the runs' last lanes.  No accumulator, no scatter, and every float32
+  sum keeps the scatter's bits.  ``sorted_bag`` says from the static
+  shape where it is taken.
 
 The gather (``gather_postings``) lays each term's postings run, one
 contiguous stretch of the staged columns, into a flat ``budget``-sized
@@ -43,6 +59,8 @@ import opensearch_tpu.common.jaxenv  # noqa: F401
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from opensearch_tpu.ops import topk as topk_ops
 
 K1_DEFAULT = 1.2
 B_DEFAULT = 0.75
@@ -292,6 +310,116 @@ def impact_score_count(offsets, doc_ids, impacts, term_ids, term_active,
     contrib = jnp.where(valid, weights[slot] * base, 0.0)
     scores = jnp.zeros(n_pad, jnp.float32).at[d].add(contrib)
     return scores, count
+
+
+_INT32_MAX = 2 ** 31 - 1
+
+
+# Which way a scored bag's top-k goes, from its static shape alone.
+# Measured on one v5e (PR 36, ``tools/bag_bench.py``, table in PERF.md
+# section 3), in us a bag from the gathered lanes to the top 10, dense
+# scatter-add / sort + fold, a quarter of the lanes carrying a posting
+# and all of them (the same within 6%):
+#   (t_pad 8, budget 4,096)           111 /    74
+#   (8, 16,384)                       227 /    87
+#   (8, 65,536)                       657 /   126
+#   (8 to 64, 262,144)              2,440 /   360
+#   (8 to 64, 1,048,576)            9,380 / 1,180
+#   (32 and 64, 4,194,304)         37,060 / 5,060
+# The sort wins at every bucket ``pad_bucket`` gives, so the rule has no
+# floor: it only says where the sorted lanes cannot stand for the
+# segment.  (The scatter declared ``unique_indices`` and
+# ``indices_are_sorted`` slot by slot, ROADMAP B3 (i), still adds lane
+# by lane: 7.4 to 9.4 ms at t_pad 8 and 1,048,576 lanes, 115 ms at 64.)
+def sorted_bag(t_pad: int, budget: int, n_pad: int, k: int) -> bool:
+    """True where a scored bag's top-k goes by ``impact_topk_sorted``
+    (a sort of the ``budget`` lanes by doc id) and not by the dense
+    accumulator.  The kernel's entry and the ``device.
+    sorted_bag_programs`` counter both ask here, so they cannot
+    disagree.  No where the top-k would want more lanes than the bag
+    has, and where ``doc * t_pad + slot`` would not fit the int32 sort
+    key below the dead lanes' ``INT32_MAX``."""
+    return k <= budget and n_pad * t_pad < 2 ** 31
+
+
+def impact_topk_sorted(offsets, doc_ids, impacts, term_ids, term_active,
+                       idfs, weights, required, min_score, *, n_pad: int,
+                       budget: int, k: int, fast: bool):
+    """(top_scores[k], top_local_ids[k], total_matched, max_score) of a
+    scored bag over a segment WITHOUT a deleted doc, bit for bit what
+    ``impact_scores`` / ``impact_score_count``, the match rule, the
+    ``min_score`` cut and ``topk_ops.topk_and_max`` over ``[n_pad]``
+    give, without the ``[n_pad]`` accumulator and its scatter-add.
+
+    The gathered lanes are sorted by ``doc * t_pad + slot`` (unique among
+    valid lanes, so the sort needs no stability; dead lanes carry
+    ``INT32_MAX`` and sort last): the lanes of one doc lie side by side
+    in slot order, at most one a slot.  ``p`` is a lane's place in its
+    run.  Pass ``j`` of the fold gives every lane at place ``j`` its left
+    neighbour's sum plus its own contribution, so a run's last lane ends
+    with ``((c0 + c1) + c2) + ...``: the order in which the scatter adds
+    and ``TermBagPlan.host_topk`` accumulates, hence the same float32.
+    The loop stops at the longest run, never past ``t_pad - 1`` passes.
+    A run's length is the doc's count of matched slots.  The key of the
+    top-k holds a doc's score at its run's last lane and ``-inf``
+    elsewhere; lanes are in doc order, so the lower lane of a tie is the
+    lower doc id, as over ``[n_pad]``.
+
+    A posting's doc id is below ``n_docs``, so with every doc live the
+    live mask is true on every valid lane and is not read; a segment
+    with a deleted doc keeps the dense path (its mask would cost an
+    element gather a lane)."""
+    t_pad = term_ids.shape[0]
+    assert sorted_bag(t_pad, budget, n_pad, k)
+    d, imp, slot, valid = gather_postings(
+        offsets, doc_ids, impacts, term_ids, term_active,
+        budget=budget, pad_doc=n_pad - 1)
+    base = idfs[slot] * imp
+    contrib = jnp.where(valid, weights[slot] * base, 0.0)
+    return sorted_lanes_topk(d, contrib, slot, valid, required, min_score,
+                             t_pad=t_pad, n_pad=n_pad, k=k, fast=fast)
+
+
+def sorted_lanes_topk(d, contrib, slot, valid, required, min_score, *,
+                      t_pad: int, n_pad: int, k: int, fast: bool):
+    """``impact_topk_sorted`` from the gathered lanes on: the sort by
+    ``(doc, slot)``, the fold of each doc's run in slot order, the match
+    rule at a run's last lane and the top-k over the lanes."""
+    assert t_pad & (t_pad - 1) == 0
+    shift = t_pad.bit_length() - 1
+    budget = d.shape[0]
+    key = jnp.where(valid, (d.astype(jnp.int32) << shift) | slot, _INT32_MAX)
+    key, contrib = lax.sort((key, contrib), num_keys=1, is_stable=False)
+    valid = key != _INT32_MAX
+    doc = key >> shift
+    i = jnp.arange(budget, dtype=jnp.int32)
+    edge = doc[1:] != doc[:-1]
+    first = jnp.concatenate([jnp.ones(1, bool), edge])
+    last = jnp.concatenate([edge, jnp.ones(1, bool)])
+    # a run's first lane lies inside the t_pad lanes that end at any of
+    # its lanes: a running maximum over that window, by doubling (a
+    # cummax of the whole array compiles for 27 s at 1,048,576 lanes)
+    start, step = jnp.where(first, i, 0), 1
+    while step < min(t_pad, budget):
+        start = jnp.maximum(start, jnp.concatenate(
+            [jnp.zeros(step, jnp.int32), start[:-step]]))
+        step *= 2
+    p = i - start
+
+    def fold(state):
+        j, s = state
+        left = jnp.concatenate([s[:1], s[:-1]])
+        return j + 1, jnp.where(p == j, left + contrib, s)
+
+    longest = jnp.max(jnp.where(valid, p, 0))
+    _, s = lax.while_loop(lambda state: state[0] <= longest, fold,
+                          (jnp.int32(1), contrib))
+    matched = (valid & last & (s > 0.0 if fast else p + 1 >= required)
+               & (s >= min_score))
+    vals, idx, mx = topk_ops.topk_and_max(
+        jnp.where(matched, s, -jnp.inf), k)
+    # an entry at -inf may name a dead lane: keep its id inside the segment
+    return vals, jnp.minimum(doc[idx], n_pad - 1), matched.sum(), mx
 
 
 def match_count(offsets, doc_ids, tfs, term_ids, term_active, *,

@@ -375,7 +375,9 @@ class MeshSearcher:
                     dims, ins = plan.prepare(bind, seg, dseg, shard.ctx)
                     kk = min(k, dseg.n_pad)
                     vals, idx, tot, _mx = planmod.run_topk_parts(
-                        plan, dims, kk, A, ins, ms)
+                        plan, dims, kk, A, ins, ms,
+                        sorted_bag=plan.sorted_topk(dims, dseg.n_pad, kk)
+                        and shard.ctx.all_live(seg))
                     if kk < k:                       # pad to common k
                         pad = k - kk
                         vals = jnp.concatenate(

@@ -56,11 +56,20 @@ class ShardContext:
         # point-in-time live-bitmap snapshot (apply_deletes replaces the
         # array, so this context keeps seeing the state at acquire time)
         self.lives = {id(s): s.live for s in segments}
+        self._all_live: dict[int, bool] = {}
         self._fstats: dict[str, FieldStats] = {}
         self._sorted_terms: dict[tuple[int, str], list[str]] = {}
 
     def live_jnp(self, seg, dseg):
         return dseg.live_jnp(self.lives[id(seg)])
+
+    def all_live(self, seg) -> bool:
+        """True where this snapshot of the segment has no deleted doc: a
+        program over it may leave the live mask unread."""
+        out = self._all_live.get(id(seg))
+        if out is None:
+            out = self._all_live[id(seg)] = bool(self.lives[id(seg)].all())
+        return out
 
     def field_type(self, field: str):
         return self.mapper.field_type(field)

@@ -1153,14 +1153,20 @@ class ShardSearcher:
                         dseg, dims, ins, A = self._segment_inputs(
                             plan, bind, seg, needed, ckey, prof)
                         k = min(k_want, dseg.n_pad)
-                        out = P.run_topk(plan, dims, k, A, ins, ms)
+                        sorts = (plan.sorted_topk(dims, dseg.n_pad, k)
+                                 and self.ctx.all_live(seg))
+                        out = P.run_topk(plan, dims, k, A, ins, ms,
+                                         sorted_bag=sorts)
                         # queued behind the program: phase 2 finds the
                         # result on the host instead of asking for it
                         out.copy_to_host_async()
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims),
-                            block_topk=topk_ops.block_size(dseg.n_pad, k))
+                            sorted_bag=sorts,
+                            # a sorted bag's key is its budget's lanes
+                            block_topk=topk_ops.block_size(
+                                dims[1] if sorts else dseg.n_pad, k))
                         if bag_postings is not None:
                             # what the 4^k bucket rule costs in lanes:
                             # postings gathered against lanes keyed

@@ -138,6 +138,18 @@ class Plan:
         slice_gather_programs``).  Composites ask their children."""
         return False
 
+    def sorted_topk(self, dims, n_pad: int, k: int) -> bool:
+        """True when ``run_topk`` can give this plan's top-k by
+        ``eval_topk``, with no dense ``[n_pad]`` score vector: a scored
+        term bag at the root, where ``bm25_ops.sorted_bag`` says so.
+        That path reads no live mask, so a caller (``ShardSearcher.
+        _topk``, the mesh) passes ``run_topk(sorted_bag=True)`` only for
+        a segment whose snapshot has no deleted doc (``ShardContext.
+        all_live``), and counts it (``_nodes/stats`` ``device.
+        sorted_bag_programs``); a segment with a deletion keeps the
+        dense path."""
+        return False
+
     def max_score_bound(self, bind, seg) -> float:
         """Safe UPPER bound on any single doc's score in this segment —
         the MaxScore/BMW pruning surface over the per-term block-max
@@ -406,6 +418,25 @@ class TermBagPlan(Plan):
     def slice_gathers(self, dims):
         # 4-tuple dims = quantized lowering: the bit-packed gather
         return len(dims) == 3 and bm25_ops.slice_lowering(dims[0], dims[1])
+
+    def sorted_topk(self, dims, n_pad, k):
+        # 4-tuple dims = quantized lowering: its own gather and scores
+        return (self.scored and len(dims) == 3
+                and bm25_ops.sorted_bag(dims[0], dims[1], n_pad, k))
+
+    def eval_topk(self, A, dims, k: int, ins, min_score):
+        """``_run_topk``'s four results for this bag at the root of a
+        plan, over a segment whose every doc is live (``sorted_topk``):
+        the same bits as ``eval`` + ``_key_topk``, by ``bm25_ops.
+        impact_topk_sorted``."""
+        p = A["postings"][self.field]
+        t_pad, budget, fast = dims
+        tids, active, idfs, weights, required = _unpack_term_inputs(
+            ins[0], t_pad)
+        return bm25_ops.impact_topk_sorted(  # engine-ok: TermBag scored lowering
+            p["offsets"], p["doc_ids"], ins[1], tids, active, idfs,
+            weights, required, min_score, n_pad=A["live"].shape[0],
+            budget=budget, k=k, fast=fast)
 
     def prefetch_quantized(self, bind, segments) -> int:
         """Prefetch oracle for the pager: rank candidate segments by
@@ -1903,26 +1934,34 @@ def unpack_topk(packed: np.ndarray):
             int(packed[2 * k]), float(packed[2 * k + 1:].view(np.float32)[0]))
 
 
-def _run_topk(plan: Plan, dims, k: int, A, ins, min_score):
+def _run_topk(plan: Plan, dims, k: int, A, ins, min_score, sorted_bag):
+    if sorted_bag:
+        # the caller saw no deleted doc: the sorted lanes read no live mask
+        return plan.eval_topk(A, dims, k, ins, min_score)
     scores, matched = plan.eval(A, dims, ins)
     matched = matched & A["live"] & (scores >= min_score)
     return _key_topk(jnp.where(matched, scores, -jnp.inf), k, matched)
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def run_topk(plan: Plan, dims, k: int, A, ins, min_score):
+@partial(jax.jit, static_argnums=(0, 1, 2), static_argnames=("sorted_bag",))
+def run_topk(plan: Plan, dims, k: int, A, ins, min_score, *,
+             sorted_bag: bool = False):
     """One segment's top-k, packed (``unpack_topk`` on the host gives
     (top_scores[k], top_local_ids[k], total_matched, max_score)).
     ``min_score`` (-inf when unset) excludes docs from hits AND total,
-    matching MinimumScoreCollector semantics."""
-    return _pack_topk(*_run_topk(plan, dims, k, A, ins, min_score))
+    matching MinimumScoreCollector semantics.  ``sorted_bag``: the
+    caller found ``plan.sorted_topk`` true and no deleted doc in its
+    snapshot of the segment; the same bits either way."""
+    return _pack_topk(*_run_topk(plan, dims, k, A, ins, min_score,
+                                 sorted_bag))
 
 
-@partial(jax.jit, static_argnums=(0, 1, 2))
-def run_topk_parts(plan: Plan, dims, k: int, A, ins, min_score):
+@partial(jax.jit, static_argnums=(0, 1, 2), static_argnames=("sorted_bag",))
+def run_topk_parts(plan: Plan, dims, k: int, A, ins, min_score, *,
+                   sorted_bag: bool = False):
     """``run_topk``'s four results as four device arrays, for a caller
     that goes on with them on the device (the mesh's shard-local merge)."""
-    return _run_topk(plan, dims, k, A, ins, min_score)
+    return _run_topk(plan, dims, k, A, ins, min_score, sorted_bag)
 
 
 @partial(jax.jit, static_argnums=(1,))

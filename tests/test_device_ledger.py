@@ -312,6 +312,38 @@ def test_a_narrow_segment_search_takes_no_block_topk(size):
     assert stats["block_topk_programs"] == 0
 
 
+@pytest.mark.parametrize("query,deleted,sorted_programs", [
+    ({"match": {"t": "alpha beta"}}, False, 2),
+    ({"match": {"t": "alpha beta"}}, True, 1),
+    ({"bool": {"must": [{"match": {"t": "alpha beta"}}]}}, False, 0),
+    ({"bool": {"must": [{"match": {"t": "alpha"}}],
+               "filter": [{"term": {"k": "g0"}}]}}, False, 0),
+    ({"match_all": {}}, False, 0)],
+    ids=["bag", "bag_one_segment_with_a_delete", "bool_of_one_bag",
+         "bag_under_a_bool", "match_all"])
+def test_sorted_bag_programs_counts_root_bags_of_live_segments(
+        query, deleted, sorted_programs):
+    """``record_dispatch(sorted_bag=)`` moves for a scored term bag at the
+    root of a plan over a segment without a deleted doc, and for
+    nothing else (a ``bool`` of one ``must`` is still a ``bool``);
+    ``reset`` zeroes it."""
+    s = _searcher(n_segs=2)
+    if deleted:
+        s.segments[0].delete_local(0)
+        s = ShardSearcher(s.segments, _mapper())
+    led = device_ledger()
+    resp = s.search({"query": query, "size": 3})
+    assert resp["hits"]["hits"]
+    stats = led.stats()
+    assert stats["dispatches"] == 2
+    assert stats["sorted_bag_programs"] == sorted_programs
+    led.record_dispatch(None, sorted_bag=True)
+    led.record_dispatch(None, block_topk=True, slice_gather=True)
+    assert led.stats()["sorted_bag_programs"] == sorted_programs + 1
+    led.reset()
+    assert led.stats()["sorted_bag_programs"] == 0
+
+
 # -- compile registry -------------------------------------------------------
 
 def test_compile_registry_counts_query_kernels():
@@ -443,6 +475,8 @@ def test_nodes_stats_device_section_and_budget_setting(node):
     assert dev["compile_registry"]["total"] >= 1
     assert dev["slice_gather_programs"] >= 1
     assert dev["block_topk_programs"] == 0      # a segment of 40 rows
+    # a scored bag at the root over segments with no deleted doc
+    assert dev["sorted_bag_programs"] == dev["dispatches"] >= 1
     # what jax actually runs on, so a node that came up on the wrong
     # backend says so from the client's side
     assert dev["backend"]["platform"] == "cpu"

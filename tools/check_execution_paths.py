@@ -11,8 +11,9 @@ paths grew in the first place.
 
 Therefore: any call of a scoring-kernel function —
 
-    impact_scores / impact_score_count / bm25_scores / bm25_score_count
-    / match_count (ops/bm25.py), batch_impact_union_topk
+    impact_scores / impact_score_count / impact_topk_sorted /
+    bm25_scores / bm25_score_count / match_count (ops/bm25.py),
+    batch_impact_union_topk
     (search/batch.py), or a plan's host_topk
 
 — anywhere under ``opensearch_tpu/`` must either live in
@@ -38,9 +39,9 @@ import sys
 ANNOTATION = "# engine-ok"
 
 KERNELS = frozenset({
-    "impact_scores", "impact_score_count", "bm25_scores",
-    "bm25_score_count", "match_count", "batch_impact_union_topk",
-    "host_topk",
+    "impact_scores", "impact_score_count", "impact_topk_sorted",
+    "bm25_scores", "bm25_score_count", "match_count",
+    "batch_impact_union_topk", "host_topk",
 })
 
 # modules allowed to touch kernels without annotation: the engine entry
