@@ -16,11 +16,30 @@ at the fidelity this engine needs:
 A span reads two clocks once, at its start: ``time.monotonic_ns`` (the
 clock durations come from, and the one a profiler session's markers are
 stamped with) and ``time.time_ns`` (the wall timestamp, for display).
+One opened with ``cpu=True`` may also read ``time.thread_time_ns`` at
+both ends (its thread's CPU time: duration less CPU is the time the
+thread did not run inside the span, waiting for the interpreter lock, a
+lock, a socket or the device).  That clock is a system call and not
+every span's to pay: 0.3 us a read on a plain Linux host, but 6 us on a
+sandboxed one, 18 to 35 us there while six threads are in it, all of it
+holding the interpreter lock, and such a host counts thread CPU in ticks
+of 10 ms.  So the call sites whose waiting somebody reads ask for it,
+one trace in eight is metered (``CPU_METERED``: by the trace id's last
+digit, so a request's spans meter together; the traces that start here
+take that digit in turn, so every eighth meters), and a name's totals count
+each metered span eight times (``CPU_WEIGHT``): sums over thousands of
+spans are sound where one span's reading is a tick or nothing (a span
+in which a tick lands takes its share of ``off_cpu_in_millis`` back).
+Named parts (``Span.part``) split a span's duration where the work
+happens, without more spans.
 Spans opened with ``start_span`` also enter a
 ``jax.profiler.TraceAnnotation``, so a profiler session shows them on
 host lines of the same trace as the device's operations.  Everything
 stays always-on: a span is one small object appended to a ring, and its
-dict is only built when somebody reads the ring.
+dict is only built when somebody reads the ring.  The ring forgets; the
+per-name totals a span is folded into where it ends (``Tracer.totals``)
+do not, so a reader of ``_nodes/stats`` ``telemetry.spans`` sees every
+span that ever ended, whatever the ring still holds.
 """
 
 from __future__ import annotations
@@ -28,12 +47,23 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import gc
+import itertools
 import threading
 import time
 from bisect import bisect_left
 from collections import deque
 from random import getrandbits
+from threading import get_ident
+from time import monotonic_ns, thread_time_ns
 from typing import Optional
+
+# last hex digit of the trace ids whose ``cpu=True`` spans read the thread
+# CPU clock: one trace in eight, so a metered span counts for eight.  A
+# trace that starts here takes that digit in turn (``Tracer.begin_span``),
+# so it is every eighth and not one in eight by luck; an id that came
+# with the request (``traceparent``) is taken as it is.
+CPU_METERED = "08"
+CPU_WEIGHT = 16 // len(CPU_METERED)
 
 _current_span: "contextvars.ContextVar[Optional[Span]]" = \
     contextvars.ContextVar("opensearch_tpu_span", default=None)
@@ -79,27 +109,59 @@ class SpanContext:
         return SpanContext(parts[1], parts[2])
 
 
+class _Part:
+    """``with span.part(name)``: the block's wall time, added to the
+    span's part of that name."""
+
+    __slots__ = ("span", "name", "t0")
+
+    def __init__(self, span: "Span", name: str):
+        self.span = span
+        self.name = name
+
+    def __enter__(self) -> None:
+        self.t0 = monotonic_ns()
+
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        self.span.add_part(self.name, monotonic_ns() - self.t0)
+        return False
+
+
 class Span:
     """One timed operation.  ``end()`` freezes the duration and ships the
     span to the tracer's in-memory exporter."""
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_span_id",
                  "attributes", "start_nanos", "start_wall_nanos",
-                 "duration_nanos", "error")
+                 "duration_nanos", "error", "cpu_nanos", "parts",
+                 "_thread", "_cpu0")
 
     def __init__(self, tracer: "Tracer", name: str, trace_id: str,
                  parent_span_id: Optional[str],
-                 attributes: Optional[dict] = None):
+                 attributes: Optional[dict] = None, cpu: bool = False):
         self.tracer = tracer
         self.name = name
         self.trace_id = trace_id
         self.span_id = f"{getrandbits(64):016x}"
         self.parent_span_id = parent_span_id
         self.attributes: dict = dict(attributes) if attributes else {}
-        self.start_nanos = time.monotonic_ns()
-        self.start_wall_nanos = time.time_ns()  # wall-clock: display timestamp
         self.duration_nanos: Optional[int] = None
         self.error: Optional[str] = None
+        # the thread's CPU time inside the span; None where it was not
+        # asked for, the trace is not one that meters, or the span was
+        # ended on another thread than the one that opened it
+        self.cpu_nanos: Optional[int] = None
+        self.parts: Optional[dict] = None       # name -> nanos
+        self.start_wall_nanos = time.time_ns()  # wall-clock: display timestamp
+        self.start_nanos = monotonic_ns()
+        # the CPU reads lie inside the monotonic ones, here and in end():
+        # an exact clock never reads more CPU than the duration (one that
+        # counts in ticks may, for one span)
+        if cpu and trace_id[-1] in CPU_METERED:
+            self._thread = get_ident()
+            self._cpu0 = thread_time_ns()
+        else:
+            self._thread = None
 
     def context(self) -> SpanContext:
         return SpanContext(self.trace_id, self.span_id)
@@ -111,10 +173,28 @@ class Span:
     def record_error(self, err) -> None:
         self.error = f"{type(err).__name__}: {err}"
 
+    def add_part(self, name: str, nanos: int) -> None:
+        """Add ``nanos`` of the span's wall time to its part ``name``
+        (two clock reads at the caller and a dict add: no object in the
+        ring).  Called on the span's own thread."""
+        parts = self.parts
+        if parts is None:
+            self.parts = {name: nanos}
+        else:
+            parts[name] = parts.get(name, 0) + nanos
+
+    def part(self, name: str) -> _Part:
+        return _Part(self, name)
+
     def end(self) -> None:
         if self.duration_nanos is not None:
             return                       # idempotent
-        self.duration_nanos = time.monotonic_ns() - self.start_nanos
+        if self._thread is not None and self._thread == get_ident():
+            cpu = thread_time_ns() - self._cpu0
+            self.duration_nanos = monotonic_ns() - self.start_nanos
+            self.cpu_nanos = cpu
+        else:
+            self.duration_nanos = monotonic_ns() - self.start_nanos
         self.tracer._export(self)
 
     def to_dict(self) -> dict:
@@ -128,8 +208,61 @@ class Span:
                "start_time_in_nanos": self.start_nanos,
                "duration_in_nanos": self.duration_nanos,
                "attributes": dict(self.attributes)}
+        if self.cpu_nanos is not None:
+            out["cpu_in_nanos"] = self.cpu_nanos
+        if self.parts:
+            out["parts"] = dict(self.parts)
         if self.error is not None:
             out["error"] = self.error
+        return out
+
+
+class _SpanTotals:
+    """What every finished span of one name adds up to.  Spans end on
+    every request thread, so each name has a lock of its own.  A span
+    that metered its CPU stands for ``CPU_WEIGHT`` spans of its name: its
+    CPU time and the rest of its duration are added that many times,
+    where it is folded, so ``cpu_in_millis`` and ``off_cpu_in_millis``
+    are sums like the others (an estimate from one trace in eight, and
+    no share taken at read time: a delta over a window holds that
+    window's spans only)."""
+
+    __slots__ = ("_lock", "count", "nanos", "metered", "cpu_nanos",
+                 "off_nanos", "parts")
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.count = 0
+        self.nanos = 0
+        self.metered = 0             # the spans that metered their CPU,
+        self.cpu_nanos = 0           # their CPU time, weighted
+        self.off_nanos = 0           # and the rest of their wall time
+        self.parts: dict = {}
+
+    def add(self, span: Span) -> None:
+        duration, cpu, parts = span.duration_nanos, span.cpu_nanos, span.parts
+        with self._lock:
+            self.count += 1
+            self.nanos += duration
+            if cpu is not None:
+                self.metered += 1
+                self.cpu_nanos += CPU_WEIGHT * cpu
+                self.off_nanos += CPU_WEIGHT * (duration - cpu)
+            if parts is not None:
+                mine = self.parts
+                for name, nanos in parts.items():
+                    mine[name] = mine.get(name, 0) + nanos
+
+    def stats(self) -> dict:
+        with self._lock:
+            out = {"count": self.count, "time_in_millis": self.nanos / 1e6,
+                   "metered_count": self.metered,
+                   "cpu_in_millis": self.cpu_nanos / 1e6,
+                   "off_cpu_in_millis": self.off_nanos / 1e6}
+            parts = dict(self.parts)
+        if parts:
+            out["parts"] = {name: {"time_in_millis": n / 1e6}
+                            for name, n in sorted(parts.items())}
         return out
 
 
@@ -172,25 +305,38 @@ class Tracer:
 
     def __init__(self, max_spans: int = 8192):
         # appended to by every thread that ends a span: a deque's append
-        # is thread-safe, so ending a span takes no lock
+        # is thread-safe, so the ring takes no lock
         self._finished: "deque[Span]" = deque(maxlen=max_spans)
+        # name -> _SpanTotals (a lock a name: no lock is shared between
+        # names); names are code-level literals, a bounded set
+        self._totals: dict = {}
+        self._totals_lock = threading.Lock()    # guards creation only
+        # the traces started here, counted: the low four bits are a new
+        # trace id's last digit (next() of a count is atomic)
+        self._traces = itertools.count()
 
     # -- span lifecycle ---------------------------------------------------
 
     def begin_span(self, name: str, attributes: Optional[dict] = None,
-                   parent: "SpanContext | Span | None" = None) -> Span:
+                   parent: "SpanContext | Span | None" = None,
+                   cpu: bool = False) -> Span:
         """Non-context-manager start (callers that end() across scopes
-        or threads; not mirrored into the profiler's trace)."""
+        or threads; not mirrored into the profiler's trace).  ``cpu``:
+        meter the thread's CPU time beside the wall time where the trace
+        is one of ``CPU_METERED`` (two system calls a span; no reading
+        where it ends on another thread)."""
         if parent is None:
             parent = _current_span.get()
         if parent is None:
-            return Span(self, name, f"{getrandbits(128):032x}", None,
-                        attributes)
-        return Span(self, name, parent.trace_id, parent.span_id, attributes)
+            trace_id = f"{getrandbits(124):031x}{next(self._traces) & 15:x}"
+            return Span(self, name, trace_id, None, attributes, cpu)
+        return Span(self, name, parent.trace_id, parent.span_id, attributes,
+                    cpu)
 
     def start_span(self, name: str, attributes: Optional[dict] = None,
-                   parent: "SpanContext | Span | None" = None) -> _SpanScope:
-        return _SpanScope(self.begin_span(name, attributes, parent))
+                   parent: "SpanContext | Span | None" = None,
+                   cpu: bool = False) -> _SpanScope:
+        return _SpanScope(self.begin_span(name, attributes, parent, cpu))
 
     @staticmethod
     def current() -> Optional[Span]:
@@ -198,6 +344,11 @@ class Tracer:
 
     def _export(self, span: Span) -> None:
         self._finished.append(span)
+        totals = self._totals.get(span.name)
+        if totals is None:
+            with self._totals_lock:
+                totals = self._totals.setdefault(span.name, _SpanTotals())
+        totals.add(span)
 
     # -- context propagation (TracingContextPropagator analog) ------------
 
@@ -238,8 +389,73 @@ class Tracer:
             spans = [s for s in spans if s.trace_id == trace_id]
         return [s.to_dict() for s in spans[: max(0, int(limit))]]
 
+    def totals(self) -> dict:
+        """{name: count, time, CPU, off-CPU and parts} over every span
+        that ended since the last ``reset``, in the ring or not."""
+        with self._totals_lock:
+            names = sorted(self._totals.items())
+        return {name: t.stats() for name, t in names}
+
+    def stats(self) -> dict:
+        """``finished``: spans ended since the last ``reset``; ``ring``:
+        how many of them the ring can hold.  A reader whose window ended
+        more than ``ring`` spans ago finds its oldest spans gone."""
+        with self._totals_lock:
+            totals = list(self._totals.values())
+        return {"finished": sum(t.count for t in totals),
+                "ring": self._finished.maxlen}
+
+    def prometheus_text(self) -> str:
+        """The totals in the Prometheus text format, the span's name (a
+        code-level literal) and the part as labels: the data
+        ``_nodes/stats`` ``telemetry.spans`` / ``telemetry.tracer``
+        report as JSON."""
+        def series(metric: str, kind: str, doc: str, rows: list) -> list:
+            return [f"# HELP {metric} {doc}", f"# TYPE {metric} {kind}",
+                    *(f"{metric}{labels} {value:.10g}"
+                      for labels, value in rows)]
+
+        totals = self.totals()
+        by_name = [(f'{{span="{name}"}}', t)  # label-ok: span names are code-level literals
+                   for name, t in totals.items()]
+        lines = []
+        for key, metric, kind, doc in (
+                ("count", "telemetry_spans_total", "counter",
+                 "Finished spans"),
+                ("time_in_millis", "telemetry_span_time_ms_total", "counter",
+                 "Wall time inside finished spans (milliseconds)"),
+                ("metered_count", "telemetry_spans_metered_total", "counter",
+                 "Finished spans that metered their thread's CPU time (one "
+                 f"trace in {CPU_WEIGHT})"),
+                ("cpu_in_millis", "telemetry_span_cpu_ms_total", "counter",
+                 f"Thread CPU time inside the metered spans, x {CPU_WEIGHT} "
+                 "(milliseconds)"),
+                # a sum, yet no counter: the CPU clock may count in ticks,
+                # and a span in which one lands takes its share back
+                ("off_cpu_in_millis", "telemetry_span_off_cpu_ms", "gauge",
+                 "Wall time less thread CPU time inside the metered spans, "
+                 f"x {CPU_WEIGHT} (milliseconds)")):
+            lines += series(metric, kind, doc,
+                            [(labels, t[key]) for labels, t in by_name])
+        lines += series(
+            "telemetry_span_part_time_ms_total", "counter",
+            "Wall time inside a named part of finished spans (milliseconds)",
+            [(f'{{span="{name}",part="{part}"}}', p["time_in_millis"])  # label-ok: span and part names are code-level literals
+             for name, t in totals.items()
+             for part, p in t.get("parts", {}).items()])
+        own = self.stats()
+        lines += series("telemetry_tracer_finished_total", "counter",
+                        "Spans ended since the tracer was reset",
+                        [("", own["finished"])])
+        lines += series("telemetry_tracer_ring", "gauge",
+                        "Finished spans the ring can hold",
+                        [("", own["ring"])])
+        return "\n".join(lines) + "\n"
+
     def reset(self) -> None:
         self._finished.clear()
+        with self._totals_lock:
+            self._totals.clear()
 
 
 # default latency buckets in milliseconds (upper bounds; +inf implied) —

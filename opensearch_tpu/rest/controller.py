@@ -236,11 +236,19 @@ class RestController:
         passes so error mappings can attach headers (Retry-After on
         backpressure rejections) without changing the return shape."""
         import contextlib
+        from time import monotonic_ns
 
         from opensearch_tpu.common import tasks as taskmod
         from opensearch_tpu.common.telemetry import metrics, tracer
         from opensearch_tpu.common.threadpool import RejectedExecutionError
 
+        # under the HTTP front end the edge's own work is split on its
+        # span: ``route`` from here to the rest: span, ``after`` from
+        # that span's end to the return
+        t_entry = monotonic_ns()
+        http_span = tracer().current()
+        if http_span is not None and http_span.name != "http.request":
+            http_span = None
         headers = headers or {}
         # request attribution: X-Opaque-Id threads into the task and all
         # downstream transport requests (Task.java HEADERS_TO_COPY)
@@ -311,12 +319,13 @@ class RestController:
                     # called directly, the root that honours it
                     parent = (None if tracer().current() is not None
                               else tracer().extract(headers))
+                    rest_span = None    # set once admitted
                     try:
                         with admission, tracer().start_span(
                                 f"rest:{action}", attributes=attrs,
                                 parent=parent) as span, \
-                                metrics().time_ms("rest.request_ms"), \
                                 insights.collecting() as sink:
+                            rest_span = span
                             metrics().counter("rest.requests").inc()
                             status, resp = route.handler(req)
                             span.set_attribute("http.status", status)
@@ -348,6 +357,17 @@ class RestController:
                             metrics().counter("search.cpu_micros").inc(
                                 task.cpu_time_nanos // 1000)
                         self.node.task_manager.unregister(task)
+                        if rest_span is not None:
+                            # the span has ended: its duration is the
+                            # histogram's sample, its edges the parts'
+                            nanos = rest_span.duration_nanos
+                            metrics().histogram("rest.request_ms").observe(
+                                nanos / 1e6)
+                            if http_span is not None:
+                                start = rest_span.start_nanos
+                                http_span.add_part("route", start - t_entry)
+                                http_span.add_part(
+                                    "after", monotonic_ns() - start - nanos)
             # method-mismatch vs not-found distinction
             if any(r.rx.match(path.rstrip("/") or "/") for r in self.routes):
                 return 405, {"error": f"Incorrect HTTP method for uri [{path}]"
@@ -732,7 +752,8 @@ class RestController:
 
     def h_nodes_stats(self, req):
         from opensearch_tpu.common.breakers import breaker_service
-        from opensearch_tpu.common.telemetry import gc_timer, metrics
+        from opensearch_tpu.common.telemetry import (gc_timer, metrics,
+                                                     tracer)
         from opensearch_tpu.indices.request_cache import request_cache
         # probe on read: stats reflect CURRENT disk health, not boot-time
         self.node.fs_health.check()
@@ -818,8 +839,14 @@ class RestController:
                 # time they stopped every thread for (cumulative)
                 "runtime": {"gc": gc_timer().stats()},
                 # counters + latency histograms with p50/p90/p99 readout
-                # (the telemetry SPI's MetricsRegistry surface)
-                "telemetry": metrics().stats(),
+                # (the telemetry SPI's MetricsRegistry surface), and the
+                # tracer's totals: what every finished span of a name
+                # adds up to (wall, thread CPU, off-CPU, parts), kept
+                # where a span ends and so whole whatever the ring of
+                # GET /_nodes/trace still holds
+                "telemetry": {**metrics().stats(),
+                              "spans": tracer().totals(),
+                              "tracer": tracer().stats()},
             }}}
 
     def _recovery_stats(self) -> dict:
@@ -903,10 +930,15 @@ class RestController:
         from opensearch_tpu.common.telemetry import tracer
         limit = int(req.param("size", 100))
         spans = tracer().recent(limit, trace_id=req.param("trace_id"))
+        out = {"name": self.node.name, **tracer().stats(), "spans": spans}
+        if spans:
+            # monotonic, like every span's start_time_in_nanos: a window
+            # that began before it lies (in part) outside what was read,
+            # which is not the same as a window in which nothing ran
+            out["oldest_start_time_in_nanos"] = min(
+                s["start_time_in_nanos"] for s in spans)
         return 200, {"cluster_name": self.node.cluster_name,
-                     "nodes": {self.node.node_id: {
-                         "name": self.node.name,
-                         "spans": spans}}}
+                     "nodes": {self.node.node_id: out}}
 
     def h_metrics(self, req):
         """Prometheus text exposition of the full MetricsRegistry —
@@ -916,8 +948,8 @@ class RestController:
         LABEL drawn from the bounded top-N path, never a metric name).
         The same underlying data ``_nodes/stats`` serves as JSON."""
         from opensearch_tpu.common.device_ledger import device_ledger
-        from opensearch_tpu.common.telemetry import metrics
-        text = metrics().prometheus_text()
+        from opensearch_tpu.common.telemetry import metrics, tracer
+        text = metrics().prometheus_text() + tracer().prometheus_text()
         insights = getattr(self.node, "insights", None)
         if insights is not None:
             text += insights.prometheus_text()

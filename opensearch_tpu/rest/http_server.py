@@ -48,8 +48,10 @@ class _Handler(BaseHTTPRequestHandler):
         # the thread hand-off: from accept's return on the serving thread
         # to this handler thread running
         accepted = self.server.accepted_ns.pop(self.client_address, None)
-        self._accept_wait_ns = (None if accepted is None
-                                else time.monotonic_ns() - accepted)
+        # from here this thread waits for the request line and parses
+        # the headers (http.server), before any span is open
+        self._waiting_ns = now = time.monotonic_ns()
+        self._accept_wait_ns = None if accepted is None else now - accepted
         super().setup()
 
     def _handle(self):
@@ -58,21 +60,29 @@ class _Handler(BaseHTTPRequestHandler):
         if wait_ns is not None:
             # the connection's first request only: the time between the
             # requests of a kept-alive connection is the client's
+            head_ns = time.monotonic_ns() - self._waiting_ns
             attrs["accept_wait_ns"] = wait_ns
+            attrs["head_read_ns"] = head_ns
             metrics().histogram("rest.accept_wait_ms").observe(
                 wait_ns / 1e6)
+            metrics().histogram("rest.head_read_ms").observe(head_ns / 1e6)
+        # cpu: duration less CPU is all the time this request's thread
+        # did not run, from the body read to the socket write
         with tracer().start_span(
                 "http.request", attrs,
                 parent=SpanContext.from_traceparent(
-                    self.headers.get(TRACEPARENT))) as span:
-            span.set_attribute("http.status", self._respond())
+                    self.headers.get(TRACEPARENT)), cpu=True) as span:
+            span.set_attribute("http.status", self._respond(span))
 
-    def _respond(self) -> int:
+    def _respond(self, span) -> int:
         """Body read, dispatch, serialisation and socket write; returns
-        the status sent."""
+        the status sent.  ``span`` (``http.request``) gets the parts
+        ``read`` and ``respond`` here, ``route`` and ``after`` in
+        ``RestController.dispatch``."""
         from opensearch_tpu.common.breakers import (CircuitBreakingError,
                                                     breaker_service)
 
+        t_read = time.monotonic_ns()
         split = urlsplit(self.path)
         params = dict(parse_qsl(split.query, keep_blank_values=True))
         length = int(self.headers.get("Content-Length") or 0)
@@ -103,6 +113,7 @@ class _Handler(BaseHTTPRequestHandler):
         else:
             try:
                 body = self.rfile.read(length) if length else b""
+                span.add_part("read", time.monotonic_ns() - t_read)
                 status, payload = self.server.controller.dispatch(
                     self.command, split.path, params, body,
                     self.headers.get("Content-Type") or "",
@@ -111,6 +122,7 @@ class _Handler(BaseHTTPRequestHandler):
                     response_headers=extra_headers)
             finally:
                 breaker.release(length)
+        t_respond = time.monotonic_ns()
         from opensearch_tpu.rest.controller import PlainText
         is_cat = split.path.startswith("/_cat") and params.get("format") != "json"
         if isinstance(payload, PlainText):
@@ -155,6 +167,7 @@ class _Handler(BaseHTTPRequestHandler):
         self.end_headers()
         if self.command != "HEAD":
             self.wfile.write(data)
+        span.add_part("respond", time.monotonic_ns() - t_respond)
         return status
 
     do_GET = do_POST = do_PUT = do_DELETE = do_HEAD = _handle

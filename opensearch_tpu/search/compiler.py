@@ -837,7 +837,8 @@ def _knn_filter_masks(q, ctx) -> dict:
     """{seg_order: bool[n_pad]}: a filtered ``knn``'s filter as one mask
     program (``plan.run_full``) a segment that holds the vector field,
     all dispatched before the first scan; nothing is read back.  Span
-    ``knn.filter`` covers the filter's compile and the dispatches."""
+    ``knn.filter`` covers the filter's compile and the dispatches, its
+    part ``launch`` the calls of the mask programs."""
     from opensearch_tpu.common.device_ledger import device_ledger
     from opensearch_tpu.common.telemetry import metrics, tracer
     from opensearch_tpu.search.executor import build_arrays
@@ -856,8 +857,9 @@ def _knn_filter_masks(q, ctx) -> dict:
                 continue
             A = build_arrays(dseg, fplan.arrays(), ctx.mapper)
             dims, ins = fplan.prepare(fbind, seg, dseg, ctx)
-            _s, masks[seg_order] = P.run_full(fplan, dims, A, ins,
-                                              min_score)
+            with span.part("launch"):
+                _s, masks[seg_order] = P.run_full(fplan, dims, A, ins,
+                                                  min_score)
             ledger.record_dispatch(getattr(dseg, "_ledger_group", None),
                                    slice_gather=fplan.slice_gathers(dims))
         span.set_attribute("segments", len(masks))
@@ -919,7 +921,7 @@ def _c_knn(q, ctx, scored):
     # phase 1: dispatch every segment's device program, keep DEVICE arrays
     # (a filter's mask programs went first, all of them)
     pending = []             # (seg_order, vals_dev, idx_dev)
-    with scan_span:
+    with scan_span as span:        # None without a filter: no knn.scan
         for seg_order, seg in enumerate(ctx.segments):
             dseg = seg.device()
             vcol = dseg.vector.get(q.field)
@@ -932,6 +934,7 @@ def _c_knn(q, ctx, scored):
             kk = min(q.k, dseg.n_pad)
             ann = (seg.ann_index(q.field, method)
                    if use_ann and q.filter is None else None)
+            t_launch = time.monotonic_ns()
             if ann is not None:
                 nprobe = min(int(method.get("nprobe", 0))
                              or max(1, ann.nlist // 8), ann.nlist)
@@ -957,6 +960,9 @@ def _c_knn(q, ctx, scored):
             # a segment find their arrays on the host
             vals.copy_to_host_async()
             idx.copy_to_host_async()
+            if span is not None:
+                # the scan's call and the start of its copies
+                span.add_part("launch", time.monotonic_ns() - t_launch)
             pending.append((seg_order, vals, idx))
             ledger.record_dispatch(
                 getattr(dseg, "_ledger_group", None),
@@ -966,7 +972,8 @@ def _c_knn(q, ctx, scored):
     if pending:
         t_sync = time.monotonic()
         fetched_bytes = 0
-        with tracer().start_span("device.sync", {"site": "knn_prepass"}):
+        with tracer().start_span("device.sync", {"site": "knn_prepass"},
+                                 cpu=True):
             for seg_order, vals, idx in pending:
                 vals, idx = np.asarray(vals), np.asarray(idx)
                 fetched_bytes += vals.nbytes + idx.nbytes

@@ -384,51 +384,58 @@ class ShardSearcher:
     def _segment_inputs(self, plan, bind, seg, needed, ckey, prof):
         """(dseg, dims, ins, A): everything one segment's program takes,
         under a ``segment.prepare`` span — the host work and the H2D
-        before a launch."""
-        with _tracer().start_span("segment.prepare",
-                                  {"prepared": "miss"}) as span:
-            dseg = seg.device()
+        before a launch.  The span's parts: ``device`` (``seg.device()``),
+        ``cache_get`` / ``bind`` / ``cache_put`` (``_prepared``) and
+        ``arrays`` (the live mask and ``build_arrays``)."""
+        with _tracer().start_span("segment.prepare", {"prepared": "miss"},
+                                  cpu=True) as span:
+            with span.part("device"):
+                dseg = seg.device()
             # prepare FIRST: dims tells build_arrays which array groups
             # the lowering left deliberately partial (quantized segments)
-            dims, ins = self._prepared(plan, bind, seg, dseg, ckey,
-                                       prof=prof, span=span)
-            A = build_arrays(dseg, needed, self.mapper,
-                             live=self.ctx.live_jnp(seg, dseg),
-                             partial_ok=plan.skip_arrays(dims))
+            dims, ins = self._prepared(plan, bind, seg, dseg, ckey, prof,
+                                       span)
+            with span.part("arrays"):
+                A = build_arrays(dseg, needed, self.mapper,
+                                 live=self.ctx.live_jnp(seg, dseg),
+                                 partial_ok=plan.skip_arrays(dims))
         return dseg, dims, ins, A
 
-    def _prepared(self, plan, bind, seg, dseg, ckey, prof=None, span=None):
+    def _prepared(self, plan, bind, seg, dseg, ckey, prof, span):
         """``plan.prepare``'s per-(plan, segment) static products —
         padded term ids, staged impact references, device scalars —
         cached so a repeated query shape does zero host-side prepare
         work (and zero H2D transfers) per segment.  ``prof`` records
         prepare time and the per-segment prepared-bindings hit/miss;
-        a hit is also written on ``span`` (its ``prepared`` attribute)."""
-        if ckey is None:
-            if prof is None:
-                return plan.prepare(bind, seg, dseg, self.ctx)
-            prof.inc("prepared_misses")
-            with prof.phase("prepare"):
-                return plan.prepare(bind, seg, dseg, self.ctx)
-        from opensearch_tpu.common.cache import attached_cache
-
-        cache = attached_cache(self, "_prep_cache",
-                               name="search.prepare",
-                               max_weight=64 << 20, breaker="fielddata",
-                               weigher=self._prep_weight)
-        key = (ckey, id(seg))
-        out = cache.get(key)
-        if out is None:
-            if prof is not None:
+        a hit is also written on ``span`` (``segment.prepare``: its
+        ``prepared`` attribute), and so are the parts ``cache_get``,
+        ``bind`` (``plan.prepare``: padding and the H2D ``stage_input``)
+        and ``cache_put`` (weigher, lock, eviction)."""
+        def prepare():
+            with span.part("bind"):
+                if prof is None:
+                    return plan.prepare(bind, seg, dseg, self.ctx)
                 prof.inc("prepared_misses")
                 with prof.phase("prepare"):
-                    out = plan.prepare(bind, seg, dseg, self.ctx)
-            else:
-                out = plan.prepare(bind, seg, dseg, self.ctx)
-            cache.put(key, out)
+                    return plan.prepare(bind, seg, dseg, self.ctx)
+
+        if ckey is None:
+            return prepare()
+        from opensearch_tpu.common.cache import attached_cache
+
+        with span.part("cache_get"):
+            cache = attached_cache(self, "_prep_cache",
+                                   name="search.prepare",
+                                   max_weight=64 << 20, breaker="fielddata",
+                                   weigher=self._prep_weight)
+            key = (ckey, id(seg))
+            out = cache.get(key)
+        if out is None:
+            out = prepare()
+            with span.part("cache_put"):
+                cache.put(key, out)
         else:
-            if span is not None:
-                span.set_attribute("prepared", "hit")
+            span.set_attribute("prepared", "hit")
             if prof is not None:
                 prof.inc("prepared_hits")
         return out
@@ -606,11 +613,7 @@ class ShardSearcher:
                 aggregations = execu.run(aggs_json, seg_views)
 
         t_fetch = time.monotonic() if prof is not None else 0.0
-        with _tracer().start_span("fetch_phase",
-                                  {"index": self.index_name,
-                                   "hits": len(rows)}), \
-                _metrics().time_ms("search.fetch_ms"):
-            hits = self._hits_from_rows(rows, source_spec, fetch_extras)
+        hits = self._fetch(rows, source_spec, fetch_extras)
         if prof is not None:
             prof.add("fetch", time.monotonic() - t_fetch)
 
@@ -732,12 +735,7 @@ class ShardSearcher:
         _metrics().counter("search.hybrid.candidates").inc(n_union)
         rows = combined[from_: from_ + size]
         t_fetch = time.monotonic()
-        with _tracer().start_span("fetch_phase",
-                                  {"index": self.index_name,
-                                   "hits": len(rows)}), \
-                _metrics().time_ms("search.fetch_ms"):
-            hits = self._hits_from_rows(rows, body.get("_source"),
-                                        fetch_extras)
+        hits = self._fetch(rows, body.get("_source"), fetch_extras)
         t_done = time.monotonic()
         insights.emit(
             signature=insights.canonical_query(body.get("query")),
@@ -874,6 +872,19 @@ class ShardSearcher:
                 results[pos] = self.search(bodies[pos])
         return results
 
+    def _fetch(self, rows, source_spec, fetch_extras):
+        """The fetch phase under its ``fetch_phase`` span, whose own
+        duration is histogram ``search.fetch_ms``'s sample."""
+        scope = _tracer().start_span("fetch_phase",
+                                     {"index": self.index_name,
+                                      "hits": len(rows)})
+        try:
+            with scope:
+                return self._hits_from_rows(rows, source_spec, fetch_extras)
+        finally:
+            _metrics().histogram("search.fetch_ms").observe(
+                scope.span.duration_nanos / 1e6)
+
     def _hits_from_rows(self, rows, source_spec, fetch_extras=None):
         from opensearch_tpu.search.fetch import (docvalue_fields,
                                                  explain_hit,
@@ -997,11 +1008,13 @@ class ShardSearcher:
             with _tracer().start_span(
                     "segment.dispatch",
                     {"segment": seg.seg_id, "index": self.index_name,
-                     "shard": self.shard_id}):
+                     "shard": self.shard_id}) as span:
                 try:
                     dseg, dims, ins, A = self._segment_inputs(
                         plan, bind, seg, needed, ckey, prof)
-                    scores, matched = P.run_full(plan, dims, A, ins, ms)
+                    with span.part("launch"):
+                        scores, matched = P.run_full(plan, dims, A, ins,
+                                                     ms)
                 except Exception as exc:
                     if not is_device_error(exc):
                         raise
@@ -1138,7 +1151,7 @@ class ShardSearcher:
             with _tracer().start_span(
                     "segment.dispatch",
                     {"segment": seg.seg_id, "index": self.index_name,
-                     "shard": self.shard_id}):
+                     "shard": self.shard_id}) as span:
                 device_ok = (health.allow("dispatch")
                              and health.allow("staging"))
                 if not device_ok or (getattr(seg, "_device_evicted", False)
@@ -1155,11 +1168,15 @@ class ShardSearcher:
                         k = min(k_want, dseg.n_pad)
                         sorts = (plan.sorted_topk(dims, dseg.n_pad, k)
                                  and self.ctx.all_live(seg))
-                        out = P.run_topk(plan, dims, k, A, ins, ms,
-                                         sorted_bag=sorts)
-                        # queued behind the program: phase 2 finds the
-                        # result on the host instead of asking for it
-                        out.copy_to_host_async()
+                        # the part: the jitted program's call, to its
+                        # return, and the start of the result's copy
+                        with span.part("launch"):
+                            out = P.run_topk(plan, dims, k, A, ins, ms,
+                                             sorted_bag=sorts)
+                            # queued behind the program: phase 2 finds
+                            # the result on the host instead of asking
+                            # for it
+                            out.copy_to_host_async()
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims),
@@ -1208,7 +1225,8 @@ class ShardSearcher:
         fetched_bytes = fetched_arrays = 0
         # the span only where a device result is read back: a recovered
         # segment left numpy arrays
-        with (_tracer().start_span("device.sync", {"site": "topk"})
+        with (_tracer().start_span("device.sync", {"site": "topk"},
+                                   cpu=True)
               if recovered < len(launched)
               else contextlib.nullcontext()):
             for si, out in launched:

@@ -12,7 +12,7 @@ import time
 import pytest
 
 from opensearch_tpu.common.device_ledger import device_ledger
-from opensearch_tpu.common.telemetry import gc_timer, tracer
+from opensearch_tpu.common.telemetry import gc_timer, metrics, tracer
 from opensearch_tpu.node import Node
 from opensearch_tpu.search import engine
 
@@ -30,6 +30,8 @@ def knn(x: float) -> tuple:
 
 
 TRACEPARENT = "00-" + "ab" * 16 + "-" + "cd" * 8 + "-01"
+# a trace whose spans meter their thread's CPU (one in eight does)
+METERED = {"traceparent": "00-" + "ab" * 15 + "a0-" + "cd" * 8 + "-01"}
 
 
 _sent = []          # the requests answered since the ring was emptied
@@ -204,10 +206,14 @@ def test_incoming_traceparent_is_honoured_at_http_request(node):
 def test_device_counters_count_every_program_and_sync(
         node, request_, dispatches, fetches):
     path, body = request_
+    index = path.split("/")[1]
     before = _stats(node)["device"]
     assert call(node, "POST", path, body)[0] == 200
     after = _stats(node)["device"]
-    assert after["dispatches"] - before["dispatches"] == dispatches
+    # the index's own: the node's sum is over the groups still resident,
+    # and falls when an earlier test's node is collected
+    assert (after["indices"][index]["dispatches"]
+            - before["indices"][index]["dispatches"]) == dispatches
     assert (after["transfers"]["fetch"]["ops"]
             - before["transfers"]["fetch"]["ops"]) == fetches
     assert after["health"]["breakers"]["dispatch"]["failures"] == 0
@@ -227,7 +233,8 @@ def test_slice_gather_programs_counts_what_the_kernel_copied(
                         {"query": query, "size": 3})
     assert status == 200 and resp["hits"]["hits"], resp
     after = _stats(node)["device"]
-    assert after["dispatches"] - before["dispatches"] == 2
+    assert (after["indices"][TEXT]["dispatches"]
+            - before["indices"][TEXT]["dispatches"]) == 2
     assert (after["slice_gather_programs"]
             - before["slice_gather_programs"]) == slices
 
@@ -272,6 +279,151 @@ def test_accept_wait_is_recorded_once_a_connection(node):
     assert len(roots) == 2
     assert "accept_wait_ns" in roots[0]["attributes"]
     assert "accept_wait_ns" not in roots[1]["attributes"]
+
+
+PREPARE_PARTS = {"device", "cache_get", "bind", "cache_put", "arrays"}
+EDGE_PARTS = {"read", "route", "after", "respond"}
+
+
+def test_totals_of_a_bm25_search_and_the_parts_of_its_spans(node):
+    """A body no other test sends: both segments miss what is prepared,
+    so ``segment.prepare`` shows all five parts; the totals of
+    ``_nodes/stats`` move by what the ring's spans say."""
+    request = ("/" + TEXT + "/_search",
+               {"query": {"match": {"t": "beta w2 alpha"}}, "size": 4})
+    _empty_ring()
+    before = _stats(node)["telemetry"]
+    status, resp = call(node, "POST", *request, headers=METERED)
+    assert status == 200 and resp["hits"]["hits"], resp
+    both = _finished_spans()             # the first stats read's too
+    after = _stats(node)["telemetry"]
+    spans = [s for s in both if s["trace_id"] == "ab" * 15 + "a0"]
+
+    def moved(name, key="count"):
+        return (after["spans"][name][key]
+                - before["spans"].get(name, {}).get(key, 0))
+
+    assert moved("segment.prepare") == 2
+    prepares = [s for s in spans if s["name"] == "segment.prepare"]
+    assert len(prepares) == 2
+    for s in prepares:
+        assert set(s["parts"]) == PREPARE_PARTS
+        assert sum(s["parts"].values()) <= s["duration_in_nanos"]
+        assert 0 <= s["cpu_in_nanos"] <= s["duration_in_nanos"]
+    assert set(after["spans"]["segment.prepare"]["parts"]) == PREPARE_PARTS
+    # the totals are the spans', to the rounding of a sum of floats
+    assert moved("segment.prepare", "time_in_millis") == pytest.approx(
+        sum(s["duration_in_nanos"] for s in prepares) / 1e6, abs=1e-6)
+    assert after["spans"]["segment.prepare"]["metered_count"] == 2
+    # a metered span counts for the eight of its name
+    assert after["spans"]["segment.prepare"]["off_cpu_in_millis"] == \
+        pytest.approx(8 * sum(s["duration_in_nanos"] - s["cpu_in_nanos"]
+                              for s in prepares) / 1e6, abs=1e-6)
+    for s in spans:
+        if s["name"] == "segment.dispatch":
+            assert set(s["parts"]) == {"launch"}
+            assert s["parts"]["launch"] <= s["duration_in_nanos"]
+            assert "cpu_in_nanos" not in s      # it did not ask
+    sync, = [s for s in spans if s["name"] == "device.sync"]
+    assert 0 <= sync["cpu_in_nanos"] <= sync["duration_in_nanos"]
+    # the REST edge: four parts and the rest: span make up http.request
+    root, = [s for s in spans if s["name"] == "http.request"]
+    rest, = [s for s in spans if s["name"] == REST]
+    assert set(root["parts"]) == EDGE_PARTS
+    covered = sum(root["parts"].values()) + rest["duration_in_nanos"]
+    assert covered <= root["duration_in_nanos"]
+    assert covered >= 0.9 * root["duration_in_nanos"]
+    assert 0 <= root["cpu_in_nanos"] <= root["duration_in_nanos"]
+    assert root["attributes"]["head_read_ns"] >= 0
+    # the tracer's own count: a stats read's two spans end after its
+    # body is built, so between the reads lie the first read's and the
+    # search's
+    assert len(both) == len(spans) + 2
+    assert (after["tracer"]["finished"] - before["tracer"]["finished"]
+            == len(both))
+    assert after["tracer"]["ring"] == 8192
+
+
+def test_a_prepared_hit_binds_and_puts_nothing(node):
+    _search_spans(node, BM25)
+    again = _search_spans(node, BM25)
+    for s in again:
+        if s["name"] == "segment.prepare":
+            assert s["attributes"]["prepared"] == "hit"
+            assert set(s["parts"]) == {"device", "cache_get", "arrays"}
+
+
+def test_knn_scans_put_their_launch_on_the_span_they_open(node):
+    # without a filter the pre-pass opens no span, and writes into none
+    spans = _search_spans(node, knn(11.0))
+    assert not [s for s in spans if s["name"] in ("knn.scan", "knn.filter")]
+    plan, = [s for s in spans if s["name"] == "query.plan"]
+    assert "parts" not in plan
+    filtered = ("/" + VECTORS + "/_search",
+                {"query": {"knn": {"v": {
+                    "vector": [12.0, 4, 1.5, 1], "k": 3,
+                    "filter": {"match_all": {}}}}}, "size": 3})
+    spans = _search_spans(node, filtered)
+    by_name = {s["name"]: s for s in spans}
+    for name in ("knn.scan", "knn.filter"):
+        assert set(by_name[name]["parts"]) == {"launch"}
+        assert by_name[name]["parts"]["launch"] <= \
+            by_name[name]["duration_in_nanos"]
+    assert "parts" not in by_name["query.plan"]
+
+
+def test_head_read_is_recorded_once_a_connection(node):
+    hist = metrics().histogram("rest.head_read_ms")
+    count0 = hist.count
+    conn = http.client.HTTPConnection("127.0.0.1", node.port)
+    try:
+        for _ in range(2):
+            assert call(node, "POST", *BM25, conn=conn)[0] == 200
+    finally:
+        conn.close()
+    roots = [s for s in _finished_spans() if s["name"] == "http.request"]
+    assert len(roots) == 2
+    assert roots[0]["attributes"]["head_read_ns"] >= 0
+    assert "head_read_ns" not in roots[1]["attributes"]
+    assert hist.count - count0 == 1
+
+
+def test_nodes_trace_says_what_the_ring_holds(node):
+    assert call(node, "POST", *BM25)[0] == 200
+    _finished_spans()
+    out = next(iter(call(node, "GET", "/_nodes/trace?size=3")[1][
+        "nodes"].values()))
+    assert len(out["spans"]) == 3 and out["ring"] == 8192
+    assert out["finished"] >= 3
+    assert out["oldest_start_time_in_nanos"] == min(
+        s["start_time_in_nanos"] for s in out["spans"])
+    assert out["oldest_start_time_in_nanos"] <= time.monotonic_ns()
+
+
+def test_metrics_endpoint_shows_the_totals(node):
+    assert call(node, "POST", *BM25)[0] == 200
+    _finished_spans()
+    c = http.client.HTTPConnection("127.0.0.1", node.port)
+    try:
+        c.request("GET", "/_metrics")
+        text = c.getresponse().read().decode()
+    finally:
+        c.close()
+    _sent.append("/_metrics")
+    stats = _stats(node)["telemetry"]
+    assert 'telemetry_spans_total{span="segment.prepare"} 2' in text
+    assert 'telemetry_span_cpu_ms_total{span="http.request"}' in text
+    # a sum that a tick of the CPU clock may step back: no counter
+    assert "# TYPE telemetry_span_off_cpu_ms gauge" in text
+    assert 'telemetry_span_off_cpu_ms{span="http.request"}' in text
+    assert 'telemetry_spans_metered_total{span="http.request"}' in text
+    assert ('telemetry_span_part_time_ms_total{span="segment.prepare",'
+            'part="arrays"}') in text
+    assert "rest_head_read_ms_count" in text
+    assert "telemetry_tracer_ring 8192" in text
+    # both surfaces render the tracer's one set of totals
+    assert stats["spans"]["segment.prepare"]["count"] == 2
+    assert stats["tracer"]["ring"] == 8192
 
 
 def test_spans_are_host_events_of_a_profiler_trace(node, tmp_path):

@@ -180,6 +180,245 @@ def test_spans_end_on_many_threads_while_the_ring_is_read():
     assert len({s["span_id"] for s in spans}) == len(spans)
 
 
+# a trace whose ``cpu=True`` spans meter (one in eight does, by the id's
+# last digit), and one whose do not
+METERED = SpanContext("ab" * 15 + "a0", "cd" * 8)
+UNMETERED = SpanContext("ab" * 16, "cd" * 8)
+
+
+def test_span_meters_its_threads_cpu_beside_its_wall_time():
+    """A span that spins is all CPU (never more than its duration, on
+    this host's exact clock); one that sleeps is all waiting: duration
+    less CPU is the time the thread did not run."""
+    t = Tracer()
+    with t.start_span("spins", parent=METERED, cpu=True):
+        until = time.monotonic_ns() + 30_000_000
+        while time.monotonic_ns() < until:
+            pass
+    with t.start_span("sleeps", parent=METERED, cpu=True):
+        time.sleep(0.03)
+    # the clock is a system call: only a span that asks reads it, and
+    # only in one trace of eight
+    with t.start_span("did.not.ask", parent=METERED):
+        pass
+    with t.start_span("other.trace", parent=UNMETERED, cpu=True):
+        pass
+    other, plain, sleeps, spins = t.recent()
+    assert "cpu_in_nanos" not in plain and "cpu_in_nanos" not in other
+    from opensearch_tpu.common.telemetry import CPU_METERED, CPU_WEIGHT
+    assert len(set(CPU_METERED)) * CPU_WEIGHT == 16 and CPU_WEIGHT == 8
+    assert 0 < spins["cpu_in_nanos"] <= spins["duration_in_nanos"]
+    # another thread may have held this core for part of the spin
+    assert spins["cpu_in_nanos"] >= spins["duration_in_nanos"] // 4
+    assert sleeps["duration_in_nanos"] >= 30_000_000
+    assert 0 <= sleeps["cpu_in_nanos"] < 5_000_000
+    totals = t.totals()
+    # a metered span stands for the eight of its name
+    assert totals["sleeps"]["off_cpu_in_millis"] == pytest.approx(
+        8 * (sleeps["duration_in_nanos"] - sleeps["cpu_in_nanos"]) / 1e6)
+    assert totals["spins"]["cpu_in_millis"] == pytest.approx(
+        8 * spins["cpu_in_nanos"] / 1e6)
+    assert totals["spins"]["metered_count"] == 1
+    assert totals["other.trace"]["metered_count"] == 0
+    assert totals["other.trace"]["off_cpu_in_millis"] == 0
+
+
+def test_totals_weight_each_metered_span_where_it_is_folded():
+    """One trace in eight meters, and its span counts for eight: the CPU
+    totals are sums, taken where a span ends, so they only grow (on an
+    exact clock) and a delta holds nothing of the spans before it."""
+    t = Tracer()
+    with t.start_span("waits", parent=METERED, cpu=True):
+        until = time.monotonic_ns() + 10_000_000
+        while time.monotonic_ns() < until:
+            pass
+    spun = t.totals()["waits"]
+    seen = [spun]
+    for ctx in (UNMETERED, METERED, UNMETERED):
+        with t.start_span("waits", parent=ctx, cpu=True):
+            time.sleep(0.01)
+        seen.append(t.totals()["waits"])
+    got = seen[-1]
+    assert got["count"] == 4 and got["metered_count"] == 2
+    assert got["time_in_millis"] >= 40
+    for key in ("cpu_in_millis", "off_cpu_in_millis", "time_in_millis"):
+        assert [a[key] for a in seen] == sorted(a[key] for a in seen)
+    # an unmetered span moves neither
+    assert seen[1]["cpu_in_millis"] == spun["cpu_in_millis"]
+    assert seen[1]["off_cpu_in_millis"] == spun["off_cpu_in_millis"]
+    # the window of the three sleeps reads as waiting, whatever share of
+    # the spin before it was CPU: 8 x one sleep of >= 10 ms
+    assert got["off_cpu_in_millis"] - spun["off_cpu_in_millis"] >= 8 * 9.0
+    assert got["cpu_in_millis"] - spun["cpu_in_millis"] < 8 * 2.0
+    assert spun["cpu_in_millis"] >= 8 * 2.5     # another thread may hold the core
+
+
+def test_every_eighth_trace_that_starts_here_meters():
+    """A new trace's id takes its last digit in turn, so the sample is
+    every eighth trace and not one in eight by luck; an id that came with
+    the request decides for itself."""
+    t = Tracer()
+    for _ in range(64):
+        with t.start_span("root", cpu=True):
+            with t.start_span("child", cpu=True):
+                pass
+    spans = t.recent(limit=1000)
+    ids = {s["trace_id"] for s in spans}
+    assert len(ids) == 64 and all(len(i) == 32 for i in ids)
+    assert sorted(i[-1] for i in ids) == sorted("0123456789abcdef" * 4)
+    for name in ("root", "child"):      # a trace's spans meter together
+        assert sum("cpu_in_nanos" in s for s in spans
+                   if s["name"] == name) == 8
+    assert t.totals()["root"]["metered_count"] == 8
+    with t.start_span("given", parent=UNMETERED, cpu=True):
+        pass
+    assert t.totals()["given"]["metered_count"] == 0
+
+
+def test_a_span_ended_on_another_thread_meters_no_cpu():
+    import threading
+    t = Tracer()
+    span = t.begin_span("handed.over", parent=METERED, cpu=True)
+    worker = threading.Thread(target=span.end)
+    worker.start()
+    worker.join(timeout=10)
+    assert not worker.is_alive()
+    t.begin_span("stayed", parent=METERED, cpu=True).end()
+    stayed, handed = t.recent()
+    assert "cpu_in_nanos" not in handed and span.cpu_nanos is None
+    assert stayed["cpu_in_nanos"] >= 0
+    # the CPU totals are over the spans that metered it, so a span that
+    # could not does not read as time off the CPU
+    totals = t.totals()
+    assert totals["handed.over"]["count"] == 1
+    assert totals["handed.over"]["time_in_millis"] > 0
+    assert totals["handed.over"]["cpu_in_millis"] == 0
+    assert totals["handed.over"]["off_cpu_in_millis"] == 0
+
+
+def test_parts_split_a_span_and_sum_to_no_more_than_it():
+    t = Tracer()
+    with t.start_span("whole") as span:
+        with span.part("a"):
+            time.sleep(0.002)
+        with span.part("b"):
+            pass
+        with span.part("a"):             # a part adds up
+            time.sleep(0.001)
+        span.add_part("c", 5)
+    with t.start_span("plain"):
+        pass
+    plain, whole = t.recent()
+    assert "parts" not in plain
+    assert set(whole["parts"]) == {"a", "b", "c"}
+    assert whole["parts"]["a"] >= 3_000_000 and whole["parts"]["c"] == 5
+    assert sum(whole["parts"].values()) <= whole["duration_in_nanos"]
+    totals = t.totals()
+    assert "parts" not in totals["plain"]
+    assert totals["whole"]["parts"]["a"]["time_in_millis"] == \
+        pytest.approx(whole["parts"]["a"] / 1e6)
+    assert sum(p["time_in_millis"] for p in
+               totals["whole"]["parts"].values()) <= \
+        totals["whole"]["time_in_millis"]
+
+
+def test_totals_are_exact_under_sixteen_threads_while_they_are_read():
+    """Spans end on every request thread: each name's totals are exact
+    (a lock a name), and a reader sees them only grow."""
+    import threading
+    t = Tracer(max_spans=64)             # the ring forgets; totals do not
+    workers, each = 16, 10_000
+    stop = threading.Event()
+    reads = []
+
+    def end_spans(w):
+        name = f"n{w % 4}"               # four threads a name
+        for _ in range(each):
+            with t.start_span(name, parent=METERED, cpu=True) as span:
+                span.add_part("p", 3)
+
+    def read_totals():
+        while not stop.is_set():
+            reads.append((t.stats()["finished"],
+                          sum(v["count"] for v in t.totals().values())))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        reader = threading.Thread(target=read_totals)
+        reader.start()
+        threads = [threading.Thread(target=end_spans, args=(w,))
+                   for w in range(workers)]
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=120)
+        stop.set()
+        reader.join(timeout=60)
+    finally:
+        sys.setswitchinterval(interval)
+    assert not reader.is_alive() and not any(th.is_alive() for th in threads)
+    totals = t.totals()
+    assert sorted(totals) == ["n0", "n1", "n2", "n3"]
+    for v in totals.values():
+        assert v["count"] == 4 * each
+        assert v["parts"]["p"]["time_in_millis"] == pytest.approx(
+            4 * each * 3 / 1e6, rel=1e-12)
+        assert v["metered_count"] == 4 * each
+        assert 0 <= v["cpu_in_millis"] <= 8 * v["time_in_millis"]
+        assert v["cpu_in_millis"] + v["off_cpu_in_millis"] == \
+            pytest.approx(8 * v["time_in_millis"], rel=1e-9)
+    assert t.stats() == {"finished": workers * each, "ring": 64}
+    assert reads and reads == sorted(reads)
+    assert len(t.recent(limit=1000)) == 64
+    t.reset()
+    assert t.totals() == {} and t.stats()["finished"] == 0
+
+
+def test_the_ring_read_out_loses_a_busy_windows_oldest_spans():
+    """What `_nodes/trace?size=4096` can hold of a window: a closed cell
+    ends some 2,000 spans a second, so the 4,096 newest are the last two
+    seconds of a traced four.  The totals and the tracer's own count
+    still account for every span, and the read-out's oldest start tells
+    a reader that its window began before what it was given."""
+    t = Tracer()
+    window_start = time.monotonic_ns()
+    total = 6000
+    for i in range(total):
+        with t.start_span("early" if i < 1500 else "late"):
+            pass
+    read = t.recent(4096)                # what Served.spans reads
+    assert len(read) == 4096
+    assert {s["name"] for s in read} == {"late"}      # no early span left
+    oldest = min(s["start_time_in_nanos"] for s in read)
+    assert oldest > window_start          # the window began before it ...
+    assert t.stats() == {"finished": total, "ring": 8192}
+    assert t.stats()["finished"] > len(read)          # ... and says so
+    totals = t.totals()
+    assert totals["early"]["count"] == 1500
+    assert totals["late"]["count"] == total - 1500
+    assert totals["early"]["time_in_millis"] > 0
+
+
+def test_tracer_totals_in_the_prometheus_text():
+    t = Tracer()
+    with t.start_span("segment.prepare") as span:
+        span.add_part("bind", 2_000_000)
+    text = t.prometheus_text()
+    assert 'telemetry_spans_total{span="segment.prepare"} 1' in text
+    assert ('telemetry_span_part_time_ms_total{span="segment.prepare",'
+            'part="bind"} 2') in text
+    assert "telemetry_tracer_finished_total 1" in text
+    assert "telemetry_tracer_ring 8192" in text
+    for line in text.splitlines():
+        assert line.startswith("#") or len(line.rsplit(" ", 1)) == 2
+        if not line.startswith("#"):
+            float(line.rsplit(" ", 1)[1])
+    totals = t.totals()["segment.prepare"]
+    assert (f'telemetry_span_time_ms_total{{span="segment.prepare"}} '
+            f'{totals["time_in_millis"]:.10g}') in text
+
+
 def test_span_records_errors():
     t = Tracer()
     with pytest.raises(ValueError):
