@@ -30,9 +30,11 @@ contiguous stretch of the staged columns, into a flat ``budget``-sized
 space: as contiguous slices up to the threshold of ``slice_lowering``,
 as an element gather beyond it.  The TPU gathers element by element at
 30-50 ns a lane, which was three quarters of a term-bag program's time;
-a slice copy streams, but costs a window of ``budget`` lanes per term
-slot, so an expansion of hundreds of terms over a small bucket keeps the
-element gather.
+a slice copy streams.  It moves each run in chunks of ``copy_chunk``
+lanes and loops as often as the runs have chunks, so it costs the
+postings it places plus a few microseconds a chunk to start: an empty
+or inactive slot costs nothing, and a long bag over a large bucket no
+longer pays ``budget`` lanes a slot.
 
 This is the BM25S formulation (see PAPERS.md): the tf-side factor
 ``tf / (tf + k1*(1-b + b*dl/avgdl))`` depends only on segment data plus
@@ -92,13 +94,18 @@ def compute_impacts(tfs, doc_ids, doc_lens, avgdl, *,
 
 
 # Which lowering ``gather_postings`` takes, from its static shape alone.
-# Measured on one v5e over an 8,388,608-slot column (PR 28, PERF.md
-# section 5): a slot of the slice copy costs about 4 us to start plus
-# 0.03 ns a lane, which is 131,072 lanes' worth; a lane of the element
-# gather costs 30-50 ns, some 1,600 copied lanes.  So the copy's
-# ``t_pad`` windows of ``budget`` lanes win while
+# Measured on one v5e over an 8,388,608-slot column (PR 28) against the
+# slice copy as it then was, one window of ``budget`` lanes a slot: a
+# slot cost about 4 us to start plus 0.03 ns a lane, which is 131,072
+# lanes' worth; a lane of the element gather costs 30-50 ns, some 1,600
+# copied lanes.  So that copy's ``t_pad`` windows won while
 #   t_pad * (_SLOT_START_LANES + budget) <= _ELEMENT_LANE * budget:
 # up to 32 slots at a budget of 4,096, 512 at 65,536, 1,024 from 262,144.
+# Since PR 38 the copy moves chunks of ``copy_chunk`` lanes, as many as
+# the runs have, so a slot costs its run and not ``budget`` lanes: the
+# break-even lies further towards the slices at a large ``t_pad`` than
+# this rule says.  No cell runs ``t_pad`` above 64; the threshold wants
+# ``tools/gather_bench.py`` again from 128 up (ROADMAP B3).
 _SLOT_START_LANES = 131072
 _ELEMENT_LANE = 1600
 
@@ -111,6 +118,35 @@ def slice_lowering(t_pad: int, budget: int) -> bool:
     return t_pad * (_SLOT_START_LANES + budget) <= _ELEMENT_LANE * budget
 
 
+# The fewest lanes a chunk of the slice copy moves, where the budget has
+# them.  Measured on one v5e (PR 38, ``tools/gather_bench.py``, PERF.md
+# section 3): a chunk costs about 3.9 us to start plus 0.08 ns a lane
+# (read, select under the mask, write; two columns), so a start is worth
+# some 49,000 lanes.  us a gather of a bag as a SPLADE query brings it
+# (three quarters of the slots active, a quarter to a half of the bucket
+# filled), in chunks of 8,192 / 16,384 / 32,768 / 65,536 / 131,072:
+#   (t_pad 64, budget 1,048,576)   771 / 672 / 641 / 674 / 743
+#   (32, 1,048,576)                609 / 424 / 362 / 358 / 389
+#   (32, 262,144)                  212 / 186 / 190 / 211 / 245
+#   (8, 262,144)                   123 /  83 /  68 /  69 /  78
+#   (8, 65,536)                     51 /  49 /  50 /  55
+# against 5,508, 2,771, 389, 112 and 62 for one window of ``budget``
+# lanes a slot, the copy as it was.  (One slot at 1,048,576 lanes: 114
+# to 118 as one window, 62 to 77 in chunks of 131,072; ROADMAP B3.)
+_CHUNK_FLOOR = 32768
+
+
+def copy_chunk(t_pad: int, budget: int) -> int:
+    """Lanes a chunk of the slice copy moves (``_copy_runs``), from the
+    static shape alone: the budget's share of a slot rounded down to a
+    power of two, so a full budget is at most ``2 * t_pad`` chunks at
+    any shape and a single slot (a ``term`` filter) keeps one window of
+    the whole budget; never under ``_CHUNK_FLOOR`` lanes, below which a
+    chunk is all start cost, unless the budget itself is."""
+    share = max(budget // t_pad, 1)
+    return min(budget, max(_CHUNK_FLOOR, 1 << (share.bit_length() - 1)))
+
+
 def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
                     budget: int, pad_doc: int):
     """Flatten the postings of up to T terms into fixed-size arrays.
@@ -118,12 +154,12 @@ def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
     The CSR rows selected by ``term_ids`` are laid end-to-end into a
     ``budget``-sized flat space — fully on-device, shape-static.  A row
     is one contiguous run ``offsets[t] : offsets[t+1]`` of the columns,
-    so up to the threshold of ``slice_lowering`` each run is copied as a
-    contiguous slice (streaming vector work, ``t_pad`` windows of
-    ``budget`` lanes); beyond it, where that many windows would cost
-    more than they save, every output lane computes its own address and
-    the columns are gathered element by element (a serial gather on the
-    TPU: 30-50 ns a lane).  Both give the same lanes.
+    so up to the threshold of ``slice_lowering`` each run is copied as
+    contiguous slices (streaming vector work: chunks of ``copy_chunk``
+    lanes, as many as the runs have, nothing for an inactive slot);
+    beyond it every output lane computes its own address and the columns
+    are gathered element by element (a serial gather on the TPU: 30-50
+    ns a lane).  Both give the same lanes.
 
     Contract: the caller must choose ``budget >= sum(df[term_ids])``
     (the executor computes this from host-side df stats and rounds up to a
@@ -147,7 +183,8 @@ def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
         slot = jnp.sum(cum[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
         slot = jnp.minimum(slot, t_pad - 1)
         d, tf = _copy_runs(doc_ids, tfs, starts, lens, cum - lens,
-                           budget=budget, pad_doc=pad_doc)
+                           budget=budget, pad_doc=pad_doc,
+                           chunk=copy_chunk(t_pad, budget))
         return d, tf, slot, valid
     slot = jnp.searchsorted(cum, i, side="right").astype(jnp.int32)
     slot = jnp.minimum(slot, t_pad - 1)
@@ -159,32 +196,48 @@ def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
 
 
 def _copy_runs(doc_ids, tfs, starts, lens, prevs, *, budget: int,
-               pad_doc: int):
-    """The slice lowering of ``gather_postings``: slot by slot, read one
-    ``win``-lane window of each column that holds the term's run and
-    write the run's lanes, and no others, at the term's place
-    ``prevs[t]`` in the flat space.
+               pad_doc: int, chunk: int):
+    """The slice lowering of ``gather_postings``: run by run, chunk by
+    chunk, read one ``win``-lane window of each column (``win`` is
+    ``chunk``, or the column where that is shorter) and write the lanes
+    of it that the run still has, and no others, at their place
+    ``prevs[t] + c * win`` in the flat space.  A slot has
+    ``ceil(lens[t] / win)`` chunks, none when it is inactive or empty,
+    and the loop runs once a chunk: its trip count comes from the data,
+    its shapes from ``(t_pad, budget)`` alone.
 
     ``dynamic_slice`` clamps a window's start so that the window fits, so
-    a run near the column's end (or any run, when the column is shorter
-    than ``budget``) begins ``shift`` lanes into its window; the write
-    goes ``shift`` lanes earlier to undo that, into a buffer with ``win``
-    spare lanes on either side so that no write is clamped in turn.  The
-    write is read-modify-write under the run's lane mask: what lies
-    beyond a term's own run never reaches the flat space, and the order
-    of the slots does not matter."""
+    a chunk near the column's end begins ``shift`` lanes into its
+    window; the write goes ``shift`` lanes earlier to undo that, into a
+    buffer with ``win`` spare lanes on either side so that no write is
+    clamped in turn.  The write is read-modify-write under the chunk's
+    lane mask: what lies beyond a term's own run never reaches the flat
+    space, and the order of the chunks does not matter."""
     n_post = doc_ids.shape[0]
-    win = min(budget, n_post)
+    t_pad = starts.shape[0]
+    win = min(chunk, n_post)
     lane = jnp.arange(win, dtype=jnp.int32)
     # a caller that broke the contract loses the lanes past ``budget``,
     # as the element gather drops them
     prevs = jnp.minimum(prevs, budget)
+    lens = jnp.clip(lens, 0, budget - prevs)
+    n_chunks = (lens + (win - 1)) // win
+    ends = jnp.cumsum(n_chunks)
+    # trip i -> its slot, by t_pad compares a trip, and what that slot
+    # has done before it; a table of the most trips the shape allows, so
+    # the loop reads three scalars and searches nothing
+    trip = jnp.arange(budget // win + t_pad, dtype=jnp.int32)
+    t = jnp.minimum(
+        jnp.sum(ends[None, :] <= trip[:, None], axis=1, dtype=jnp.int32),
+        t_pad - 1)
+    done = (trip - (ends[t] - n_chunks[t])) * win
+    src, dst, left = starts[t] + done, prevs[t] + done, lens[t] - done
 
-    def copy_slot(t, bufs):
-        start = jnp.clip(starts[t], 0, n_post - win)
-        shift = starts[t] - start
-        keep = (lane >= shift) & (lane < shift + lens[t])
-        at = prevs[t] - shift + win
+    def copy_one(i, bufs):
+        start = jnp.clip(src[i], 0, n_post - win)
+        shift = src[i] - start
+        keep = (lane >= shift) & (lane < shift + left[i])
+        at = dst[i] - shift + win
         return tuple(
             lax.dynamic_update_slice(
                 buf, jnp.where(keep,
@@ -193,9 +246,8 @@ def _copy_runs(doc_ids, tfs, starts, lens, prevs, *, budget: int,
                 (at,))
             for col, buf in zip((doc_ids, tfs), bufs))
 
-    # a loop, not unrolled: the program stays the same size at any t_pad
     d, tf = lax.fori_loop(
-        0, starts.shape[0], copy_slot,
+        0, ends[-1], copy_one,
         (jnp.full(budget + 2 * win, pad_doc, doc_ids.dtype),
          jnp.zeros(budget + 2 * win, tfs.dtype)))
     return d[win:win + budget], tf[win:win + budget]

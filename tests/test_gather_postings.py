@@ -4,7 +4,12 @@ reference of the CSR lay-out, on both lowerings (contiguous slices up to
 
 The columns carry values that name their own position, so a lane that
 read from beyond its term's run shows as a wrong number, not a wrong
-score some layers up."""
+score some layers up.  The slice copy moves a run in chunks of
+``copy_chunk`` lanes, the whole budget at these sizes; the ``chunk_``
+cases ask for a small chunk so that a run of the test column is several."""
+
+import math
+from typing import NamedTuple
 
 import numpy as np
 import pytest
@@ -22,9 +27,16 @@ PAD_DOC = 999_999
 LENS = np.array([5, 1, 40, 7, 0, 300, 2, 64, 68, 80], dtype=np.int32)
 
 
-def column(pad_to: int | None = None):
-    offsets = np.zeros(len(LENS) + 1, dtype=np.int32)
-    np.cumsum(LENS, out=offsets[1:])
+def cell_lens():
+    """Forty runs as a SPLADE segment's query tokens have them: 330,000
+    postings, the longest some 100,000."""
+    w = np.random.default_rng(38).lognormal(0.0, 1.2, 40)
+    return np.maximum(w / w.sum() * 330_000, 1).astype(np.int32)
+
+
+def column(pad_to: int | None = None, lens=LENS):
+    offsets = np.zeros(len(lens) + 1, dtype=np.int32)
+    np.cumsum(lens, out=offsets[1:])
     n = int(offsets[-1])
     size = n if pad_to is None else pad_to
     doc_ids = np.full(size, -7, dtype=np.int32)
@@ -48,9 +60,9 @@ def reference(offsets, doc_ids, tfs, term_ids, active, budget):
         at += n
         bounds.append(at)
     # lane i belongs to the first slot whose cumulative end is past i
-    for i in range(budget):
-        s = int(np.searchsorted(bounds, i, side="right"))
-        slot[i] = min(s, len(term_ids) - 1)
+    slot[:] = np.minimum(
+        np.searchsorted(bounds, np.arange(budget), side="right"),
+        len(term_ids) - 1)
     return d, tf, slot, np.arange(budget) < at
 
 
@@ -71,7 +83,19 @@ def padded(term_ids, active, t_pad):
     return term_ids, active
 
 
-# name: (term ids, active, t_pad, budget, column padded to, slices?)
+class Case(NamedTuple):
+    term_ids: list
+    active: list
+    t_pad: int
+    budget: int
+    pad_to: int | None      # the column's length, None: its last posting
+    slices: bool            # the lowering the shape takes
+    chunk: int | None = None    # None: what ``copy_chunk`` says
+    lens: np.ndarray = LENS
+
+
+# name: (term ids, active, t_pad, budget, column padded to, slices?
+#        [, lanes a chunk [, the column's runs]])
 CASES = {
     # term 9's run ends at the column's last posting, so its window's
     # start is clamped; term 0's window is not
@@ -111,14 +135,55 @@ CASES = {
     "slices_many_slots":
         (list(range(10)) * 3, [True, False, True] * 10, 32, 4096, 4096,
          True),
+    # the chunked copy: term 7 has 64 postings, term 9 has 80 and ends at
+    # the column's last, term 5 has 300
+    "chunk_run_of_exactly_one_chunk":
+        ([7, 0, 2], [True] * 3, 4, 512, 4096, True, 64),
+    "chunk_run_a_whole_multiple":
+        ([7, 9, 2], [True] * 3, 4, 512, 4096, True, 16),
+    "chunk_run_a_multiple_plus_one":
+        ([5, 0, 7], [True] * 3, 4, 512, 4096, True, 13),
+    # the third window of term 9 would pass the column's end: clamped,
+    # its lanes ``shift``ed
+    "chunk_long_run_ends_at_last_posting":
+        ([9, 0, 7], [True] * 3, 4, 512, None, True, 32),
+    "chunk_last_posting_first_and_alone":
+        ([9], [True], 1, 512, None, True, 32),
+    "chunk_column_shorter_than_a_chunk":
+        ([5, 9, 1], [True] * 3, 4, 2048, None, True, 1024),
+    "chunk_nothing_active":
+        ([1, 2], [False, False], 2, 512, 4096, True, 16),
+    "chunk_inactive_and_empty_slots":
+        ([3, 5, 4, 7], [True, False, True, True], 4, 512, 4096, True, 4),
+    "chunk_total_equal_to_budget":
+        ([5, 9, 7, 8], [True] * 4, 4, 512, 4096, True, 32),
+    "chunk_same_term_twice":
+        ([7, 7, 3], [True] * 3, 4, 512, 4096, True, 16),
+    "chunk_of_one_lane":
+        ([2, 9], [True, True], 2, 512, None, True, 1),
+    # a caller that broke the contract, a run across the budget's end
+    "chunk_total_past_budget":
+        ([5, 5, 9, 7], [True] * 4, 4, 512, 600, True, 32),
+    "chunk_many_slots":
+        (list(range(10)) * 3, [True, False, True] * 10, 32, 4096, 4096,
+         True, 8),
+    # a cell's shape: ``splade_sparse_*``'s most frequent program, 24 of
+    # 32 slots active, chunks of ``copy_chunk``'s own choosing over a
+    # column shorter than the budget
+    "cell_shape_32_by_1048576":
+        (list(range(24)) + [0] * 8, [True] * 24 + [False] * 8, 32,
+         1048576, 1 << 19, True, None, cell_lens()),
 }
+CASES = {name: Case(*case) for name, case in CASES.items()}
 
 
 @pytest.mark.parametrize("name", sorted(CASES))
-def test_gather_postings_matches_csr_layout(name):
-    term_ids, active, t_pad, budget, pad_to, slices = CASES[name]
+def test_gather_postings_matches_csr_layout(monkeypatch, name):
+    term_ids, active, t_pad, budget, pad_to, slices, chunk, lens = CASES[name]
     assert bm25.slice_lowering(t_pad, budget) is slices
-    offsets, doc_ids, tfs = column(pad_to)
+    if chunk is not None:
+        monkeypatch.setattr(bm25, "copy_chunk", lambda t, b: chunk)
+    offsets, doc_ids, tfs = column(pad_to, lens)
     term_ids, active = padded(term_ids, active, t_pad)
     want = reference(offsets, doc_ids, tfs, term_ids, active, budget)
     got = gather(offsets, doc_ids, tfs, term_ids, active, budget)
@@ -130,12 +195,15 @@ def test_gather_postings_matches_csr_layout(name):
     assert (got[1][total:] == 0.0).all()
 
 
-@pytest.mark.parametrize("t_pad,budget", [(4, 512), (8, 4096), (32, 4096),
-                                          (64, 4096), (128, 4096),
-                                          (512, 65536)])
-def test_both_lowerings_agree(monkeypatch, t_pad, budget):
+@pytest.mark.parametrize("t_pad,budget,chunk", [
+    (4, 512, None), (8, 4096, None), (32, 4096, None), (64, 4096, None),
+    (128, 4096, None), (512, 65536, None), (8, 4096, 7), (64, 4096, 64),
+    (128, 4096, 256)])
+def test_both_lowerings_agree(monkeypatch, t_pad, budget, chunk):
     """The same random terms through both lowerings: the threshold picks
-    a price, never a result."""
+    a price, never a result, and the chunk only how the slices move."""
+    if chunk is not None:
+        monkeypatch.setattr(bm25, "copy_chunk", lambda t, b: chunk)
     rng = np.random.default_rng(t_pad * 31 + budget)
     offsets, doc_ids, tfs = column(4096)
     term_ids = rng.integers(0, len(LENS), t_pad)
@@ -164,6 +232,63 @@ def test_slice_lowering_is_chosen_from_the_static_shape():
     assert bm25.slice_lowering(512, 262144)
     assert not bm25.slice_lowering(512, 16384)
     assert not bm25.slice_lowering(4096, 1048576)
+
+
+def test_copy_chunk_is_chosen_from_the_static_shape():
+    """One window of the whole budget at one slot; else the budget's
+    share of a slot, a power of two, never under the floor unless the
+    budget is, and a full budget in at most ``2 * t_pad`` chunks."""
+    floor = bm25._CHUNK_FLOOR
+    assert floor & (floor - 1) == 0
+    buckets = [4096 * 4 ** k for k in range(7)]
+    for budget in buckets:
+        assert bm25.copy_chunk(1, budget) == budget
+        for t_pad in (1, 2, 4, 8, 16, 32, 64, 128, 512, 4096):
+            chunk = bm25.copy_chunk(t_pad, budget)
+            assert 1 <= chunk <= budget
+            assert chunk & (chunk - 1) == 0
+            assert chunk >= min(floor, budget)
+            assert math.ceil(budget / chunk) <= 2 * t_pad
+    # the cells' shapes
+    assert bm25.copy_chunk(32, 1048576) == max(floor, 32768)
+    assert bm25.copy_chunk(64, 1048576) == max(floor, 16384)
+    assert bm25.copy_chunk(2, 1048576) == 524288
+    assert bm25.copy_chunk(8, 4096) == 4096
+    # any shape a caller may bring: no power of two, more slots than lanes
+    assert bm25.copy_chunk(3, 600) == 600
+    assert bm25.copy_chunk(7, 10 * floor) == floor
+    assert bm25.copy_chunk(4096, 512) == 512
+
+
+@pytest.mark.parametrize("term_ids,lens,chunk,trips", [
+    ([7, 4, 0, 5], [64, 0, 5, 300], 16, 4 + 0 + 1 + 19),
+    ([7, 4, 0, 5], [64, 0, 5, 300], 512, 3),
+    ([7, 4, 0, 5], [0, 0, 0, 0], 16, 0),         # every slot inactive
+    ([5, 5, 9, 7], [300, 300, 80, 64], 32, 10 + 7),  # cut at the budget:
+                                                     # 300 + 212 lanes
+])
+def test_the_loop_runs_once_a_chunk(monkeypatch, term_ids, lens, chunk,
+                                    trips):
+    """The trip count comes from the data: as many window writes as the
+    runs have chunks, none for an empty or inactive slot."""
+    offsets, doc_ids, tfs = column(4096)
+    lens = np.array(lens, np.int32)
+    starts = offsets[term_ids]
+    writes = []
+    real = jax.lax.dynamic_update_slice
+    monkeypatch.setattr(
+        bm25.lax, "dynamic_update_slice",
+        lambda *a, **kw: writes.append(1) or real(*a, **kw))
+    with jax.disable_jit():
+        d, tf = bm25._copy_runs(
+            jnp.asarray(doc_ids), jnp.asarray(tfs), jnp.asarray(starts),
+            jnp.asarray(lens), jnp.asarray(np.cumsum(lens) - lens),
+            budget=512, pad_doc=PAD_DOC, chunk=chunk)
+    assert len(writes) == 2 * trips      # one a column
+    total = min(int(lens.sum()), 512)
+    assert (np.asarray(d)[total:] == PAD_DOC).all()
+    assert (np.asarray(tf)[total:] == 0.0).all()
+    assert (np.asarray(tf)[:total] > 0.0).all()
 
 
 def test_slice_lowering_traces_no_element_gather_of_a_column():
