@@ -252,6 +252,7 @@ class DeviceResidencyLedger:
         self.slice_gather_programs = 0
         self.block_topk_programs = 0
         self.sorted_bag_programs = 0
+        self.phrase_programs = 0
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
@@ -396,7 +397,8 @@ class DeviceResidencyLedger:
     def record_dispatch(self, group: Optional[_Group], *,
                         slice_gather: bool = False,
                         block_topk: bool = False,
-                        sorted_bag: bool = False) -> None:
+                        sorted_bag: bool = False,
+                        phrase: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
         signal budget eviction orders by.  ``slice_gather``: the
         program's static shape took ``gather_postings``'s contiguous-
@@ -407,11 +409,13 @@ class DeviceResidencyLedger:
         ``sorted_bag``: a scored term bag's top-k came from its sorted
         postings, without the dense accumulator (``Plan.sorted_topk``,
         i.e. ``ops/bm25.py::sorted_bag``, over a segment with no
-        deleted doc)."""
+        deleted doc).  ``phrase``: the program holds a ``PhrasePlan``
+        somewhere in its plan (``plan.phrase_dims`` of its key)."""
         with self._lock:
             self.slice_gather_programs += bool(slice_gather)
             self.block_topk_programs += bool(block_topk)
             self.sorted_bag_programs += bool(sorted_bag)
+            self.phrase_programs += bool(phrase)
             if group is not None:
                 group.dispatches += 1
                 group.last_dispatch_tick = next(self._tick)
@@ -532,6 +536,7 @@ class DeviceResidencyLedger:
             slice_gathers = self.slice_gather_programs
             block_topks = self.block_topk_programs
             sorted_bags = self.sorted_bag_programs
+            phrases = self.phrase_programs
         per_index: dict[str, dict] = {}
         resident = 0
         dispatches = 0
@@ -551,6 +556,7 @@ class DeviceResidencyLedger:
             "slice_gather_programs": slice_gathers,
             "block_topk_programs": block_topks,
             "sorted_bag_programs": sorted_bags,
+            "phrase_programs": phrases,
             "budget": {
                 "budget_bytes": budget or 0,
                 "evictions": ev,
@@ -625,7 +631,7 @@ class DeviceResidencyLedger:
             self.budget_bytes = None
             self.evictions = self.restages = self.host_fallbacks = 0
             self.slice_gather_programs = self.block_topk_programs = 0
-            self.sorted_bag_programs = 0
+            self.sorted_bag_programs = self.phrase_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
                 for key in t:

@@ -321,27 +321,36 @@ def _auto_fuzzy(fuzziness, term: str) -> int:
 
 
 def _c_match_phrase(q, ctx, scored):
+    """``match_phrase``: span ``phrase.bind`` covers the analysis of the
+    phrase and the bind (attributes ``slots``, and ``known``: the slots
+    whose term some segment of the shard holds); the ``search.phrase.*``
+    counters move where the bound plan runs (``ShardSearcher._topk``)."""
+    from opensearch_tpu.common.telemetry import tracer
+
     ft = _require_ft(ctx, q.field, "match_phrase")
     if ft is None:
         return _none()
     if not isinstance(ft, TextFieldType):
         return _c_term(dsl.TermQuery(field=q.field, value=q.query,
                                      boost=q.boost), ctx, scored)
-    analyzer = ctx.mapper.analyzers.get(ft.search_analyzer_name)
-    toks = analyzer.analyze(str(q.query))
-    if not toks:
-        return _none()
-    if len(toks) == 1:
-        return _term_bag(ctx, q.field, [toks[0].term], 1, q.boost, scored)
-    if q.slop:
-        raise IllegalArgumentError("match_phrase slop > 0 is not supported yet")
-    terms = [t.term for t in toks]
-    positions = [t.position for t in toks]
-    stats = ctx.field_stats(q.field)
-    idf_sum = float(np.sum(_idfs_for(ctx, q.field, terms)))
-    bind = {"terms": tuple(terms), "positions": tuple(positions),
-            "idf_sum": idf_sum, "boost": q.boost, "avgdl": stats.avgdl}
-    return P.PhrasePlan(field=q.field, scored=scored), bind
+    with tracer().start_span("phrase.bind") as span:
+        analyzer = ctx.mapper.analyzers.get(ft.search_analyzer_name)
+        toks = analyzer.analyze(str(q.query))
+        span.set_attribute("slots", len(toks))
+        if not toks:
+            return _none()
+        if len(toks) == 1:
+            return _term_bag(ctx, q.field, [toks[0].term], 1, q.boost,
+                             scored)
+        if q.slop:
+            raise IllegalArgumentError(
+                "match_phrase slop > 0 is not supported yet")
+        terms = [t.term for t in toks]
+        span.set_attribute(
+            "known", sum(1 for t in terms if ctx.df(q.field, t)))
+        return _phrase_from_tokens(ctx, q.field, terms,
+                                   [t.position for t in toks], q.boost,
+                                   scored)
 
 
 def _c_multi_match(q, ctx, scored):

@@ -1125,6 +1125,9 @@ class ShardSearcher:
                 _metrics().counter("search.neural_sparse.requests").inc()
                 _metrics().counter("search.neural_sparse.query_tokens").inc(
                     len(bind["terms"]))
+        # a request's phrases: slots (of one program), and over its
+        # programs the anchors' positions and the lanes keyed
+        phrase_slots = phrase_positions = phrase_lanes = 0
         # [si, out]: a recovered segment's (vals, idx, tot, mx), or the
         # device's packed result (P.run_topk), its copy to the host
         # under way
@@ -1177,10 +1180,11 @@ class ShardSearcher:
                             # the result on the host instead of asking
                             # for it
                             out.copy_to_host_async()
+                        phrases = list(P.phrase_dims(dims))
                         _ledger().record_dispatch(
                             getattr(dseg, "_ledger_group", None),
                             slice_gather=plan.slice_gathers(dims),
-                            sorted_bag=sorts,
+                            sorted_bag=sorts, phrase=bool(phrases),
                             # a sorted bag's key is its budget's lanes
                             block_topk=topk_ops.block_size(
                                 dims[1] if sorts else dseg.n_pad, k))
@@ -1189,6 +1193,14 @@ class ShardSearcher:
                             # postings gathered against lanes keyed
                             bag_postings.inc(dims.postings)
                             bag_lanes.inc(dims[1])
+                        if phrases:
+                            # wherever the phrase sits in the plan, and
+                            # on a plan-cache hit too: what its anchor
+                            # holds against the bucket it was keyed with
+                            phrase_slots = sum(pd.slots for pd in phrases)
+                            phrase_positions += sum(
+                                pd.anchor_positions for pd in phrases)
+                            phrase_lanes += sum(pd[1] for pd in phrases)
                     except Exception as exc:
                         if not is_device_error(exc):
                             raise
@@ -1212,6 +1224,13 @@ class ShardSearcher:
             if allow_kth_prune and len(launched) >= 1 \
                     and si + 1 < len(self.segments):
                 kth = self._harvest_kth(launched, k_want, kth)
+        if phrase_lanes:
+            _metrics().counter("search.phrase.requests").inc()
+            _metrics().counter("search.phrase.slots").inc(phrase_slots)
+            _metrics().counter("search.phrase.anchor_positions").inc(
+                phrase_positions)
+            _metrics().counter("search.phrase.budget_lanes").inc(
+                phrase_lanes)
         # phase 2: ONE host-sync region over all segments' results —
         # also the result-sanity guard: non-finite device scores are
         # poison (a misbehaving accelerator, not a query property);

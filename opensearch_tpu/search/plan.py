@@ -504,10 +504,48 @@ class TermBagPlan(Plan):
         return jnp.where(matched, scores, 0.0), matched
 
 
+class PhraseDims(tuple):
+    """``PhrasePlan``'s program key ``(s_pad, bucket)``: the padded slot
+    count and ONE ``1024 * 4^k`` bucket, the anchor slot's (a lane of it
+    costs ~1 us of searches, and three phrases in four anchor on a word
+    with under 1,024 positions in a segment).  Beside the
+    key, and no part of it (``BagDims``' way), it remembers what
+    ``prepare`` saw: the phrase's slots and the anchor's positions in the
+    segment (``search.phrase.slots``, ``search.phrase.anchor_positions``)."""
+
+    slots = 0
+    anchor_positions = 0
+
+    @classmethod
+    def of(cls, slots: int, anchor_positions: int) -> "PhraseDims":
+        dims = cls((pad_pow2(slots, minimum=4),
+                    pad_bucket(anchor_positions, minimum=1024)))
+        dims.slots = slots
+        dims.anchor_positions = anchor_positions
+        return dims
+
+
+def phrase_dims(dims):
+    """Every ``PhraseDims`` in a plan's ``dims`` tree, wherever the
+    phrase sits (root, ``bool.must``, ``bool.should``, ``dis_max``)."""
+    if isinstance(dims, PhraseDims):
+        yield dims
+    elif isinstance(dims, tuple):
+        for d in dims:
+            yield from phrase_dims(d)
+
+
 @dataclass(frozen=True)
 class PhrasePlan(Plan):
     """Exact phrase over one field (match_phrase, slop=0).  bind: {terms,
-    positions, idf_sum, boost, avgdl}."""
+    positions, idf_sum, boost, avgdl}.
+
+    The segment program is keyed by ``PhraseDims``.  ``prepare`` puts the
+    slot with the fewest positions in the segment first (the anchor, as
+    Lucene leads with the rarest term) and the others behind it, fewest
+    first; ``ops/phrase.py`` gathers the anchor's occurrences and probes
+    the other slots for each, so a program costs what its rarest word
+    holds, and a slot the segment lacks makes it match nothing."""
 
     field: str = ""
     scored: bool = True
@@ -532,33 +570,40 @@ class PhrasePlan(Plan):
     def prepare(self, bind, seg, dseg, ctx):
         terms = bind["terms"]
         pf = seg.postings.get(self.field)
-        m = len(terms)
-        tids = np.zeros(m, dtype=_I32)
-        active = np.zeros(m, dtype=bool)
-        budgets = []
-        for j, t in enumerate(terms):
+        slots = []                     # (positions here, term id, position)
+        for t, at in zip(terms, bind["positions"]):
             tid = pf.term_id(t) if pf is not None else -1
-            count = 0
-            if tid >= 0:
-                tids[j] = tid
-                active[j] = True
-                e0, e1 = int(pf.offsets[tid]), int(pf.offsets[tid + 1])
-                count = int(pf.pos_offsets[e1] - pf.pos_offsets[e0])
-            budgets.append(pad_bucket(count, minimum=1024))
-        ins = (_stage_input(tids), _stage_input(active),
-               _stage_input(np.asarray(bind["positions"], _I32)),
-               _scalar(bind["idf_sum"], _F32),
-               _scalar(bind["boost"], _F32),
-               _scalar(bind["avgdl"], _F32))
-        return (tuple(budgets),), ins
+            if tid < 0:
+                slots = []
+                break
+            e0, e1 = int(pf.offsets[tid]), int(pf.offsets[tid + 1])
+            slots.append((int(pf.pos_offsets[e1] - pf.pos_offsets[e0]),
+                          tid, int(at)))
+        slots.sort(key=lambda s: s[0])         # stable: ties keep slot order
+        dims = PhraseDims.of(len(terms), slots[0][0] if slots else 0)
+        s_pad = dims[0]
+        # one int32 array a segment program: [term ids | positions less
+        # the anchor's | slots, 0 where a term is missing | the bits of
+        # idf_sum, boost, avgdl]
+        packed = np.zeros(2 * s_pad + 4, dtype=_I32)
+        for j, (_n, tid, at) in enumerate(slots):
+            packed[j] = tid
+            packed[s_pad + j] = at - slots[0][2]
+        packed[2 * s_pad] = len(slots)
+        packed[2 * s_pad + 1:] = np.asarray(
+            [bind["idf_sum"], bind["boost"], bind["avgdl"]], _F32).view(_I32)
+        return dims, (_stage_input(packed),)
 
     def eval(self, A, dims, ins):
-        (budgets,) = dims
-        tids, active, positions, idf_sum, boost, avgdl = ins
+        s_pad, budget = dims
+        (packed,) = ins
+        idf_sum, boost, avgdl = lax.bitcast_convert_type(
+            packed[2 * s_pad + 1:], jnp.float32)
         p = A["postings"][self.field]
         n_pad = A["live"].shape[0]
         tf = phrase_ops.phrase_freqs(
-            p, tids, active, positions, budgets=budgets, n_pad=n_pad)
+            p, packed[:s_pad], packed[s_pad:2 * s_pad], packed[2 * s_pad],
+            budget=budget, n_pad=n_pad)
         matched = tf > 0
         if not self.scored:
             return jnp.zeros(n_pad, jnp.float32), matched
