@@ -17,12 +17,23 @@ caches):
   and, failing that, skips caching instead of dying — memory pressure
   degrades hit rate, never correctness.
 - **telemetry** — hit/miss/eviction counters stream into the metrics
-  registry as ``cache.<name>.{hits,misses,evictions}`` so
-  ``_nodes/stats`` exposes every cache without bespoke plumbing.
+  registry as ``cache.<name>.{hits,misses,evictions}`` (and
+  ``lock_waits``, below) so ``_nodes/stats`` exposes every cache
+  without bespoke plumbing.
 
-The lock is a plain RLock around an OrderedDict: removal listeners run
-under it and must not re-enter the cache.  ``clock`` is injectable so
-TTL tests never sleep.
+The lock is an RLock around an OrderedDict, and a critical section holds
+only pure-Python bookkeeping: the OrderedDict, the weight, the counts
+and the breaker's charge and release, which stay atomic with the
+eviction loop.  Removal listeners run under it too and must not
+re-enter the cache or block.  Nothing that can give up the interpreter
+lock or queue on another contended lock runs under it: the last
+reference to a removed value (a device array's free is a call into the
+runtime that gives the interpreter lock up, and every caller then
+queues on this lock behind a holder waiting to get it back) and the
+telemetry counters' increments (each takes its counter's lock) are
+applied after the release (``_Section``).  ``cache.<name>.lock_waits``
+counts the acquisitions that found the lock held.  ``clock`` is
+injectable so TTL tests never sleep.
 """
 
 from __future__ import annotations
@@ -84,6 +95,46 @@ class _Entry:
         self.expiry = expiry
 
 
+# the cache's telemetry counters, in the order _Section.__exit__ reads them
+_COUNTED = ("lock_waits", "hits", "misses", "evictions")
+
+
+class _Section:
+    """``with cache._section:`` — one critical section of ``cache``.
+
+    Entering tries the lock first; a failed try is one ``lock_waits``
+    and then a blocking acquire.  Leaving takes, still under the lock,
+    the entries ``_remove`` set aside and the counts not yet reported,
+    releases the lock, and only then increments the telemetry counters
+    and drops the removed entries — so no value is freed, and no
+    counter's lock taken, with the cache's lock held.  Its state lives
+    on the cache and is touched only by the holder, so one instance
+    serves every thread; each exit reports what no exit has yet."""
+
+    __slots__ = ("_cache",)
+
+    def __init__(self, cache: "Cache"):
+        self._cache = cache
+
+    def __enter__(self):
+        lock = self._cache._lock
+        if not lock.acquire(blocking=False):
+            lock.acquire()
+            self._cache._lock_waits += 1
+
+    def __exit__(self, *exc) -> None:
+        c = self._cache
+        counts = (c._lock_waits, c._hits, c._misses, c._evictions)
+        deltas = [n - r for n, r in zip(counts, c._reported)]
+        c._reported = counts
+        removed, c._removed = c._removed, []
+        c._lock.release()
+        for what, n in zip(_COUNTED, deltas):
+            if n:
+                _metrics().counter(f"cache.{c.name}.{what}").inc(n)  # metric-name-ok: bounded set of cache names
+        del removed                  # the last references, lock released
+
+
 class Cache:
     """Thread-safe weighted LRU cache.
 
@@ -109,12 +160,18 @@ class Cache:
         self._breaker_ref = breaker
         self._clock = clock
         self._lock = threading.RLock()
+        self._section = _Section(self)
         self._entries: "OrderedDict" = OrderedDict()
         self._weight = 0
         self._hits = 0
         self._misses = 0
         self._evictions = 0
         self._rejections = 0
+        self._lock_waits = 0
+        self._reported = (0, 0, 0, 0)   # _COUNTED as the counters have them
+        self._removed: list = []        # removed entries, freed after release
+        # registered now so a window's delta reads 0, not absent
+        _metrics().counter(f"cache.{name}.lock_waits")  # metric-name-ok: bounded set of cache names
 
     # -- breaker plumbing --------------------------------------------------
 
@@ -140,19 +197,22 @@ class Cache:
         if breaker is not None:
             breaker.release(weight)
 
-    # -- internals (call with the lock held) -------------------------------
+    # -- internals (call inside a section) ----------------------------------
 
-    def _remove(self, key, reason: str):
+    def _remove(self, key, reason: str) -> Optional[_Entry]:
+        """Take ``key`` out and hand its entry back; the entry is also
+        set aside for ``_Section`` to drop after the release."""
         entry = self._entries.pop(key, None)
         if entry is None:
-            return
+            return None
+        self._removed.append(entry)
         self._weight -= entry.weight
         self._release(entry.weight)
         if reason == EVICTED:
             self._evictions += 1
-            _metrics().counter(f"cache.{self.name}.evictions").inc()  # metric-name-ok: cache names are code-level identifiers
         if self.removal_listener is not None:
             self.removal_listener(key, entry.value, reason)
+        return entry
 
     def _evict_lru(self) -> bool:
         if not self._entries:
@@ -164,7 +224,7 @@ class Cache:
     # -- public API --------------------------------------------------------
 
     def get(self, key, default=None):
-        with self._lock:
+        with self._section:
             entry = self._entries.get(key)
             if entry is not None and entry.expiry is not None \
                     and self._clock() >= entry.expiry:
@@ -172,11 +232,9 @@ class Cache:
                 entry = None
             if entry is None:
                 self._misses += 1
-                _metrics().counter(f"cache.{self.name}.misses").inc()  # metric-name-ok: bounded set of cache names
                 return default
             self._entries.move_to_end(key)
             self._hits += 1
-            _metrics().counter(f"cache.{self.name}.hits").inc()  # metric-name-ok: bounded set of cache names
             return entry.value
 
     def get_or_load(self, key, loader: Callable):
@@ -196,7 +254,7 @@ class Cache:
         (single entry over max_weight, or the breaker refused even after
         evicting the whole cache)."""
         weight = int(self.weigher(key, value))
-        with self._lock:
+        with self._section:
             self._remove(key, REPLACED)
             if self.max_weight is not None and weight > self.max_weight:
                 self._rejections += 1
@@ -218,11 +276,11 @@ class Cache:
             return True
 
     def invalidate(self, key) -> None:
-        with self._lock:
+        with self._section:
             self._remove(key, EXPLICIT)
 
     def invalidate_all(self) -> None:
-        with self._lock:
+        with self._section:
             for key in list(self._entries):
                 self._remove(key, EXPLICIT)
 
@@ -230,7 +288,7 @@ class Cache:
         """Remove every entry where ``pred(key, value)`` is true;
         returns the number removed (targeted invalidation — e.g. one
         index's request-cache entries)."""
-        with self._lock:
+        with self._section:
             doomed = [k for k, e in self._entries.items()
                       if pred(k, e.value)]
             for key in doomed:
@@ -239,7 +297,7 @@ class Cache:
 
     def set_max_weight(self, max_weight: Optional[int]) -> None:
         """Dynamic resize; shrinking evicts immediately."""
-        with self._lock:
+        with self._section:
             self.max_weight = max_weight
             if max_weight is not None:
                 while self._weight > max_weight:
@@ -248,12 +306,12 @@ class Cache:
 
     def entries(self) -> list[tuple]:
         """Snapshot of (key, value, weight), LRU→MRU (stats walks)."""
-        with self._lock:
+        with self._section:
             return [(k, e.value, e.weight)
                     for k, e in self._entries.items()]
 
     def __len__(self) -> int:
-        with self._lock:
+        with self._section:
             return len(self._entries)
 
     @property
@@ -261,7 +319,7 @@ class Cache:
         return self._weight
 
     def stats(self) -> dict:
-        with self._lock:
+        with self._section:
             return {"entries": len(self._entries),
                     "memory_size_in_bytes": self._weight,
                     "hit_count": self._hits,

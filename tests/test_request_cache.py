@@ -14,6 +14,7 @@ import json
 import os
 import subprocess
 import sys
+import threading
 import time
 import urllib.error
 import urllib.request
@@ -165,6 +166,192 @@ def test_cache_invalidate_if_and_resize():
     assert len(c) == 3
     c.set_max_weight(10)             # dynamic shrink evicts immediately
     assert len(c) == 1
+
+
+class _Tracked:
+    """A value whose ``__del__`` records whether its cache's lock is held
+    (the free of a device array gives the interpreter lock up: ISSUE 40)."""
+
+    def __init__(self, cache, seen):
+        self.cache, self.seen = cache, seen
+
+    def __del__(self):
+        self.seen.append(self.cache._lock._is_owned())
+
+
+def _evict(c, seen):
+    c.put("a", _Tracked(c, seen))
+    c.put("b", "x")                  # over max_weight: a is the LRU
+
+
+def _replace(c, seen):
+    c.put("a", _Tracked(c, seen))
+    c.put("a", "x")
+
+
+def _expire(c, seen, now):
+    c.put("a", _Tracked(c, seen))
+    now[0] = 10.0
+    assert c.get("a") is None
+
+
+def _set_max(c, seen):
+    c.put("a", _Tracked(c, seen))
+    c.set_max_weight(0)
+
+
+@pytest.mark.parametrize("how", [
+    _evict, _replace, _expire,
+    lambda c, s: (c.put("a", _Tracked(c, s)), c.invalidate("a")),
+    lambda c, s: (c.put("a", _Tracked(c, s)), c.invalidate_all()),
+    lambda c, s: (c.put("a", _Tracked(c, s)),
+                  c.invalidate_if(lambda k, v: k == "a")),
+    _set_max,
+], ids=["evicted", "replaced", "expired", "invalidate", "invalidate_all",
+        "invalidate_if", "set_max_weight"])
+def test_cache_frees_removed_values_after_the_lock(how):
+    now, seen = [0.0], []
+    c = Cache("t.free_after", max_weight=10, ttl_s=5.0,
+              weigher=lambda k, v: 10, clock=lambda: now[0])
+    if how is _expire:
+        how(c, seen, now)
+    else:
+        how(c, seen)
+    assert seen == [False]           # freed once, and not under the lock
+    assert not c._lock._is_owned()
+
+
+def _lock_waits(name):
+    from opensearch_tpu.common.telemetry import metrics
+    return metrics().counter(f"cache.{name}.lock_waits").value
+
+
+def test_cache_lock_waits_counts_a_contended_get():
+    c = Cache("t.lock_waits")
+    c.put("k", "v")
+    c.get("k")
+    assert _lock_waits("t.lock_waits") == 0      # nothing contends
+    c._lock.acquire()
+    t = threading.Thread(target=c.get, args=("k",), name="t-lock-waits",
+                         daemon=True)
+    try:
+        t.start()
+        deadline = time.monotonic() + 10.0
+        # the lock is ours, so a thread inside the section's __enter__
+        # has failed its try or is about to
+        while (getattr(sys._current_frames().get(t.ident), "f_code", None)
+               is None or sys._current_frames()[t.ident].f_code.co_name
+               != "__enter__"):
+            assert time.monotonic() < deadline, "get never reached the lock"
+            time.sleep(0.001)
+    finally:
+        c._lock.release()
+    t.join(timeout=10.0)
+    assert not t.is_alive()
+    assert _lock_waits("t.lock_waits") == 1 and c._lock_waits == 1
+    assert c.stats()["hit_count"] == 2           # stats() does not show it
+
+
+def test_cache_counters_exact_under_contention():
+    """Sixteen threads, a short switch interval: every count the sections
+    defer to after the release still lands once."""
+    from opensearch_tpu.common.telemetry import metrics
+    svc = CircuitBreakerService({"breaker.request.limit": 10_000,
+                                 "breaker.total.limit": 20_000})
+    c = Cache("t.contended", max_weight=200, weigher=lambda k, v: 10,
+              breaker=svc.request)
+
+    def work(i):
+        for j in range(300):
+            key = (i * 7 + j) % 40
+            if c.get(key) is None:
+                c.put(key, [i, j])
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,), daemon=True,
+                                    name=f"t-contended-{i}")
+                   for i in range(16)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60.0)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(old)
+    s = c.stats()
+    assert s["hit_count"] + s["miss_count"] == 16 * 300
+    for what, n in (("hits", s["hit_count"]), ("misses", s["miss_count"]),
+                    ("evictions", s["evictions"]),
+                    ("lock_waits", c._lock_waits)):
+        assert metrics().counter(f"cache.t.contended.{what}").value == n
+    assert svc.request.used == c.weight <= 200
+    assert c._removed == []
+
+
+def _scripted_sequence():
+    now, seen, trail = [0.0], [], []
+    svc = CircuitBreakerService({"breaker.request.limit": 100,
+                                 "breaker.total.limit": 1000})
+    c = Cache("t.scripted", max_weight=70, ttl_s=5.0,
+              weigher=lambda k, v: 10 * len(v), clock=lambda: now[0],
+              removal_listener=lambda k, v, r: seen.append((k, r)),
+              breaker=svc.request)
+
+    def step(out):
+        s = c.stats()
+        trail.append((out, s["entries"], s["memory_size_in_bytes"],
+                      s["hit_count"], s["miss_count"], s["evictions"],
+                      s["rejections"], svc.request.used))
+    step(c.put("a", "xx"))
+    step(c.put("b", "xxx"))
+    step(c.get("a"))
+    step(c.put("c", "xxxx"))          # over 70: b is the LRU
+    step(c.get("b"))
+    step(c.put("a", "x"))             # replaced
+    svc.request.add_estimate(40, "other")
+    step(c.put("d", "xxx"))           # the breaker sheds c
+    svc.request.release(40)
+    step(c.put("e", "x" * 8))         # over max_weight: rejected
+    now[0] = 3.0
+    step(c.put("f", "xx"))
+    now[0] = 5.5
+    step(c.get("a"))                  # expired
+    step(c.get("f"))
+    step(c.invalidate("d"))
+    step(c.put("g", "x"))
+    step(c.put("h", "xx"))
+    step(c.invalidate_if(lambda k, v: len(v) == 1))
+    step(c.set_max_weight(10))
+    step(c.put("i", "x"))
+    step(c.invalidate_all())
+    return trail, seen
+
+
+def test_cache_scripted_sequence_matches_the_parent():
+    """What ``Cache`` gave before ISSUE 40 over one scripted sequence:
+    (return, entries, bytes, hits, misses, evictions, rejections, the
+    breaker's ``used``) after each step, and the listener's reasons."""
+    from opensearch_tpu.common.telemetry import metrics
+    trail, seen = _scripted_sequence()
+    assert trail == [
+        (True, 1, 20, 0, 0, 0, 0, 20), (True, 2, 50, 0, 0, 0, 0, 50),
+        ("xx", 2, 50, 1, 0, 0, 0, 50), (True, 2, 60, 1, 0, 1, 0, 60),
+        (None, 2, 60, 1, 1, 1, 0, 60), (True, 2, 50, 1, 1, 1, 0, 50),
+        (True, 2, 40, 1, 1, 2, 0, 80), (False, 2, 40, 1, 1, 2, 1, 40),
+        (True, 3, 60, 1, 1, 2, 1, 60), (None, 2, 50, 1, 2, 2, 1, 50),
+        ("xx", 2, 50, 2, 2, 2, 1, 50), (None, 1, 20, 2, 2, 2, 1, 20),
+        (True, 2, 30, 2, 2, 2, 1, 30), (True, 3, 50, 2, 2, 2, 1, 50),
+        (1, 2, 40, 2, 2, 2, 1, 40), (None, 0, 0, 2, 2, 4, 1, 0),
+        (True, 1, 10, 2, 2, 4, 1, 10), (None, 0, 0, 2, 2, 4, 1, 0)]
+    assert seen == [("b", EVICTED), ("a", REPLACED), ("c", EVICTED),
+                    ("a", EXPIRED), ("d", EXPLICIT), ("g", EXPLICIT),
+                    ("f", EVICTED), ("h", EVICTED), ("i", EXPLICIT)]
+    counters = {w: metrics().counter(f"cache.t.scripted.{w}").value
+                for w in ("hits", "misses", "evictions", "lock_waits")}
+    assert counters == {"hits": 2, "misses": 2, "evictions": 4,
+                        "lock_waits": 0}
 
 
 # -- REST end-to-end -------------------------------------------------------
