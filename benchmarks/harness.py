@@ -223,9 +223,23 @@ class Served:
         shutil.rmtree(self.data_path, ignore_errors=True)
 
 
+def served_on_the_device(resp: dict) -> None:
+    """Raises unless every shard of a profiled response ran on the device
+    (a shard that names no path ran there)."""
+    shards = (resp.get("profile") or {}).get("shards")
+    if not shards:
+        raise RuntimeError("the profiled request came back with no shard's "
+                           "profile")
+    for n, shard in enumerate(shards):
+        path = (shard.get("engine") or {}).get("execution_path", "device")
+        if path != "device":
+            raise RuntimeError(f"profiled request ran on [{path}] in shard "
+                               f"{n} of {len(shards)}")
+
+
 def warm_programs(cell: Cell, served: Served, data) -> int:
     """One crafted request per program the configuration can need, the
-    first one profiled to see that the device serves it."""
+    first one profiled to see that the device serves every shard."""
     crafted = cell.kind.warmup_queries(cell.cfg, data)
     for n, (_sig, q) in enumerate(crafted):
         body = cell.kind.body(cell.cfg, q)
@@ -235,10 +249,7 @@ def warm_programs(cell: Cell, served: Served, data) -> int:
         if not compare.usable(resp):
             raise RuntimeError(f"warm-up request degraded: {resp}")
         if n == 0:
-            engine = resp["profile"]["shards"][0].get("engine", {})
-            path = engine.get("execution_path", "device")
-            if path != "device":
-                raise RuntimeError(f"profiled request ran on [{path}]")
+            served_on_the_device(resp)
     return len(crafted)
 
 
@@ -509,6 +520,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, traced: bool, *,
                 result["metrics"][m["name"]] = {"value": value,
                                                 "unit": m["unit"]}
         if summary:
+            say(f"trace: {summary['devices']} chip(s) busy "
+                f"{summary['busy_s']:.4f}s each in the mean, "
+                f"{summary['busy_any_s']:.4f}s any of them, of "
+                f"{summary['window_s']:.4f}s")
             result["device"].update(busy_s=summary["busy_s"],
                                     window_s=summary["window_s"])
             result["breakdown"] = {

@@ -74,49 +74,56 @@ def markers(planes: list) -> tuple:
 
 
 def reduce(planes: list) -> dict:
-    """{"window_s", "busy_s", "devices", "ops": {name: s}, "modules":
-    {name: s}, "gaps": [(start_ns, end_ns)], "t0_ns"}: busy time is the
-    union of the device's operation intervals, averaged over the device
-    planes; operations and programs are summed over them.  Returns {} when
-    no device plane holds an operation: there is then nothing to read."""
+    """{"window_s", "busy_s", "busy_any_s", "devices", "ops": {name: s},
+    "modules": {name: s}, "gaps": [(start_ns, end_ns)], "t0_ns"}: a
+    device's busy time is the union of its operation intervals; ``busy_s``
+    is their mean over the device planes, ``busy_any_s`` the union over
+    all of them.  Operations and programs are summed over the planes.  A
+    gap is a stretch of the window in which no device runs an operation.
+    Returns {} when no device plane holds an operation: there is then
+    nothing to read."""
     window = markers(planes)
-    per_device, ops, modules, gaps = [], {}, {}, []
+    devices = []                         # (lines by name, events)
     for name, lines in planes:
         if not name.startswith(DEVICE_PREFIX):
             continue
         by_line = dict(lines)
         events = by_line.get(OPS_LINE) or by_line.get(MODULES_LINE) or []
-        if window is None and events:
-            window = (min(s for _, s, _ in events),
-                      max(s + d for _, s, d in events))
-        if not events:
-            continue
+        if events:
+            devices.append((by_line, events))
+    if window is None and devices:
+        window = (min(s for _, ev in devices for _, s, _ in ev),
+                  max(s + d for _, ev in devices for _, s, d in ev))
+    per_device, ops, modules, every = [], {}, {}, []
+    for by_line, events in devices:
         t0, t1 = window
         clipped = _clip(events, t0, t1)
         busy = merge([(a, b) for _, a, b in clipped])
         per_device.append(sum(b - a for a, b in busy))
+        every.extend(busy)
         for ename, a, b in clipped:
             ops[ename] = ops.get(ename, 0.0) + (b - a) / 1e9
         for ename, a, b in _clip(by_line.get(MODULES_LINE, []), t0, t1):
             modules[ename] = modules.get(ename, 0.0) + (b - a) / 1e9
-        if not gaps:                     # the first device's gaps
-            edges = [t0] + [x for ab in busy for x in ab] + [t1]
-            gaps = [(edges[i], edges[i + 1])
-                    for i in range(0, len(edges), 2)
-                    if edges[i + 1] > edges[i]]
     if not per_device or sum(per_device) <= 0:
         return {}
+    union = merge(every)
+    edges = [window[0]] + [x for ab in union for x in ab] + [window[1]]
+    gaps = [(edges[i], edges[i + 1]) for i in range(0, len(edges), 2)
+            if edges[i + 1] > edges[i]]
     return {"window_s": (window[1] - window[0]) / 1e9,
             "busy_s": sum(per_device) / len(per_device) / 1e9,
+            "busy_any_s": sum(b - a for a, b in union) / 1e9,
             "devices": len(per_device), "ops": ops, "modules": modules,
             "gaps": gaps, "t0_ns": window[0]}
 
 
 def kernel_seconds(summary: dict, match: str = "") -> float:
     """Seconds of device operations inside programs whose name contains
-    ``match`` (every operation when it is empty).  Where the trace has no
-    line of programs, the operations' own names are matched."""
-    if not match:                    # the union: nested ops count once
+    ``match`` (every operation when it is empty), summed over the devices.
+    Where the trace has no line of programs, the operations' own names are
+    matched."""
+    if not match:                    # each device's union: nested ops once
         return summary["busy_s"] * summary["devices"]
     table = summary["modules"] or summary["ops"]
     return sum(s for name, s in table.items() if match in name)

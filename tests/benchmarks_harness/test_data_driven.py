@@ -6,7 +6,7 @@ import os
 import shutil
 
 from benchmarks import harness
-from bench_tiny import TINY, last_line_ok, run_tiny
+from bench_tiny import TINY, assert_joins_up, last_line_ok, run_tiny
 
 import dataclasses
 
@@ -67,21 +67,4 @@ def test_a_new_cell_and_a_new_metric_are_entries_and_files(
 
 
 def test_committed_benchmark_joins_up():
-    bench = harness._json(os.path.join(harness.ROOT, "BENCHMARK.json"))
-    for w in bench["workloads"]:
-        cell = harness.load_cell(w["name"])
-        assert cell.cfg["name"] == w["config"]
-        assert cell.mix["loop"] in ("closed", "paced")
-        assert hasattr(cell.reference, "Reference")
-        names = [m["name"] for m in cell.metrics("end_to_end")]
-        assert "setup_s" in names and len(names) >= 2
-        assert cell.metrics("per_layer")
-        assert set(cell.cfg["limits"]) == {"failed", "malformed",
-                                           "score_err", "rank_gap",
-                                           "device_faults"}
-    for c in bench["configs"]:
-        assert os.path.exists(os.path.join(
-            harness.ROOT, c["file"].replace(".json", ".reference.py")))
-        cfg = harness._json(os.path.join(harness.ROOT, c["file"]))
-        assert cfg["source"] == c["source"]
-        assert sorted(cfg["reduced"]) == sorted(c["reduced"])
+    assert_joins_up(harness.ROOT)

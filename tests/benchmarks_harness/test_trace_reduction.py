@@ -61,6 +61,109 @@ def test_without_markers_the_device_events_span_the_window():
     assert s["busy_s"] == pytest.approx(0.055)
 
 
+def one_plane() -> list:
+    """``synthetic`` with an operation and a program across each edge of
+    the bracketed window."""
+    host, (name, lines) = synthetic()
+    by_line = dict(lines)
+    by_line["XLA Ops"] = by_line["XLA Ops"] + [
+        ("%fusion.9", 95 * MS, 10 * MS), ("%fusion.1", -5 * MS, 7 * MS)]
+    by_line["XLA Modules"] = by_line["XLA Modules"] + [
+        ("jit_run_topk(1)", 95 * MS, 10 * MS),
+        ("jit_run_full(4)", -5 * MS, 7 * MS)]
+    return [host, (name, list(by_line.items()))]
+
+
+# what the reduction of one device plane returned before it read several,
+# field for field: with the markers, and without them
+ONE_PLANE = {
+    "window_s": 0.1, "busy_s": 0.052, "devices": 1,
+    "ops": {"%fusion.3 = gather": 0.03, "%sort": 0.005, "%while.4": 0.01,
+            "%fusion.40": 0.004, "%fusion.9": 0.005, "%fusion.1": 0.002},
+    "modules": {"jit_run_topk(1)": 0.04, "jit_knn_topk(2)": 0.01,
+                "jit_run_full(4)": 0.002},
+    "gaps": [(2000000.0, 10000000.0), (35000000.0, 50000000.0),
+             (60000000.0, 80000000.0), (90000000.0, 95000000.0)],
+    "t0_ns": 0.0}
+ONE_PLANE_UNMARKED = {
+    "window_s": 0.135, "busy_s": 0.072, "devices": 1,
+    "ops": {"%fusion.3 = gather": 0.04, "%sort": 0.005, "%while.4": 0.01,
+            "%fusion.40": 0.004, "%fusion.9": 0.01, "%fusion.1": 0.007},
+    "modules": {"jit_run_topk(1)": 0.045000000000000005,
+                "jit_knn_topk(2)": 0.01, "jit_run_full(4)": 0.007},
+    "gaps": [(2000000.0, 10000000.0), (35000000.0, 50000000.0),
+             (60000000.0, 80000000.0), (90000000.0, 95000000.0),
+             (105000000.0, 120000000.0)],
+    "t0_ns": -5000000.0}
+
+
+@pytest.mark.parametrize("marked,recorded", [(True, ONE_PLANE),
+                                             (False, ONE_PLANE_UNMARKED)])
+def test_one_plane_reads_as_it_did(marked, recorded):
+    planes = one_plane() if marked else one_plane()[1:]
+    s = trace.reduce(planes)
+    assert s.pop("busy_any_s") == s["busy_s"]      # one plane: the same
+    assert s == recorded
+
+
+def four_planes() -> list:
+    """Four chips over a bracketed 100 ms; each plane's programs cover
+    its operations.  Chip 0 idles from 30 to 50 ms while chip 1 works
+    until 40: only 40-50 is a gap."""
+    spans = {0: [(10, 30), (50, 60)], 1: [(20, 40)], 2: [(55, 70)],
+             3: [(80, 90), (120, 130)]}          # 120-130: past the end
+    host = [(trace.MARK_BEGIN, 0.0, 1000.0), (trace.MARK_END, 100 * MS,
+                                              1000.0)]
+    planes = [("/host:CPU", [("bench", host)])]
+    for chip, busy in spans.items():
+        ops = [(f"%fusion.{chip}", a * MS, (b - a) * MS) for a, b in busy]
+        modules = [(f"jit_knn_topk({chip})", a * MS, (b - a) * MS)
+                   for a, b in busy]
+        planes.append((f"/device:TPU:{chip}",
+                       [("XLA Modules", modules), ("XLA Ops", ops)]))
+    return planes
+
+
+def test_four_planes_gap_only_where_every_chip_idles():
+    s = trace.reduce(four_planes())
+    assert s["devices"] == 4
+    assert s["window_s"] == pytest.approx(0.100)
+    # each chip's busy time 30, 20, 15, 10 ms: their mean, and their union
+    assert s["busy_s"] == pytest.approx(0.075 / 4)
+    assert s["busy_any_s"] == pytest.approx(0.060)   # 10-40, 50-70, 80-90
+    gaps = [(round(a / MS), round(b / MS)) for a, b in s["gaps"]]
+    assert gaps == [(0, 10), (40, 50), (70, 80), (90, 100)]
+    assert s["ops"] == {"%fusion.0": pytest.approx(0.030),
+                        "%fusion.1": pytest.approx(0.020),
+                        "%fusion.2": pytest.approx(0.015),
+                        "%fusion.3": pytest.approx(0.010)}
+    assert trace.kernel_seconds(s) == pytest.approx(0.075)
+    assert trace.kernel_seconds(s, "knn_topk") == pytest.approx(0.075)
+    assert trace.kernel_seconds(s, "knn_topk(1)") == pytest.approx(0.020)
+    table = dict(trace.gap_breakdown(
+        s, lambda a, b: "edge" if a == 0 or b == 100 * MS else "between"))
+    assert table == {"edge": pytest.approx(0.020),
+                     "between": pytest.approx(0.020)}
+
+
+def test_readers_on_four_planes():
+    """Idle is the mean chip's; kernel time and the roofline's device
+    time are summed over the chips."""
+    cell = tiny_cell("sift_paced")
+    full = dict(cell.cfg, n_docs=1_000_000, dim=128)
+    ctx = {"trace": trace.reduce(four_planes()),
+           "trace_queries": [None] * 12, "kind": knn_exact, "cfg": full,
+           "data": None, "device_kind": "TPU v5 lite"}
+    assert readers.trace_idle(ctx) == pytest.approx(81.25)
+    assert readers.trace_kernel_time(ctx, per="query") == pytest.approx(
+        75.0 / 12)
+    assert readers.trace_kernel_time(ctx, match="knn_topk") == \
+        pytest.approx(75.0)
+    # 12 queries x 0.625 ms over 75 ms of knn_topk programs on four chips
+    assert readers.roofline_bytes(ctx, match="knn_topk") == pytest.approx(
+        10.0, rel=2e-3)
+
+
 def test_no_device_plane_nothing_to_read():
     assert trace.reduce([("/host:CPU", [("python", [("x", 0.0, 5.0)])])]) \
         == {}
