@@ -104,8 +104,6 @@ class KernelCompileRegistry:
     # bootstrap are the same reason profile.py resolved them lazily)
     _DEFAULTS = (
         ("plan.run_topk", "opensearch_tpu.search.plan", "run_topk"),
-        ("plan.run_topk_parts", "opensearch_tpu.search.plan",
-         "run_topk_parts"),
         ("plan.run_full", "opensearch_tpu.search.plan", "run_full"),
         ("plan.topk_from_scores", "opensearch_tpu.search.plan",
          "topk_from_scores"),
@@ -114,6 +112,10 @@ class KernelCompileRegistry:
         ("knn.knn_scores", "opensearch_tpu.ops.knn", "knn_scores"),
         ("knn.knn_topk", "opensearch_tpu.ops.knn", "knn_topk"),
         ("knn.knn_topk_batch", "opensearch_tpu.ops.knn", "knn_topk_batch"),
+        ("mesh.run_topk_mesh", "opensearch_tpu.parallel.dist_search",
+         "run_topk_mesh"),
+        ("mesh.merge_topk_mesh", "opensearch_tpu.parallel.dist_search",
+         "merge_topk_mesh"),
     )
 
     def __init__(self):
@@ -185,7 +187,8 @@ class _Group:
 
     __slots__ = ("index", "shard", "segment", "entries", "staged_tick",
                  "dispatches", "last_dispatch_tick", "sealed",
-                 "evict_cb", "evict_class", "_gid", "__weakref__")
+                 "evict_cb", "evict_class", "device", "_gid",
+                 "__weakref__")
 
     def __init__(self, index: str, shard, segment: str,
                  evict_cb: Optional[Callable] = None,
@@ -200,6 +203,7 @@ class _Group:
         self.sealed = False                   # unsealed groups never evict
         self.evict_cb = evict_cb              # None -> not evictable
         self.evict_class = evict_class        # "page" evicts before "segment"
+        self.device = None                    # id of the chip it stages on
 
     def nbytes(self) -> int:
         return sum(self.entries.values())
@@ -209,7 +213,8 @@ class _Group:
         for (kind, _f, _n), b in self.entries.items():
             by_kind[kind] = by_kind.get(kind, 0) + b
         return {"index": self.index, "shard": self.shard,
-                "segment": self.segment, "bytes": self.nbytes(),
+                "segment": self.segment, "device": self.device,
+                "bytes": self.nbytes(),
                 "entries": len(self.entries),
                 "by_kind": dict(sorted(by_kind.items())),
                 "staged_tick": self.staged_tick,
@@ -253,6 +258,7 @@ class DeviceResidencyLedger:
         self.block_topk_programs = 0
         self.sorted_bag_programs = 0
         self.phrase_programs = 0
+        self.mesh_programs = 0
         self._evicted_bytes = 0
         self._transfers = {
             "stage": {"bytes": 0, "ops": 0, "seconds": 0.0},
@@ -314,22 +320,30 @@ class DeviceResidencyLedger:
         t0 = time.monotonic()
         out = jnp.asarray(host_array)      # staging-ok: the ledger itself
         dt = time.monotonic() - t0
+        if group is not None and group.device is None:
+            group.device = next(iter(out.devices())).id
         self._record(group, (kind, field, name),
                      int(getattr(host_array, "nbytes", None)
                          or out.nbytes), dt)
         return out
 
-    def stage_input(self, host_array):
+    def stage_input(self, host_array, sharding=None):
         """Transfer-only staging of ONE per-query input (term ids, a
         query vector, a scalar): the ``jnp.asarray`` and a count under
         ``transfers.input``, the outbound mirror of ``record_fetch``'s
         ``arrays``.  Nothing becomes resident here and no group owns
         it: the prepared-bindings cache holds the result for as long as
         the query may come again.  Untimed: the copy is asynchronous and
-        two clock reads would cost what a small one does."""
+        two clock reads would cost what a small one does.  With a
+        ``sharding``, one copy of the shards' inputs stacked, placed
+        over the mesh (``MeshSearcher``)."""
         import jax.numpy as jnp
 
-        out = jnp.asarray(host_array)      # staging-ok: the ledger itself
+        if sharding is None:
+            out = jnp.asarray(host_array)  # staging-ok: the ledger itself
+        else:
+            import jax
+            out = jax.device_put(host_array, sharding)  # staging-ok: the ledger itself
         with self._lock:
             t = self._transfers["input"]
             t["bytes"] += int(getattr(host_array, "nbytes", None)
@@ -398,7 +412,8 @@ class DeviceResidencyLedger:
                         slice_gather: bool = False,
                         block_topk: bool = False,
                         sorted_bag: bool = False,
-                        phrase: bool = False) -> None:
+                        phrase: bool = False,
+                        mesh: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
         signal budget eviction orders by.  ``slice_gather``: the
         program's static shape took ``gather_postings``'s contiguous-
@@ -410,8 +425,13 @@ class DeviceResidencyLedger:
         postings, without the dense accumulator (``Plan.sorted_topk``,
         i.e. ``ops/bm25.py::sorted_bag``, over a segment with no
         deleted doc).  ``phrase``: the program holds a ``PhrasePlan``
-        somewhere in its plan (``plan.phrase_dims`` of its key)."""
+        somewhere in its plan (``plan.phrase_dims`` of its key).
+        ``mesh``: one program over the chips of a mesh (a segment index
+        of every shard, or the merge; ``MeshSearcher``): it belongs to
+        no one segment's group, and ``stats()`` adds it to
+        ``dispatches``."""
         with self._lock:
+            self.mesh_programs += bool(mesh)
             self.slice_gather_programs += bool(slice_gather)
             self.block_topk_programs += bool(block_topk)
             self.sorted_bag_programs += bool(sorted_bag)
@@ -537,13 +557,18 @@ class DeviceResidencyLedger:
             block_topks = self.block_topk_programs
             sorted_bags = self.sorted_bag_programs
             phrases = self.phrase_programs
+            meshes = self.mesh_programs
         per_index: dict[str, dict] = {}
+        per_device: dict[str, int] = {}
         resident = 0
-        dispatches = 0
+        dispatches = meshes
         for g in groups:
             b = g.nbytes()
             resident += b
             dispatches += g.dispatches
+            if g.device is not None:
+                per_device[str(g.device)] = per_device.get(
+                    str(g.device), 0) + b
             ix = per_index.setdefault(
                 g.index, {"bytes": 0, "segments": 0, "dispatches": 0})
             ix["bytes"] += b
@@ -557,6 +582,7 @@ class DeviceResidencyLedger:
             "block_topk_programs": block_topks,
             "sorted_bag_programs": sorted_bags,
             "phrase_programs": phrases,
+            "mesh_programs": meshes,
             "budget": {
                 "budget_bytes": budget or 0,
                 "evictions": ev,
@@ -567,6 +593,7 @@ class DeviceResidencyLedger:
             "transfers": transfers,
             "pager": device_pager().stats(),
             "indices": dict(sorted(per_index.items())),
+            "devices": dict(sorted(per_device.items())),
             "compile_registry": kernel_registry().counts(),
             "backend": _backend_memory_stats(),
         }
@@ -632,6 +659,7 @@ class DeviceResidencyLedger:
             self.evictions = self.restages = self.host_fallbacks = 0
             self.slice_gather_programs = self.block_topk_programs = 0
             self.sorted_bag_programs = self.phrase_programs = 0
+            self.mesh_programs = 0
             self._evicted_bytes = 0
             for t in self._transfers.values():
                 for key in t:

@@ -384,14 +384,19 @@ class DeviceSegment:
     Padding scheme: ``n_pad >= n_docs + 1`` so slot ``n_docs`` is a dead
     scatter target for padded postings/value entries; ``live`` is False on
     all padding slots so they can never reach the top-k.
+
+    ``pads`` raises the padded lengths (keys as ``natural_pads`` gives
+    them): the mesh stages the segments of one segment index in every
+    shard to one shape, so that they form one array over the chips.
     """
 
-    def __init__(self, seg: Segment):
+    def __init__(self, seg: Segment, pads: Optional[dict] = None):
         import opensearch_tpu.common.jaxenv  # noqa: F401
 
         self.seg = seg
+        self.pads = dict(pads or {})
         self.n_docs = seg.n_docs
-        self.n_pad = pad_pow2(seg.n_docs + 1)
+        self.n_pad = self._pad("n_pad", seg.n_docs + 1)
         n_pad = self.n_pad
         # HBM budget: the breaker estimate comes from the ONE footprint
         # source of truth (device_ledger.host_footprint; padding roughly
@@ -455,7 +460,7 @@ class DeviceSegment:
             # offsets padded by repeating the final cumulative value so
             # padded term ids decode as empty ranges and the array shape
             # stays bucketed (compile-cache sharing across segments).
-            t_pad = pad_pow2(len(pf.offsets))
+            t_pad = self._pad(("offsets", name), len(pf.offsets))
             self.postings[name] = {
                 "offsets": stage(pad1(pf.offsets, t_pad, pf.offsets[-1]),
                                  "postings", name, "offsets"),
@@ -468,7 +473,7 @@ class DeviceSegment:
                 self.ensure_postings(name)
         self.numeric: dict[str, dict] = {}
         for name, dv in seg.numeric_dv.items():
-            v_pad = pad_pow2(len(dv.values))
+            v_pad = self._pad(("numeric", name), len(dv.values))
             vals = dv.values
             self.numeric[name] = {
                 "values": stage(pad1(vals, v_pad, 0),
@@ -491,7 +496,7 @@ class DeviceSegment:
             }
         self.ordinal: dict[str, dict] = {}
         for name, dv in seg.ordinal_dv.items():
-            v_pad = pad_pow2(len(dv.ords))
+            v_pad = self._pad(("ordinal", name), len(dv.ords))
             self.ordinal[name] = {
                 "ords": stage(pad1(dv.ords, v_pad, -1),
                               "ordinal", name, "ords"),
@@ -517,7 +522,7 @@ class DeviceSegment:
             }
         self.geo: dict[str, dict] = {}
         for name, dv in seg.geo_dv.items():
-            v_pad = pad_pow2(len(dv.lats))
+            v_pad = self._pad(("geo", name), len(dv.lats))
             self.geo[name] = {
                 "lats": stage(pad1(dv.lats, v_pad, 0.0),
                               "geo", name, "lats"),
@@ -538,6 +543,26 @@ class DeviceSegment:
         # accruing into it)
         led.seal(group)
 
+    def _pad(self, key, n: int) -> int:
+        return max(pad_pow2(n), self.pads.get(key, 0))
+
+    @staticmethod
+    def natural_pads(seg: Segment) -> dict:
+        """The padded lengths a staging of ``seg`` takes by itself, by
+        the keys ``pads`` raises."""
+        out = {"n_pad": pad_pow2(seg.n_docs + 1)}
+        for name, pf in seg.postings.items():
+            out["offsets", name] = pad_pow2(len(pf.offsets))
+            out["doc_ids", name] = pad_pow2(len(pf.doc_ids))
+            out["pos_offsets", name] = pad_pow2(len(pf.pos_offsets))
+            out["positions", name] = pad_pow2(len(pf.positions))
+        for group, dvs, attr in (("numeric", seg.numeric_dv, "values"),
+                                 ("ordinal", seg.ordinal_dv, "ords"),
+                                 ("geo", seg.geo_dv, "lats")):
+            for name, dv in dvs.items():
+                out[group, name] = pad_pow2(len(getattr(dv, attr)))
+        return out
+
     def ensure_postings(self, field: str) -> Optional[dict]:
         """Full per-posting device arrays (doc_ids/tfs/positions) for
         ``field``, staged on demand.
@@ -551,8 +576,8 @@ class DeviceSegment:
         if p is None or "doc_ids" in p:
             return p
         pf = self.seg.postings[field]
-        p_pad = pad_pow2(len(pf.doc_ids))
-        pos_pad = pad_pow2(len(pf.positions))
+        p_pad = self._pad(("doc_ids", field), len(pf.doc_ids))
+        pos_pad = self._pad(("positions", field), len(pf.positions))
         led = self._ledger
         group = self._ledger_group
 
@@ -572,7 +597,8 @@ class DeviceSegment:
         # entry, so a term's positions are one contiguous slice of
         # ``positions``).
         p["pos_offsets"] = stage(
-            pad1(pf.pos_offsets, pad_pow2(len(pf.pos_offsets)),
+            pad1(pf.pos_offsets,
+                 self._pad(("pos_offsets", field), len(pf.pos_offsets)),
                  pf.pos_offsets[-1] if len(pf.pos_offsets) else 0),
             "pos_offsets")
         p["positions"] = stage(pad1(pf.positions, pos_pad, 0),
@@ -632,7 +658,8 @@ class DeviceSegment:
                 host_imp, _mx = self.seg.impact_table(field, avgdl)
                 # padded like doc_ids/tfs even when those are lazily
                 # staged (quantized segments): same bucketed shape
-                p_pad = pad_pow2(len(self.seg.postings[field].doc_ids))
+                p_pad = self._pad(("doc_ids", field),
+                                  len(self.seg.postings[field].doc_ids))
                 padded = np.zeros(p_pad, np.float32)
                 padded[: len(host_imp)] = host_imp
                 imp = self._ledger.stage(       # quantize-ok

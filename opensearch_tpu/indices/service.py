@@ -852,7 +852,8 @@ class IndexService:
     def _use_mesh(self, body: dict) -> bool:
         """Route through the device-collective scatter-gather when the
         index opted in, shards fit the mesh, and the request is a scored
-        top-k (sort/aggs reduce on the host path for now).  Semantics
+        top-k (sort/aggs reduce on the host path for now), profiled or
+        not (the mesh profiles every shard).  Semantics
         match the multi-node cluster path: per-shard scoring stats
         (query_then_fetch), vs the merged-searcher host path's global
         stats."""
@@ -862,10 +863,6 @@ class IndexService:
         if len(self.local_shards) < 2:
             return False
         if body.get("sort") is not None:
-            return False
-        if body.get("profile"):
-            # phase attribution instruments the host pipeline; profiled
-            # requests route there (hits are parity-tested identical)
             return False
         q = body.get("query")
         if isinstance(q, dict) and "hybrid" in q:
@@ -1011,53 +1008,16 @@ class IndexService:
 
     def _host_scatter_search(self, body: dict) -> dict:
         """Mesh-unavailable fallback: the same scatter-gather the device
-        collective performs, on the host — every local shard queries its
-        OWN searcher (per-shard scoring stats, query_then_fetch
-        semantics identical to the mesh and the multi-node coordinator)
-        and the top-k merges with the coordinator's tie-break order."""
-        from opensearch_tpu.search.aggs import reduce_aggs
-        from opensearch_tpu.search.executor import merge_hit_rows
-        from opensearch_tpu.search.suggest import merge_suggest
+        collective performs, on the host (``executor.scatter_merge``
+        over every local shard's OWN searcher: per-shard scoring stats,
+        query_then_fetch semantics identical to the mesh and the
+        multi-node coordinator, the coordinator's tie-break order)."""
+        from opensearch_tpu.search.executor import scatter_merge
 
-        t0 = time.monotonic()
-        size = int(body.get("size", 10)
-                   if body.get("size") is not None else 10)
-        from_ = int(body.get("from", 0) or 0)
-        aggs_json = body.get("aggs") or body.get("aggregations")
-        sub = dict(body)
-        sub["from"] = 0
-        sub["size"] = from_ + size
         with self._lock:
             searchers = [self.local_shards[s].acquire_searcher()
                          for s in sorted(self.local_shards)]
-        shard_resps = [s.search(sub, agg_partials=bool(aggs_json))
-                       for s in searchers]
-        rows = []
-        total = 0
-        max_score = None
-        for si, r in enumerate(shard_resps):
-            for pos, h in enumerate(r["hits"]["hits"]):
-                rows.append((h, si, pos))
-            total += r["hits"]["total"]["value"]
-            ms_ = r["hits"]["max_score"]
-            if ms_ is not None and (max_score is None or ms_ > max_score):
-                max_score = ms_
-        all_hits = merge_hit_rows(rows, body.get("sort"))
-        resp = {
-            "took": int((time.monotonic() - t0) * 1000),
-            "timed_out": any(r.get("timed_out") for r in shard_resps),
-            "hits": {"total": {"value": total, "relation": "eq"},
-                     "max_score": max_score,
-                     "hits": all_hits[from_: from_ + size]},
-        }
-        if aggs_json:
-            resp["aggregations"] = reduce_aggs(
-                aggs_json, [r.get("aggregation_partials") or {}
-                            for r in shard_resps])
-        if body.get("suggest"):
-            resp["suggest"] = merge_suggest(
-                [r.get("suggest") for r in shard_resps])
-        return resp
+        return scatter_merge(searchers, body)
 
     def msearch(self, bodies: list) -> list[dict]:
         """Batched multi-search over the node-local searcher (term-bag

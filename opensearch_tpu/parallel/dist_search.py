@@ -18,6 +18,9 @@ docs.
 
 from __future__ import annotations
 
+import collections
+import threading
+import time
 from functools import partial
 from typing import Optional
 
@@ -29,7 +32,11 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from opensearch_tpu.common.telemetry import metrics as _metrics
+from opensearch_tpu.common.telemetry import tracer as _tracer
 from opensearch_tpu.ops import bm25 as bm25_ops
+from opensearch_tpu.ops import topk as topk_ops
+from opensearch_tpu.search.fetch import filter_source
 
 
 def make_mesh(n_devices: int, axis: str = "shards") -> Mesh:
@@ -139,35 +146,24 @@ def prepare_match_query(segments: list, field: str, terms: list[str]):
     return stack_shards(shards), {"n_pad": n_pad, "budget": budget}
 
 
-def sharded_topk_merge(mesh: Mesh, k: int, axis: str = "shards"):
-    """The coordinator reduce as an ICI collective: every device holds its
-    shard's local top-k (vals[k] desc, rows already tie-broken locally);
-    all-gather + redundant re-top-k yields the global top-k replicated on
-    every device — replacing SearchPhaseController.sortDocs:175's host
-    heap merge.  Returns (vals[k], flat_idx[k]) where flat_idx indexes the
-    shard-major [S*k] concatenation (shard = flat_idx // k), so ties break
-    (score desc, shard asc, local rank asc) exactly like the host merge."""
-
-    def local(vals):
-        av = lax.all_gather(vals[0], axis)          # [S, k] on every device
-        fv, fi = lax.top_k(av.reshape(-1), k)
-        return fv, fi
-
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(P(axis),),
-                             out_specs=(P(), P()), check_vma=False))
-
-
 class MeshSearcher:
-    """Distributed search over shards resident on a device mesh: ANY
-    compiled plan (bool/range/match/phrase/knn/...) runs per shard on that
-    shard's own device, and the cross-shard top-k merge is an all-gather
-    collective riding ICI — the device-resident scatter-gather of SURVEY
-    §2.3 (scoring stats are per-shard, like the reference's default
-    query_then_fetch).
+    """Distributed search over shards resident on a device mesh, one
+    shard a chip, each staged on its own chip only.
 
-    One mesh device per shard; shards may have heterogeneous sizes and
-    segment counts (each compiles its own bucketed program) — only the
-    [S, k] merge is a single SPMD program.
+    A scored top-k request runs ``ShardSearcher``'s own lowering: every
+    shard compiles the query through its plan cache with its own
+    statistics (the reference's default ``query_then_fetch``) and
+    prepares each segment through ``_segment_inputs`` / ``_prepared``.
+    Segment index ``g`` of every shard then runs as ONE SPMD program
+    over the chips (``run_topk_mesh``): the program key common to the
+    shards, the per-query inputs as one array sharded over the mesh,
+    the resident columns as one global array (staged to one shape per
+    segment index).  One more program (``merge_topk_mesh``) all-gathers
+    every shard's per-segment top-k, merges by (score desc, shard asc,
+    segment asc, doc asc) and packs the winners; the host reads it once.
+    A request whose shards cannot share a program key (unequal segment
+    counts, plans or shapes) is served shard by shard on the host path
+    and counted (``search.mesh.fallback``).
     """
 
     def __init__(self, shard_searchers: list, mesh: Optional[Mesh] = None,
@@ -181,12 +177,23 @@ class MeshSearcher:
             raise ValueError(
                 f"mesh has {len(self.devices)} devices for "
                 f"{len(self.shards)} shards")
-        # bounded-cache: one compiled merge program per distinct k
-        self._merge_cache: dict[int, object] = {}
-        # per-(device, segment) staging cache (seg.device() would pin to
-        # the default device; mesh copies are staged per device) — kept
+        self._lock = threading.RLock()
+        # bounded-cache: the compiled metric reduce (mesh_metric_aggs)
+        self._merge_cache: dict = {}
+        # per-(shard, segment) staging on the shard's own chip
+        # (seg.device() would stage on the default device) — kept
         # across refreshes, pruned in update_shards
         self._dsegs: dict = {}
+        # bounded-cache: a segment index's stagings, by its segment ids
+        self._rows: dict = {}
+        # bounded-cache: the global array over the chips of each set of
+        # per-chip arrays, by their ids (LRU, _GLOBALS_MAX)
+        self._globals: "collections.OrderedDict" = collections.OrderedDict()
+        # bounded-cache: a sub-mesh per shard count, a min_score per value
+        self._meshes: dict = {}
+        self._min_scores: dict = {}
+        # reported from the first request on, 0 until one falls back
+        _metrics().counter("search.mesh.fallback")
 
     def update_shards(self, shard_searchers: list):
         """Swap in fresh per-shard searcher snapshots (after a refresh),
@@ -196,20 +203,130 @@ class MeshSearcher:
             raise ValueError(
                 f"mesh has {len(self.devices)} devices for "
                 f"{len(shard_searchers)} shards")
-        self.shards = shard_searchers
-        alive = {seg.seg_id for s in shard_searchers for seg in s.segments}
-        self._dsegs = {key: d for key, d in self._dsegs.items()
-                       if key[1] in alive}
+        with self._lock:
+            self.shards = shard_searchers
+            alive = {seg.seg_id for s in shard_searchers
+                     for seg in s.segments}
+            dsegs = {key: d for key, d in self._dsegs.items()
+                     if key[1] in alive}
+            if len(dsegs) != len(self._dsegs):
+                self._dsegs = dsegs
+                self._rows = {key: row for key, row in self._rows.items()
+                              if alive.issuperset(key)}
+                self._globals.clear()
 
-    def _dseg(self, shard_i: int, seg):
+    def _dseg(self, shard_i: int, seg, pads: Optional[dict] = None):
         from opensearch_tpu.index.segment import DeviceSegment
 
-        d = self._dsegs.get((shard_i, seg.seg_id))
-        if d is None:
-            with jax.default_device(self.devices[shard_i]):
-                d = DeviceSegment(seg)
-            self._dsegs[(shard_i, seg.seg_id)] = d
-        return d
+        with self._lock:
+            d = self._dsegs.get((shard_i, seg.seg_id))
+            if d is None or (pads is not None and d.pads != pads):
+                with jax.default_device(self.devices[shard_i]):
+                    d = DeviceSegment(seg, pads)
+                self._dsegs[(shard_i, seg.seg_id)] = d
+            return d
+
+    def _row(self, g: int) -> list:
+        """The stagings of segment index ``g``, one a shard on its chip,
+        padded to the lengths the largest of them needs."""
+        from opensearch_tpu.index.segment import DeviceSegment
+
+        segs = [s.segments[g] for s in self.shards]
+        key = tuple(seg.seg_id for seg in segs)
+        row = self._rows.get(key)
+        if row is None:
+            with self._lock:
+                row = self._rows.get(key)
+                if row is None:
+                    pads: dict = {}
+                    for seg in segs:
+                        for k, v in DeviceSegment.natural_pads(seg).items():
+                            pads[k] = max(pads.get(k, 0), v)
+                    row = [self._dseg(si, seg, pads)
+                           for si, seg in enumerate(segs)]
+                    self._rows[key] = row
+        return row
+
+    def _sub_mesh(self, n: int) -> Mesh:
+        if n == len(self.devices):
+            return self.mesh
+        mesh = self._meshes.get(n)
+        if mesh is None:
+            mesh = self._meshes[n] = Mesh(np.asarray(self.devices[:n]),
+                                          (self.axis,))
+        return mesh
+
+    def _min_score(self, mesh: Mesh, value) -> jax.Array:
+        """``min_score`` (-inf when unset) replicated over the mesh."""
+        from opensearch_tpu.common.device_ledger import device_ledger
+
+        v = np.float32(-np.inf if value is None else value)
+        key = (len(mesh.devices.flat), float(v))
+        out = self._min_scores.get(key)
+        if out is None:
+            if len(self._min_scores) >= 16:
+                self._min_scores.clear()
+            out = self._min_scores[key] = device_ledger().stage_input(
+                v, NamedSharding(mesh, P()))
+        return out
+
+    def _global(self, sharding, arrays: list):
+        """One array over the chips from each shard's array on its own
+        chip (no copy), or None where one lies elsewhere."""
+        key = tuple(id(x) for x in arrays)
+        with self._lock:
+            hit = self._globals.get(key)
+            if hit is not None and all(a is b for a, b in zip(hit[0],
+                                                             arrays)):
+                self._globals.move_to_end(key)
+                return hit[1]
+        for si, x in enumerate(arrays):
+            if x.devices() != {self.devices[si]}:
+                return None
+        x = arrays[0]
+        out = jax.make_array_from_single_device_arrays(
+            (len(arrays) * x.shape[0],) + x.shape[1:], sharding,
+            list(arrays))
+        with self._lock:
+            self._globals[key] = (tuple(arrays), out)
+            if len(self._globals) > _GLOBALS_MAX:
+                self._globals.popitem(last=False)
+        return out
+
+    def _on_mesh(self, sharding, trees: list):
+        """The shards' (A, ins) of one segment index as one pytree of
+        arrays over the mesh: resident columns glued by ``_global``,
+        host inputs stacked and staged in one copy each
+        (``stage_input``).  Returns (tree, per-shard leaf shapes), or
+        None where the shards' trees differ in structure, shape or
+        dtype, or a device array is not on its shard's chip."""
+        from opensearch_tpu.common.device_ledger import device_ledger
+
+        flat = [jax.tree_util.tree_flatten(t) for t in trees]
+        treedef = flat[0][1]
+        if any(f[1] != treedef for f in flat[1:]):
+            return None
+        leaves, shapes = [], []
+        for j in range(len(flat[0][0])):
+            col = [f[0][j] if isinstance(f[0][j], jax.Array)
+                   else np.asarray(f[0][j]) for f in flat]
+            x = col[0]
+            if any(type(y) is not type(x) or y.shape != x.shape
+                   or y.dtype != x.dtype for y in col[1:]):
+                return None
+            if isinstance(x, np.ndarray):      # a per-query host input
+                host = np.concatenate(col) if x.ndim else np.stack(col)
+                out = device_ledger().stage_input(host, sharding)
+            elif x.ndim:
+                out = self._global(sharding, col)
+            else:
+                out = None
+            if out is None:
+                return None
+            leaves.append(out)
+            shapes.append(tuple(x.shape))
+        return (jax.tree_util.tree_unflatten(treedef, leaves),
+                tuple(shapes))
 
     def supports_mesh_aggs(self, aggs_json: dict) -> bool:
         """True when every agg is a single-level numeric metric over a
@@ -331,132 +448,260 @@ class MeshSearcher:
         }
 
     def search(self, body: Optional[dict] = None) -> dict:
-        """Scored top-k search (sort/aggs stay on the host path)."""
-        import time as _time
-
-        from opensearch_tpu.search.compiler import compile_query
-        from opensearch_tpu.search.executor import build_arrays
-        from opensearch_tpu.search.fetch import filter_source
-        from opensearch_tpu.search.query_dsl import parse_query
-        from opensearch_tpu.search import plan as planmod
-
+        """Scored top-k search (sort/aggs stay on the host path): one
+        SPMD program a segment index, one merge program, one read."""
         body = body or {}
-        t0 = _time.monotonic()
+        S = len(self.shards)
+        G = len(self.shards[0].segments) if S else 0
+        _metrics().counter("search.mesh.requests").inc()
+        _metrics().counter("search.mesh.shards").inc(S)
+        with _tracer().start_span("mesh.search",
+                                  {"shards": S, "segments": G}):
+            resp = self._search(body, time.monotonic(), S, G)
+            return self._scatter(body) if resp is None else resp
+
+    def _search(self, body: dict, t0: float, S: int, G: int):
+        """The response, or None where the shards cannot share the
+        programs (unequal segment counts, plans, keys or shapes, or
+        non-finite scores back from the chips)."""
+        from opensearch_tpu.common.device_health import (check_finite,
+                                                         device_health)
+        from opensearch_tpu.common.device_ledger import device_ledger
+        from opensearch_tpu.common.tasks import check_current
+        from opensearch_tpu.search import plan as planmod
+        from opensearch_tpu.search.profile import QueryProfiler
+
         size = int(body.get("size", 10))
         from_ = int(body.get("from", 0))
         k = max(from_ + size, 1)
-        q = parse_query(body.get("query"))
-        min_score = body.get("min_score")
-        ms = np.float32(-np.inf if min_score is None else min_score)
-
-        S = len(self.shards)
-        # Phase 1: DISPATCH every shard's program to its device, keeping
-        # only jnp handles — no host sync inside the loop, so the S
-        # devices execute concurrently (jax async dispatch).
-        shard_vals, shard_rows, totals = [], [], []
+        if not S or any(len(s.segments) != G for s in self.shards):
+            return None
+        profs = [QueryProfiler() if body.get("profile") else None
+                 for _ in self.shards]
+        compiled = []
         for si, shard in enumerate(self.shards):
-            dev = self.devices[si]
-            with jax.default_device(dev):
-                if not shard.segments:
-                    shard_vals.append(
-                        jnp.full((1, k), -jnp.inf, jnp.float32))
-                    shard_rows.append((jnp.zeros(k, jnp.int32),
-                                       jnp.zeros(k, jnp.int32)))
-                    totals.append(jnp.int32(0))
-                    continue
-                plan, bind = compile_query(q, shard.ctx, scored=True)
-                needed = plan.arrays()
-                seg_vals, seg_ids, seg_locals = [], [], []
-                total = jnp.int32(0)
-                for gi, seg in enumerate(shard.segments):
-                    dseg = self._dseg(si, seg)
-                    A = build_arrays(dseg, needed, shard.mapper,
-                                     live=shard.ctx.live_jnp(seg, dseg))
-                    dims, ins = plan.prepare(bind, seg, dseg, shard.ctx)
-                    kk = min(k, dseg.n_pad)
-                    vals, idx, tot, _mx = planmod.run_topk_parts(
-                        plan, dims, kk, A, ins, ms,
-                        sorted_bag=plan.sorted_topk(dims, dseg.n_pad, kk)
-                        and shard.ctx.all_live(seg))
-                    if kk < k:                       # pad to common k
-                        pad = k - kk
-                        vals = jnp.concatenate(
-                            [vals, jnp.full(pad, -jnp.inf, vals.dtype)])
-                        idx = jnp.concatenate(
-                            [idx, jnp.zeros(pad, idx.dtype)])
-                    seg_vals.append(vals)
-                    seg_ids.append(jnp.full(k, gi, jnp.int32))
-                    seg_locals.append(idx)
-                    total = total + tot
-                # shard-local merge of per-segment top-k: flat concat is
-                # segment-major, so top_k's lowest-index tie-break
-                # reproduces the (score desc, seg asc, doc asc) Lucene
-                # merge order
-                cat_v = jnp.concatenate(seg_vals)
-                row_v, pick = lax.top_k(cat_v, k)
-                row_s = jnp.concatenate(seg_ids)[pick]
-                row_l = jnp.concatenate(seg_locals)[pick]
-                shard_vals.append(row_v.reshape(1, k))
-                shard_rows.append((row_s, row_l))
-                totals.append(total)
+            with jax.default_device(self.devices[si]):
+                compiled.append(shard.compiled(
+                    body.get("query"), scored=True, with_key=True,
+                    prof=profs[si]))
+        plan = compiled[0][0][0]
+        if any(c[0][0] != plan for c in compiled[1:]):
+            return None
+        binds = [c[0][1] for c in compiled]
+        needed = plan.arrays()
+        mesh = self._sub_mesh(S)
+        sharding = NamedSharding(mesh, P(self.axis))
+        ms = self._min_score(mesh, body.get("min_score"))
+        led = device_ledger()
+        parts, seg_of_part = [], []
+        for g in range(G):
+            check_current()        # cancellation point per segment index
+            with _tracer().start_span("mesh.prepare", {"segment": g}):
+                row = self._row(g)
+                segs = [s.segments[g] for s in self.shards]
+                if not any(plan.can_match(b, seg)
+                           for b, seg in zip(binds, segs)):
+                    continue       # no staging, no program
+                keys, trees = [], []
+                with planmod.host_inputs():
+                    for si, shard in enumerate(self.shards):
+                        with jax.default_device(self.devices[si]):
+                            _d, dims, ins, A = shard._segment_inputs(
+                                plan, binds[si], segs[si], needed,
+                                (_MESH_KEY, compiled[si][1]), profs[si],
+                                dseg=row[si])
+                        keys.append(dims)
+                        trees.append((A, ins))
+                key = _common_key(keys)
+                if key is None:
+                    return None
+                placed = self._on_mesh(sharding, trees)
+                if placed is None:
+                    return None
+                (A, ins), shapes = placed
+                n_pad = row[0].n_pad
+                kk = min(k, n_pad)
+                sorts = (plan.sorted_topk(key, n_pad, kk)
+                         and all(s.ctx.all_live(seg)
+                                 for s, seg in zip(self.shards, segs)))
+            with _tracer().start_span("mesh.launch", {"segment": g}):
+                parts.append(run_topk_mesh(
+                    mesh, self.axis, plan, key, kk, k, sorts, shapes, A,
+                    ins, ms))
+            led.record_dispatch(
+                None, mesh=True, slice_gather=plan.slice_gathers(key),
+                sorted_bag=sorts,
+                block_topk=topk_ops.block_size(key[1] if sorts else n_pad,
+                                               kk))
+            seg_of_part.append(g)
 
-        # Phase 2: device-collective merge over the mesh (the flagship
-        # reduce riding ICI)
-        sharding = NamedSharding(self.mesh, P(self.axis))
-        vals_g = jax.make_array_from_single_device_arrays(
-            (S, k), sharding, shard_vals)
-        merge = self._merge_cache.get(k)
-        if merge is None:
-            merge = sharded_topk_merge(self.mesh, k, self.axis)
-            self._merge_cache[k] = merge
-        fv, fi = merge(vals_g)
-
-        # Phase 3: host-side fetch of the k winners (first host sync)
-        from opensearch_tpu.common.device_ledger import device_ledger
-        t_sync = _time.monotonic()
-        fv = np.asarray(fv)
-        fi = np.asarray(fi)
-        rows_np = [(np.asarray(s_), np.asarray(l_))
-                   for s_, l_ in shard_rows]
-        total = int(sum(int(t) for t in totals))
-        device_ledger().record_fetch(
-            fv.nbytes + fi.nbytes
-            + sum(s_.nbytes + l_.nbytes for s_, l_ in rows_np),
-            _time.monotonic() - t_sync)
-
-        hits = []
-        source_spec = body.get("_source")
-        max_score = None
-        if size > 0 or from_ > 0:
-            for val, flat in zip(fv, fi):
-                if val == -np.inf:
+        total, max_score, rows = 0, None, []
+        if parts:
+            with _tracer().start_span("mesh.launch", {"segment": "merge"}):
+                out = merge_topk_mesh(mesh, self.axis, k, tuple(parts))
+                out.copy_to_host_async()
+            led.record_dispatch(None, mesh=True)
+            t_sync = time.monotonic()
+            with _tracer().start_span("device.sync", {"site": "mesh"},
+                                      cpu=True):
+                packed = np.asarray(out)
+            led.record_fetch(packed.nbytes, time.monotonic() - t_sync)
+            vals = packed[:k].view(np.float32)
+            bad = check_finite(vals)
+            if bad:
+                device_health().record_poison(
+                    kernel="merge_topk_mesh", segment="-",
+                    index=self.shards[0].index_name, shard="mesh", bad=bad)
+                return None
+            total = int(packed[3 * k])
+            per_part = len(parts) * k
+            for j in range(k):
+                if vals[j] == -np.inf:
                     break
-                shard_i, pos = divmod(int(flat), k)
-                seg_i = int(rows_np[shard_i][0][pos])
-                local = int(rows_np[shard_i][1][pos])
-                shard = self.shards[shard_i]
-                seg = shard.segments[seg_i]
-                hit = {"_index": shard.index_name,
-                       "_id": seg.doc_ids[local],
-                       "_score": float(val), "_shard": shard.shard_id}
+                flat = int(packed[k + j])
+                rows.append((float(vals[j]), flat // per_part,
+                             seg_of_part[flat % per_part // k],
+                             int(packed[2 * k + j])))
+            if rows:
+                max_score = rows[0][0]
+        hits = []
+        if size > 0 or from_ > 0:
+            source_spec = body.get("_source")
+            for score, si, g, local in rows[from_: from_ + size]:
+                shard = self.shards[si]
+                seg = shard.segments[g]
+                hit = {"_index": shard.index_name, "_id": seg.doc_ids[local],
+                       "_score": score, "_shard": shard.shard_id}
                 src = filter_source(seg.source(local), source_spec)
                 if src is not None:
                     hit["_source"] = src
                 hits.append(hit)
-            if hits:
-                max_score = hits[0]["_score"]
-            hits = hits[from_: from_ + size]
-        # size=0: count-only request — null max_score, like the host path
-
-        return {
-            "took": int((_time.monotonic() - t0) * 1000),
+        else:
+            max_score = None   # count-only request, like the host path
+        resp = {
+            "took": int((time.monotonic() - t0) * 1000),
             "timed_out": False,
             "_shards": {"total": S, "successful": S, "skipped": 0,
                         "failed": 0},
             "hits": {"total": {"value": total, "relation": "eq"},
-                     "max_score": max_score,
-                     "hits": hits},
+                     "max_score": max_score, "hits": hits},
         }
+        if profs[0] is not None:
+            from opensearch_tpu.search.profile import describe_plan
+            sections = []
+            for si, shard in enumerate(self.shards):
+                profs[si].set("execution_path", "device")
+                sections.append(profs[si].shard_section(
+                    shard.index_name, shard.shard_id,
+                    plan_type=type(plan).__name__,
+                    description=describe_plan(plan, binds[si]),
+                    total_segments=G))
+            resp["profile"] = {"shards": sections}
+        return resp
+
+    def _scatter(self, body: dict) -> dict:
+        """The counted fallback: every shard on its own searcher's path,
+        merged in the coordinator's order (``executor.scatter_merge``)."""
+        from opensearch_tpu.search.executor import scatter_merge
+
+        _metrics().counter("search.mesh.fallback").inc()
+        resp = scatter_merge(self.shards, body)
+        S = len(self.shards)
+        resp["_shards"] = {"total": S, "successful": S, "skipped": 0,
+                           "failed": 0}
+        return resp
+
+
+# the prepared-bindings cache key's mark: a mesh prepare leaves its
+# inputs on the host, the shard's own path stages them
+_MESH_KEY = "mesh"
+_GLOBALS_MAX = 4096
+
+
+def _common_key(keys: list):
+    """The one program key of a segment index over the shards: their
+    keys where equal, the largest gather bucket of a ``BagDims`` (a
+    bucket is a capacity: more lanes than postings give the same
+    bits); None where anything else differs."""
+    from opensearch_tpu.search import plan as planmod
+
+    first = keys[0]
+    if all(d == first for d in keys[1:]):
+        return first
+    if not isinstance(first, tuple) or any(
+            type(d) is not type(first) or len(d) != len(first)
+            for d in keys[1:]):
+        return None
+    if isinstance(first, planmod.BagDims):
+        if any(d[:1] + d[2:] != first[:1] + first[2:] for d in keys[1:]):
+            return None
+        return planmod.BagDims.of(max(d.postings for d in keys),
+                                  first[0], *first[2:])
+    if type(first) is not tuple:
+        return None
+    out = []
+    for parts in zip(*keys):
+        one = _common_key(list(parts))
+        if one is None:
+            return None
+        out.append(one)
+    return tuple(out)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2, 3, 4, 5, 6, 7))
+def run_topk_mesh(mesh: Mesh, axis: str, plan, dims, k: int, k_out: int,
+                  sorted_bag: bool, shapes: tuple, A, ins, min_score):
+    """One segment index of every shard as ONE program over the mesh:
+    each chip runs ``plan._run_topk`` on its shard's segment and packs
+    its answer as ``run_topk`` does, padded to ``k_out`` (-inf) so that
+    every segment index's answer has one width; the global result is
+    ``int32[S * (2 k_out + 2)]``, a shard's block on its chip.
+    ``shapes``: each leaf's per-shard shape (a scalar input crosses
+    stacked as one element a chip)."""
+    from opensearch_tpu.search import plan as planmod
+
+    def local(A, ins, ms):
+        leaves, tree = jax.tree_util.tree_flatten((A, ins))
+        A, ins = jax.tree_util.tree_unflatten(
+            tree, [x.reshape(s) for x, s in zip(leaves, shapes)])
+        vals, idx, tot, mx = planmod._run_topk(plan, dims, k, A, ins, ms,
+                                               sorted_bag)
+        if k_out > k:
+            vals = jnp.concatenate(
+                [vals, jnp.full(k_out - k, -jnp.inf, vals.dtype)])
+            idx = jnp.concatenate([idx, jnp.zeros(k_out - k, idx.dtype)])
+        return planmod._pack_topk(vals, idx, tot, mx)
+
+    return shard_map(local, mesh=mesh, in_specs=(P(axis), P(axis), P()),
+                     out_specs=P(axis), check_vma=False)(A, ins, min_score)
+
+
+@partial(jax.jit, static_argnums=(0, 1, 2))
+def merge_topk_mesh(mesh: Mesh, axis: str, k: int, parts: tuple):
+    """The coordinator's reduce over ICI: each chip all-gathers every
+    shard's packed answer of every launched segment index (``parts``,
+    ``run_topk_mesh``'s), takes the top ``k`` of the ``S * G * k``
+    candidates in shard-major, then segment, then rank order (the
+    lower index wins a tie: score desc, shard asc, segment asc, doc
+    asc) and packs, replicated: ``int32[3k + 2]`` = the scores' bits,
+    the candidates' flat indices, their segment-local doc ids, the
+    summed match count and the best score's bits."""
+
+    def local(*blocks):
+        allp = lax.all_gather(jnp.stack(blocks), axis)   # [S, G, 2k + 2]
+        vals = lax.bitcast_convert_type(allp[:, :, :k],
+                                        jnp.float32).reshape(-1)
+        docs = allp[:, :, k:2 * k].reshape(-1)
+        fv, fi = lax.top_k(vals, k)
+        mx = lax.bitcast_convert_type(allp[:, :, 2 * k + 1],
+                                      jnp.float32).max()
+        return jnp.concatenate([
+            lax.bitcast_convert_type(fv, jnp.int32), fi.astype(jnp.int32),
+            docs[fi], allp[:, :, 2 * k].sum().astype(jnp.int32)[None],
+            lax.bitcast_convert_type(mx, jnp.int32)[None]])
+
+    return shard_map(local, mesh=mesh, in_specs=(P(axis),) * len(parts),
+                     out_specs=P(), check_vma=False)(*parts)
 
 
 def sharded_metric_reduce(mesh: Mesh, axis: str = "shards"):

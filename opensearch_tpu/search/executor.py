@@ -381,16 +381,20 @@ class ShardSearcher:
         walk(value)
         return total
 
-    def _segment_inputs(self, plan, bind, seg, needed, ckey, prof):
+    def _segment_inputs(self, plan, bind, seg, needed, ckey, prof,
+                        dseg=None):
         """(dseg, dims, ins, A): everything one segment's program takes,
         under a ``segment.prepare`` span — the host work and the H2D
         before a launch.  The span's parts: ``device`` (``seg.device()``),
         ``cache_get`` / ``bind`` / ``cache_put`` (``_prepared``) and
-        ``arrays`` (the live mask and ``build_arrays``)."""
+        ``arrays`` (the live mask and ``build_arrays``).  ``dseg``: a
+        staging of the caller's own (the mesh stages each shard on its
+        chip), in place of ``seg.device()``."""
         with _tracer().start_span("segment.prepare", {"prepared": "miss"},
                                   cpu=True) as span:
-            with span.part("device"):
-                dseg = seg.device()
+            if dseg is None:
+                with span.part("device"):
+                    dseg = seg.device()
             # prepare FIRST: dims tells build_arrays which array groups
             # the lowering left deliberately partial (quantized segments)
             dims, ins = self._prepared(plan, bind, seg, dseg, ckey, prof,
@@ -1690,6 +1694,57 @@ def _sort_comparator(specs):
             return -1 if r1["seg"] < r2["seg"] else 1
         return -1 if r1["local"] < r2["local"] else (0 if r1["local"] == r2["local"] else 1)
     return cmp
+
+
+def scatter_merge(searchers: list, body: dict) -> dict:
+    """Every shard's searcher answers on its own path (its own scoring
+    statistics: query_then_fetch) and the top hits merge in the
+    coordinator's order, with aggregation partials reduced, suggestions
+    merged and, for a profiled request, each shard's profile kept: the
+    host's scatter-gather, the mesh's counted fallback."""
+    from opensearch_tpu.search.aggs import reduce_aggs
+    from opensearch_tpu.search.suggest import merge_suggest
+
+    t0 = time.monotonic()
+    size = int(body.get("size", 10)
+               if body.get("size") is not None else 10)
+    from_ = int(body.get("from", 0) or 0)
+    aggs_json = body.get("aggs") or body.get("aggregations")
+    sub = dict(body)
+    sub["from"] = 0
+    sub["size"] = from_ + size
+    shard_resps = [s.search(sub, agg_partials=bool(aggs_json))
+                   for s in searchers]
+    rows = []
+    total = 0
+    max_score = None
+    for si, r in enumerate(shard_resps):
+        for pos, h in enumerate(r["hits"]["hits"]):
+            rows.append((h, si, pos))
+        total += r["hits"]["total"]["value"]
+        ms_ = r["hits"]["max_score"]
+        if ms_ is not None and (max_score is None or ms_ > max_score):
+            max_score = ms_
+    all_hits = merge_hit_rows(rows, body.get("sort"))
+    resp = {
+        "took": int((time.monotonic() - t0) * 1000),
+        "timed_out": any(r.get("timed_out") for r in shard_resps),
+        "hits": {"total": {"value": total, "relation": "eq"},
+                 "max_score": max_score,
+                 "hits": all_hits[from_: from_ + size]},
+    }
+    if aggs_json:
+        resp["aggregations"] = reduce_aggs(
+            aggs_json, [r.get("aggregation_partials") or {}
+                        for r in shard_resps])
+    if body.get("suggest"):
+        resp["suggest"] = merge_suggest(
+            [r.get("suggest") for r in shard_resps])
+    if body.get("profile"):
+        resp["profile"] = {"shards": [
+            sh for r in shard_resps
+            for sh in (r.get("profile") or {}).get("shards", [])]}
+    return resp
 
 
 def merge_hit_rows(rows, sort_json):

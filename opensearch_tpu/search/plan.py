@@ -23,9 +23,11 @@ the Lucene ``Weight``/``Scorer`` machinery it produces.  The TPU twist:
 from __future__ import annotations
 
 import bisect
+import contextlib
 import fnmatch
 import math
 import re
+import threading
 from dataclasses import dataclass
 from functools import partial
 from typing import Optional
@@ -51,10 +53,28 @@ _I32 = np.int32
 _F32 = np.float32
 
 
+_HOST_INPUTS = threading.local()
+
+
+@contextlib.contextmanager
+def host_inputs():
+    """Inside, ``prepare`` leaves its per-query inputs on the host: the
+    caller stages them itself, as the mesh does, whose shards' inputs
+    cross as one array sharded over the chips (``MeshSearcher``)."""
+    prev = getattr(_HOST_INPUTS, "on", False)
+    _HOST_INPUTS.on = True
+    try:
+        yield
+    finally:
+        _HOST_INPUTS.on = prev
+
+
 def _stage_input(host_array):
     """One per-query input onto the device, counted (``_nodes/stats``
     ``device.transfers.input``).  The prepared-bindings cache owns what
-    comes back."""
+    comes back.  Under ``host_inputs`` the host array itself."""
+    if getattr(_HOST_INPUTS, "on", False):
+        return np.asarray(host_array)
     return device_ledger().stage_input(host_array)
 
 
@@ -1999,14 +2019,6 @@ def run_topk(plan: Plan, dims, k: int, A, ins, min_score, *,
     snapshot of the segment; the same bits either way."""
     return _pack_topk(*_run_topk(plan, dims, k, A, ins, min_score,
                                  sorted_bag))
-
-
-@partial(jax.jit, static_argnums=(0, 1, 2), static_argnames=("sorted_bag",))
-def run_topk_parts(plan: Plan, dims, k: int, A, ins, min_score, *,
-                   sorted_bag: bool = False):
-    """``run_topk``'s four results as four device arrays, for a caller
-    that goes on with them on the device (the mesh's shard-local merge)."""
-    return _run_topk(plan, dims, k, A, ins, min_score, sorted_bag)
 
 
 @partial(jax.jit, static_argnums=(1,))

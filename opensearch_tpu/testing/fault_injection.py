@@ -614,7 +614,8 @@ class DeviceFaultInjector:
     the sanctioned device entry points — the residency ledger's
     ``stage``/``device_put`` (every H2D transfer flows through them,
     enforced by tools/check_device_staging.py), the query-path kernels
-    ``plan.run_topk``/``plan.run_full``, the batched kernel
+    ``plan.run_topk``/``plan.run_full`` (and the mesh's
+    ``run_topk_mesh``), the batched kernel
     ``batch.batch_impact_union_topk``, and the mesh collective
     ``MeshSearcher.search``/``mesh_metric_aggs`` — so matching calls
     misbehave: staging RESOURCE_EXHAUSTED, XLA compile failure,
@@ -699,8 +700,9 @@ class DeviceFaultInjector:
             setattr(mod, attr, kernel)
 
         wrap_kernel(plan_mod, "run_topk")
-        # the mesh's four-array form of the same kernel: same rules
-        wrap_kernel(plan_mod, "run_topk_parts", name="run_topk")
+        # the mesh's one program a segment index over the chips: the
+        # same kernel, the same rules
+        wrap_kernel(dist_search, "run_topk_mesh", name="run_topk")
         wrap_kernel(plan_mod, "run_full")
         wrap_kernel(batch_mod, "batch_impact_union_topk")
 

@@ -351,7 +351,10 @@ def test_search_equals_the_host_scorer(searcher, host_recovery, query, size):
 def test_the_sorted_program_is_the_dense_programs_result(searcher):
     """``run_topk`` with and without ``sorted_bag`` over the same inputs:
     one packed array, bit for bit (the ids of ``-inf`` entries apart),
-    and ``run_topk_parts`` as the mesh calls it."""
+    and the mesh's program (``run_topk_mesh``) over a mesh of one chip."""
+    from opensearch_tpu.parallel import dist_search
+
+    mesh = dist_search.make_mesh(1)
     for query in (MATCH, SPARSE):
         plan, bind = searcher.compiled(query)
         for seg in searcher.segments:
@@ -367,10 +370,13 @@ def test_the_sorted_program_is_the_dense_programs_result(searcher):
                 P.run_topk(plan, dims, k, A, ins, ms)))
             got = P.unpack_topk(np.asarray(
                 P.run_topk(plan, dims, k, A, ins, ms, sorted_bag=True)))
-            parts = P.run_topk_parts(plan, dims, k, A, ins, ms,
-                                     sorted_bag=True)
+            shapes = tuple(tuple(x.shape)
+                           for x in jax.tree_util.tree_leaves((A, ins)))
+            on_mesh = P.unpack_topk(np.asarray(dist_search.run_topk_mesh(
+                mesh, "shards", plan, dims, k, k, True, shapes, A, ins,
+                ms)))
             keep = dense[0] > -np.inf
-            for other in (got, [np.asarray(x) for x in parts]):
+            for other in (got, on_mesh):
                 assert np.array_equal(dense[0].view(np.int32),
                                       np.asarray(other[0]).view(np.int32))
                 assert np.array_equal(dense[1][keep],

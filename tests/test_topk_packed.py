@@ -104,6 +104,10 @@ def _bits(a) -> np.ndarray:
     return a.reshape(-1).view(np.int32)
 
 
+# the one lowering's four results, unpacked: what ``run_topk`` packs
+_parts = jax.jit(P._run_topk, static_argnums=(0, 1, 2, 6))
+
+
 def _same(packed, parts) -> None:
     """``packed`` (one device array) holds ``parts`` (four), bit for bit."""
     vals, idx, tot, mx = parts
@@ -139,7 +143,7 @@ def test_packed_entry_holds_the_four_outputs_bit_for_bit(searcher, name, k):
             ms = float(np.median(scores[scores > -np.inf])) \
                 if (scores > -np.inf).any() else 1.0
         ms = jnp.asarray(np.float32(ms))
-        parts = P.run_topk_parts(plan, dims, kk, A, ins, ms)
+        parts = _parts(plan, dims, kk, A, ins, ms, False)
         top = top or parts
         _same(P.run_topk(plan, dims, kk, A, ins, ms), parts)
     tot, filled = int(top[2]), np.asarray(top[0]) > -np.inf

@@ -12,7 +12,12 @@ also keeps what a builder compares by hand:
 - the window's delta of ``_nodes/stats`` ``telemetry.spans`` (the
   tracer's per-name totals), per completed request: wall, off-CPU where
   metered, and each part, in ms; beside it (``per_query``) the process's
-  CPU, the search tasks' CPU and the two waits before ``http.request``.
+  CPU, the search tasks' CPU and the two waits before ``http.request``;
+- each chip's peak memory (``memory_peak_bytes_by_chip``: the result
+  line gives the largest);
+- with ``--serial-seconds S``, first a closed loop of one client for S
+  seconds after set-up (``serial``: its qps and median latency, the
+  one-client point of ``benchmarks/sweep.py``), then the cell's window.
 
 Run it from the root of the checkout to measure (it imports that
 checkout's ``benchmarks`` and ``opensearch_tpu``); needs the chip.
@@ -81,6 +86,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=40.0)
     ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
     ap.add_argument("--all-metrics", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--serial-seconds", type=float, default=0.0)
     ap.add_argument("--tag", required=True)
     ap.add_argument("--out", required=True)
     args = ap.parse_args(argv)
@@ -104,6 +110,12 @@ def main(argv=None) -> int:
     window = harness.Session.window
 
     def keeping(self, *a, **kw):
+        if args.serial_seconds and "serial" not in stash:
+            one = window(self, args.serial_seconds,
+                         mix={"loop": "closed", "clients": 1})["nums"]
+            stash["serial"] = {"qps": one["qps"],
+                               "p50_ms": one["latency_p50_ms"],
+                               "failed": one["failed"]}
         out = window(self, *a, **kw)
         stash.update(stats0=out["stats0"], stats1=out["stats1"],
                      completed=out["nums"]["completed"])
@@ -125,8 +137,13 @@ def main(argv=None) -> int:
     result = harness.run_cell(cell, args.seed, args.seconds,
                               bool(args.trace), t_process=T_PROCESS,
                               device=device, tamper=watch)
+    import jax
+
+    result["device"]["memory_peak_bytes_by_chip"] = [
+        int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+        for d in jax.devices()]
     result.update(tag=args.tag, workload=args.workload, seed=args.seed,
-                  traced=args.trace)
+                  traced=args.trace, serial=stash.get("serial"))
     if stash:
         result["span_split"] = span_split(stash["stats0"], stash["stats1"],
                                           stash["completed"])
