@@ -23,7 +23,8 @@ There are two ways from the postings to one score a doc:
   each doc's run of equal ids in slot order, and take the top-k over
   the runs' last lanes.  No accumulator, no scatter, and every float32
   sum keeps the scatter's bits.  ``sorted_bag`` says from the static
-  shape where it is taken.
+  shape where it is taken; ``sorted_width`` says over how many lanes
+  (half the ``budget`` where the program's own postings fit there).
 
 The gather (``gather_postings``) lays each term's postings run, one
 contiguous stretch of the staged columns, into a flat ``budget``-sized
@@ -170,29 +171,50 @@ def gather_postings(offsets, doc_ids, tfs, term_ids, term_active, *,
     into ``term_ids`` that produced each entry; lanes past the total hold
     ``pad_doc`` / ``0.0``.
     """
+    d, tf, cum, slot = _gather_runs(offsets, doc_ids, tfs, term_ids,
+                                    term_active, budget=budget,
+                                    pad_doc=pad_doc)
+    if slot is None:
+        slot = lane_slots(cum, budget)
+    return d, tf, slot, jnp.arange(budget, dtype=jnp.int32) < cum[-1]
+
+
+def _gather_runs(offsets, doc_ids, tfs, term_ids, term_active, *,
+                 budget: int, pad_doc: int):
+    """``gather_postings``' lanes as (docs[B], tfs[B], cum[T], slot):
+    ``cum`` is the running total of the runs' lengths, so ``cum[-1]``
+    lanes are valid.  The element gather needs every lane's ``slot`` to
+    find its posting and returns it; the slice copy does not, and leaves
+    ``slot`` None for the caller to compute over the lanes it keeps
+    (``lane_slots``)."""
     t_pad = term_ids.shape[0]
     starts = offsets[term_ids]
     lens = jnp.where(term_active, offsets[term_ids + 1] - starts, 0)
     cum = jnp.cumsum(lens)
-    i = jnp.arange(budget, dtype=jnp.int32)
-    valid = i < cum[-1]
     if slice_lowering(t_pad, budget):
-        # searchsorted(side="right") as t_pad compares a lane: streaming
-        # work, where the binary search's table lookups are element
-        # gathers again
-        slot = jnp.sum(cum[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
-        slot = jnp.minimum(slot, t_pad - 1)
         d, tf = _copy_runs(doc_ids, tfs, starts, lens, cum - lens,
                            budget=budget, pad_doc=pad_doc,
                            chunk=copy_chunk(t_pad, budget))
-        return d, tf, slot, valid
+        return d, tf, cum, None
+    i = jnp.arange(budget, dtype=jnp.int32)
+    valid = i < cum[-1]
     slot = jnp.searchsorted(cum, i, side="right").astype(jnp.int32)
     slot = jnp.minimum(slot, t_pad - 1)
     prev = jnp.where(slot > 0, cum[slot - 1], 0)
     idx = jnp.where(valid, starts[slot] + i - prev, 0)
     d = jnp.where(valid, doc_ids[idx], pad_doc)
     tf = jnp.where(valid, tfs[idx], 0.0)
-    return d, tf, slot, valid
+    return d, tf, cum, slot
+
+
+def lane_slots(cum, lanes: int):
+    """The slot of each of the first ``lanes`` lanes of the slice copy's
+    flat space (``searchsorted(cum, lane, side="right")``, kept inside
+    the slots) as ``t_pad`` compares a lane: streaming work, where the
+    binary search's table lookups are element gathers again."""
+    i = jnp.arange(lanes, dtype=jnp.int32)
+    slot = jnp.sum(cum[None, :] <= i[:, None], axis=1, dtype=jnp.int32)
+    return jnp.minimum(slot, cum.shape[0] - 1)
 
 
 def _copy_runs(doc_ids, tfs, starts, lens, prevs, *, budget: int,
@@ -394,6 +416,44 @@ def sorted_bag(t_pad: int, budget: int, n_pad: int, k: int) -> bool:
     return k <= budget and n_pad * t_pad < 2 ** 31
 
 
+# The smallest bucket whose sorted bag also compiles at half its width
+# (``sorted_width``).  What half the lanes saves is their share of the
+# bag: on one v5e the sorted bag costs 360 us at 262,144 lanes and 1,180
+# at 1,048,576 (the table above), so about 0.15 ms and 0.5 ms a program
+# that fits.  What it costs is a second branch in the program's compile:
+# compiled ahead of time for a v5e on a host without one, 8.8-10.3 s
+# with one sort and 9.0-10.9 with two at 262,144 lanes, 9.9-13.1 and
+# 9.8-14.5 at 1,048,576, 21.1-23.6 and 21.9-29.0 at 4,194,304 (t_pad
+# 4-64; PERF.md section 3).  Of the SPLADE cell's programs, 63.6% of
+# those at 1,048,576 lanes fit half their bucket and 12.6% of those at
+# 262,144 (a sixth of the programs): below this floor a split would save
+# ~0.2% of the kernel time and still add a branch to every 262,144-lane
+# key's compile.
+_NARROW_FLOOR = 1048576
+
+
+def sorted_width(budget: int, postings, k: int):
+    """The lanes a sorted bag's sort, fold and top-k run over: the first
+    ``budget // 2`` where its ``postings`` fit there, else all ``budget``.
+    The gather lays the runs end to end from lane 0, so every valid lane
+    lies in that prefix and the lanes past it are dead ones, which sort
+    last and which no top-k takes before a valid lane: the same bits at
+    either width.  Only from ``_NARROW_FLOOR`` lanes up, where the top-k
+    fits the narrow width and takes the same way at both
+    (``topk_ops.block_size``), so ``device.block_topk_programs`` holds
+    for either.  ``postings`` may be the program's own traced total: the
+    answer is then traced too, and is the branch ``impact_topk_sorted``
+    takes; the host's ``search.term_bag.sorted_lanes`` asks with
+    ``BagDims.postings``.  ``sorted_width(budget, 0, k)`` is the
+    narrowest width the program compiles."""
+    narrow = budget // 2
+    if (budget < _NARROW_FLOOR or k > narrow
+            or topk_ops.block_size(narrow, k)
+            != topk_ops.block_size(budget, k)):
+        return budget
+    return budget - narrow * (postings <= narrow)
+
+
 def impact_topk_sorted(offsets, doc_ids, impacts, term_ids, term_active,
                        idfs, weights, required, min_score, *, n_pad: int,
                        budget: int, k: int, fast: bool):
@@ -417,19 +477,38 @@ def impact_topk_sorted(offsets, doc_ids, impacts, term_ids, term_active,
     elsewhere; lanes are in doc order, so the lower lane of a tie is the
     lower doc id, as over ``[n_pad]``.
 
+    Everything after the copy (a lane's slot, its contribution, the sort,
+    the fold and the top-k) runs over ``sorted_width`` lanes, chosen
+    inside the program from its own total of postings: one program key,
+    two branches from ``_NARROW_FLOOR`` up.
+
     A posting's doc id is below ``n_docs``, so with every doc live the
     live mask is true on every valid lane and is not read; a segment
     with a deleted doc keeps the dense path (its mask would cost an
     element gather a lane)."""
     t_pad = term_ids.shape[0]
     assert sorted_bag(t_pad, budget, n_pad, k)
-    d, imp, slot, valid = gather_postings(
+    d, imp, cum, slot = _gather_runs(
         offsets, doc_ids, impacts, term_ids, term_active,
         budget=budget, pad_doc=n_pad - 1)
-    base = idfs[slot] * imp
-    contrib = jnp.where(valid, weights[slot] * base, 0.0)
-    return sorted_lanes_topk(d, contrib, slot, valid, required, min_score,
-                             t_pad=t_pad, n_pad=n_pad, k=k, fast=fast)
+    total = cum[-1]
+
+    def over(lanes: int):
+        def topk():
+            s = lane_slots(cum, lanes) if slot is None else slot[:lanes]
+            valid = jnp.arange(lanes, dtype=jnp.int32) < total
+            base = idfs[s] * imp[:lanes]
+            contrib = jnp.where(valid, weights[s] * base, 0.0)
+            return sorted_lanes_topk(d[:lanes], contrib, s, valid, required,
+                                     min_score, t_pad=t_pad, n_pad=n_pad,
+                                     k=k, fast=fast)
+        return topk
+
+    narrow = sorted_width(budget, 0, k)
+    if narrow == budget:
+        return over(budget)()
+    return lax.cond(sorted_width(budget, total, k) == narrow,
+                    over(narrow), over(budget))
 
 
 def sorted_lanes_topk(d, contrib, slot, valid, required, min_score, *,

@@ -32,6 +32,7 @@ from opensearch_tpu.index.segment import (
     DeviceSegment,
     Segment,
 )
+from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.ops import topk as topk_ops
 from opensearch_tpu.search import insights
 from opensearch_tpu.search import plan as P
@@ -1119,10 +1120,11 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
-        bag_postings = bag_lanes = None
+        bag_postings = bag_lanes = bag_sorted = None
         if isinstance(plan, P.TermBagPlan) and plan.scored:
             bag_postings = _metrics().counter("search.term_bag.postings")
             bag_lanes = _metrics().counter("search.term_bag.budget_lanes")
+            bag_sorted = _metrics().counter("search.term_bag.sorted_lanes")
             if plan.features:
                 # where the bound plan runs, so a plan-cache hit counts
                 # too; a feature bag under a bool is not seen here
@@ -1197,6 +1199,10 @@ class ShardSearcher:
                             # postings gathered against lanes keyed
                             bag_postings.inc(dims.postings)
                             bag_lanes.inc(dims[1])
+                            if sorts:
+                                # the lanes the program's sort ran over
+                                bag_sorted.inc(bm25_ops.sorted_width(
+                                    dims[1], dims.postings, k))
                         if phrases:
                             # wherever the phrase sits in the plan, and
                             # on a plan-cache hit too: what its anchor

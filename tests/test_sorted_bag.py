@@ -48,25 +48,28 @@ def _csr(rng, n_docs: int, dfs, grid: int = 0):
             np.pad(doc_ids, (0, pad - n)), np.pad(imp, (0, pad - n)))
 
 
-def _dense(cols, tids, active, idfs, weights, required, min_score, *,
-           n_pad, budget, k, fast):
+def _dense_topk(offsets, doc_ids, imp, tids, active, idfs, weights,
+                required, min_score, *, n_pad, budget, k, fast):
     """The dense path as ``TermBagPlan.eval`` and ``_run_topk`` put it
     together, every doc live."""
-    @jax.jit
-    def run(offsets, doc_ids, imp):
-        if fast:
-            scores = bm25_ops.impact_scores(
-                offsets, doc_ids, imp, tids, active, idfs, weights,
-                n_pad=n_pad, budget=budget)
-            matched = scores > 0.0
-        else:
-            scores, count = bm25_ops.impact_score_count(
-                offsets, doc_ids, imp, tids, active, idfs, weights,
-                n_pad=n_pad, budget=budget, scored=True)
-            matched = count >= required
-        scores = jnp.where(matched, scores, 0.0)
-        matched = matched & (scores >= min_score)
-        return P._key_topk(jnp.where(matched, scores, -jnp.inf), k, matched)
+    if fast:
+        scores = bm25_ops.impact_scores(
+            offsets, doc_ids, imp, tids, active, idfs, weights,
+            n_pad=n_pad, budget=budget)
+        matched = scores > 0.0
+    else:
+        scores, count = bm25_ops.impact_score_count(
+            offsets, doc_ids, imp, tids, active, idfs, weights,
+            n_pad=n_pad, budget=budget, scored=True)
+        matched = count >= required
+    scores = jnp.where(matched, scores, 0.0)
+    matched = matched & (scores >= min_score)
+    return P._key_topk(jnp.where(matched, scores, -jnp.inf), k, matched)
+
+
+def _dense(cols, tids, active, idfs, weights, required, min_score, **kw):
+    run = jax.jit(lambda *c: _dense_topk(*c, tids, active, idfs, weights,
+                                         required, min_score, **kw))
     return [np.asarray(x) for x in run(*cols)]
 
 
@@ -259,6 +262,151 @@ def test_the_plans_side_of_the_rule(dims, scored, takes):
 
 
 # --------------------------------------------------------------------------
+# the narrow width (``sorted_width``)
+# --------------------------------------------------------------------------
+
+
+def _bag(rng, t_pad: int, total: int, n_pad: int):
+    """CSR columns of ``t_pad`` terms whose runs hold ``total`` postings
+    between them, every term active; random idfs and weights."""
+    dfs = np.full(t_pad, total // t_pad)
+    dfs[:total % t_pad] += 1
+    cols = _csr(rng, n_pad, dfs)
+    return cols, (jnp.arange(t_pad, dtype=jnp.int32),
+                  jnp.ones(t_pad, bool),
+                  jnp.asarray((rng.random(t_pad) + 0.5).astype(np.float32)),
+                  jnp.asarray((rng.random(t_pad) + 0.5).astype(np.float32)))
+
+
+def _programs(terms, *, n_pad, budget, k, fast):
+    """The dense path and the sorted one traced with the narrow width
+    (``_NARROW_FLOOR`` at ``budget``) and without it, each one program
+    over (columns, required, min_score)."""
+    tids, active, idfs, weights = terms
+
+    def dense(offsets, doc_ids, imp, required, min_score):
+        return _dense_topk(offsets, doc_ids, imp, tids, active, idfs,
+                           weights, required, min_score, n_pad=n_pad,
+                           budget=budget, k=k, fast=fast)
+
+    def sorted_at(floor):
+        def run(offsets, doc_ids, imp, required, min_score):
+            saved, bm25_ops._NARROW_FLOOR = bm25_ops._NARROW_FLOOR, floor
+            try:
+                return bm25_ops.impact_topk_sorted(
+                    offsets, doc_ids, imp, tids, active, idfs, weights,
+                    required, min_score, n_pad=n_pad, budget=budget, k=k,
+                    fast=fast)
+            finally:
+                bm25_ops._NARROW_FLOOR = saved
+        return run
+
+    return [lambda *a, fn=jax.jit(fn): [np.asarray(x) for x in fn(*a)]
+            for fn in (dense, sorted_at(budget), sorted_at(2 * budget))]
+
+
+def _pair_sorts(jaxpr) -> list:
+    """The lanes of each pair sort (by key, with the contributions) of a
+    jaxpr, those of its branches and loops included."""
+    lanes = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "sort" and len(eqn.invars) == 2:
+            lanes.append(eqn.invars[0].aval.shape[0])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            lanes += _pair_sorts(sub)
+    return sorted(lanes)
+
+
+@pytest.mark.parametrize("fast", [True, False], ids=["fast", "counted"])
+@pytest.mark.parametrize("offset", [-1, 0, 1], ids=["under", "at", "over"])
+@pytest.mark.parametrize("t_pad", [1, 8, 64])
+def test_the_narrow_width_is_the_full_widths_result(t_pad, offset, fast,
+                                                    monkeypatch):
+    """A bag of ``budget // 2 + offset`` postings at the floor: the
+    program with both widths (the narrow one taken up to ``budget // 2``)
+    gives what one sort over the whole budget gives, bit for bit on all
+    four results, the ids of ``-inf`` entries too, and the dense path's.
+    The counted program also under ``required`` > 1, a ``min_score`` cut
+    and a bag with fewer than ``k`` matches."""
+    budget = n_pad = 262144
+    k = 10
+    rng = np.random.default_rng(43 + t_pad)
+    total = budget // 2 + offset
+    cols, terms = _bag(rng, t_pad, total, n_pad)
+    monkeypatch.setattr(bm25_ops, "_NARROW_FLOOR", budget)
+    assert bm25_ops.sorted_width(budget, total, k) == (
+        budget // 2 if offset <= 0 else budget)
+    dense, both, one = _programs(terms, n_pad=n_pad, budget=budget, k=k,
+                                 fast=fast)
+    cases = [(1, -np.inf)]
+    if not fast:
+        # two slots of a doc; a cut inside the top k; nothing past the cut
+        cut = float(dense(*cols, 1, -np.inf)[0][k // 2])
+        cases += [(min(2, t_pad), -np.inf), (1, cut), (1, 1e9)]
+    for required, min_score in cases:
+        args = (*cols, np.int32(required), np.float32(min_score))
+        want, got = dense(*args), both(*args)
+        for b, o in zip(got, one(*args)):
+            assert b.dtype == o.dtype and b.tobytes() == o.tobytes()
+        keep = want[0] > -np.inf
+        assert np.array_equal(want[0].view(np.int32), got[0].view(np.int32))
+        assert np.array_equal(want[1][keep], got[1][keep])
+        assert int(want[2]) == int(got[2])
+        assert want[3].view(np.int32) == got[3].view(np.int32)
+        if min_score == 1e9:
+            assert int(got[2]) == 0 and not keep.any()
+        elif min_score > -np.inf:
+            assert int(got[2]) > k // 2 and keep[:k // 2 + 1].all()
+        elif required == 1:
+            assert int(got[2]) > k and keep.all()
+
+
+@pytest.mark.parametrize("budget,postings,k,width", [
+    ("floor", "half", 10, "half"),
+    ("floor", "half_and_one", 10, "floor"),
+    ("floor", 0, 10, "half"),
+    ("floor", "half", "half_and_one", "floor"),      # k past the narrow lanes
+    ("floor", "half", "block_differs", "floor"),
+    ("four_floors", "half", 10, "half"),
+    ("four_floors", "half_and_one", 10, "four_floors"),
+], ids=["fits", "one_over", "empty", "k_over_half", "block_size_differs",
+        "above_the_floor", "above_the_floor_over"])
+def test_the_width_rule(budget, postings, k, width):
+    floor = bm25_ops._NARROW_FLOOR
+    b = {"floor": floor, "four_floors": 4 * floor}[budget]
+    if k == "block_differs":
+        k = next(k for k in range(1, b // 2)
+                 if topk_ops.block_size(b // 2, k)
+                 != topk_ops.block_size(b, k))
+    named = {"half": b // 2, "half_and_one": b // 2 + 1, "floor": b,
+             "four_floors": b}
+    got = bm25_ops.sorted_width(b, named.get(postings, postings),
+                                named.get(k, k))
+    assert got == named[width] and type(got) is int
+
+
+@pytest.mark.parametrize("budget", ["floor", "below"])
+def test_below_the_floor_nothing_splits(budget):
+    """Under ``_NARROW_FLOOR`` a program holds one sort of its whole
+    budget whatever its postings; from the floor up, two."""
+    floor = bm25_ops._NARROW_FLOOR
+    b = floor if budget == "floor" else floor // 4
+    for postings in (0, 1, b // 4, b // 2):
+        assert bm25_ops.sorted_width(b, postings, 10) == (
+            b // 2 if b == floor else b)
+    assert bm25_ops.sorted_width(floor // 2, 0, 10) == floor // 2
+    n_pad, t_pad = 4 * b, 8
+    cols = [jnp.zeros(t_pad + 2, jnp.int32), jnp.zeros(b, jnp.int32),
+            jnp.zeros(b, jnp.float32)]
+    jaxpr = jax.make_jaxpr(lambda o, d, imp: bm25_ops.impact_topk_sorted(
+        o, d, imp, jnp.zeros(t_pad, jnp.int32), jnp.ones(t_pad, bool),
+        jnp.ones(t_pad, jnp.float32), jnp.ones(t_pad, jnp.float32),
+        jnp.int32(1), jnp.float32(-jnp.inf), n_pad=n_pad, budget=b, k=10,
+        fast=True))(*cols)
+    assert _pair_sorts(jaxpr.jaxpr) == ([b // 2, b] if b == floor else [b])
+
+
+# --------------------------------------------------------------------------
 # through ShardSearcher.search
 # --------------------------------------------------------------------------
 
@@ -383,6 +531,43 @@ def test_the_sorted_program_is_the_dense_programs_result(searcher):
                                       np.asarray(other[1])[keep])
                 assert dense[2] == int(other[2])
                 assert dense[3] == float(other[3])
+
+
+def test_the_rule_the_kernels_branch_and_the_counter_agree(searcher,
+                                                           monkeypatch):
+    """With the floor at the smallest bucket every program of these bags
+    has two widths: the one each program's kernel ran (eagerly, so its
+    ``lax.cond`` runs one branch), ``sorted_width`` over the postings
+    ``prepare`` saw, and what ``search.term_bag.sorted_lanes`` counted
+    are the same, and both widths occur."""
+    from opensearch_tpu.common.telemetry import metrics
+
+    monkeypatch.setattr(bm25_ops, "_NARROW_FLOOR", 4096)
+    ran = []
+    inner = bm25_ops.sorted_lanes_topk
+
+    def recording(d, *args, **kw):
+        ran.append(d.shape[0])
+        return inner(d, *args, **kw)
+
+    monkeypatch.setattr(bm25_ops, "sorted_lanes_topk", recording)
+    counter = metrics().counter("search.term_bag.sorted_lanes")
+    wide = {"match": {"body": "w1 w2 w3 w4"}}
+    choices = set()
+    for query in (MATCH, wide, SPARSE):
+        plan, bind = searcher.compiled(query)
+        rule = []
+        for seg in searcher.segments:
+            dims, _ins = plan.prepare(bind, seg, seg.device(), searcher.ctx)
+            rule.append(bm25_ops.sorted_width(dims[1], dims.postings, 10))
+            choices.add(rule[-1] == dims[1])
+        before, ran[:] = counter.value, []
+        with jax.disable_jit():
+            resp = searcher.search({"query": query, "size": 10})
+        assert resp["_shards"]["failed"] == 0 and resp["hits"]["hits"]
+        assert ran == rule
+        assert counter.value - before == sum(rule)
+    assert choices == {True, False}
 
 
 def test_hybrid_match_half_is_sorted(searcher, monkeypatch):

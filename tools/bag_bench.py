@@ -20,7 +20,19 @@ three must agree bit for bit (values, total, maximum, and ids above
 ``-inf``).  Prints one JSON line a case, microseconds a bag.  A time is a
 device time only where ``platform`` is ``tpu``.
 
+With ``widths`` first, the numbers that set ``ops/bm25.py::
+_NARROW_FLOOR`` instead: the whole ``impact_topk_sorted`` program (gather
+included) compiled with one sort (``one``, the narrow width disabled) and
+with both widths (``two``), over one CSR segment of ``n_pad`` 262,144
+whose ``t_pad`` terms fill three quarters of the ``budget``; ``half`` of
+them active fill three eighths, so ``two`` takes its narrow branch there
+and its full one with every term active.  A case is ``t_pad x budget``;
+a line gives us a bag for each program at each fill, the compile
+seconds of each (the compile cache off) and whether the two agree bit
+for bit on all four results, the ids of ``-inf`` entries too.
+
 Usage: python tools/bag_bench.py [t_padxbudgetxn_padxfill ...]
+       python tools/bag_bench.py widths [t_padxbudget ...]
 """
 
 from __future__ import annotations
@@ -128,7 +140,87 @@ def timed(fn, ins, rows: int) -> float:
     return round(best / rows * 1e6, 1)
 
 
+WIDTH_CASES = [f"{t}x{b}" for b in (65536, 262144, 1048576, 4194304)
+               for t in (8, 32, 64)]
+WIDTH_N_PAD = 262144
+
+
+def segment(rng, t_pad: int, budget: int):
+    """CSR columns (offsets, doc ids, impacts) of ``t_pad`` terms whose
+    runs fill three quarters of ``budget``, each a sorted sample of the
+    segment's docs without repeats."""
+    df = budget * 3 // 4 // t_pad
+    runs = [np.sort(rng.permutation(WIDTH_N_PAD)[:df]) for _ in range(t_pad)]
+    offsets = np.arange(t_pad + 2, dtype=np.int32) * df
+    offsets[-1] = offsets[-2]
+    doc_ids = np.concatenate(runs).astype(np.int32)
+    imp = (rng.random(len(doc_ids)) + 0.01).astype(np.float32)
+    return jnp.asarray(offsets), jnp.asarray(doc_ids), jnp.asarray(imp)
+
+
+def bag_program(t_pad: int, budget: int, rows: int, floor: int):
+    """``rows`` bags, each the segment's terms in its own slot order, in
+    one program; ``floor`` is the ``_NARROW_FLOOR`` it is traced with."""
+    def one_bag(offsets, doc_ids, imp, tids, active, idfs, weights):
+        return bm25_ops.impact_topk_sorted(
+            offsets, doc_ids, imp, tids, active, idfs, weights,
+            jnp.int32(1), jnp.float32(-jnp.inf), n_pad=WIDTH_N_PAD,
+            budget=budget, k=TOP, fast=True)
+
+    def run(offsets, doc_ids, imp, tids, active, idfs, weights):
+        saved, bm25_ops._NARROW_FLOOR = bm25_ops._NARROW_FLOOR, floor
+        try:
+            first = one_bag(offsets, doc_ids, imp, tids[0], active,
+                            idfs[0], weights[0])
+
+            def body(r, acc):
+                vals, ids, tot, mx = one_bag(offsets, doc_ids, imp, tids[r],
+                                             active, idfs[r], weights[r])
+                return (acc[0] + jnp.where(vals > -jnp.inf, vals, 0).sum()
+                        + mx,
+                        acc[1] + (ids.sum() + tot).astype(jnp.int32))
+            return first, lax.fori_loop(1, rows, body,
+                                        (jnp.float32(0), jnp.int32(0)))
+        finally:
+            bm25_ops._NARROW_FLOOR = saved
+    return run
+
+
+def widths(argv):
+    jax.config.update("jax_enable_compilation_cache", False)
+    rng = np.random.default_rng(43)
+    platform = jax.devices()[0].platform
+    for case in argv or WIDTH_CASES:
+        t_pad, budget = (int(x) for x in case.split("x"))
+        rows = 17 if budget <= 262144 else 9 if budget <= 1048576 else 5
+        cols = segment(rng, t_pad, budget)
+        tids = jnp.asarray(np.stack([rng.permutation(t_pad)
+                                     for _ in range(rows)]).astype(np.int32))
+        idfs = jnp.asarray((rng.random((rows, t_pad)) + 0.5).astype(np.float32))
+        weights = jnp.asarray(
+            (rng.random((rows, t_pad)) + 0.5).astype(np.float32))
+        fills = {"half": jnp.arange(t_pad) < t_pad // 2,
+                 "full": jnp.ones(t_pad, bool)}
+        line = {"t_pad": t_pad, "budget": budget, "n_pad": WIDTH_N_PAD,
+                "platform": platform, "rows": rows}
+        got = {}
+        for name, floor in (("one", 2 ** 62), ("two", 0)):
+            t0 = time.perf_counter()
+            fn = jax.jit(bag_program(t_pad, budget, rows, floor)).lower(
+                *cols, tids, fills["full"], idfs, weights).compile()
+            line[f"{name}_compile_s"] = round(time.perf_counter() - t0, 2)
+            for fill, active in fills.items():
+                ins = (*cols, tids, active, idfs, weights)
+                got[name, fill] = [np.asarray(x) for x in fn(*ins)[0]]
+                line[f"{name}_{fill}_us"] = timed(fn, ins, rows)
+        line["same"] = all(a.tobytes() == b.tobytes() for f in fills
+                           for a, b in zip(got["one", f], got["two", f]))
+        print(json.dumps(line), flush=True)
+
+
 def main(argv):
+    if argv[:1] == ["widths"]:
+        return widths(argv[1:])
     rng = np.random.default_rng(36)
     platform = jax.devices()[0].platform
     for case in argv or CASES:
