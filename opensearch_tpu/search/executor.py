@@ -31,6 +31,7 @@ from opensearch_tpu.index.segment import (
     LONG_MISSING_MIN,
     DeviceSegment,
     Segment,
+    pad_pow2,
 )
 from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.ops import topk as topk_ops
@@ -246,6 +247,21 @@ def _min_score_scalar(min_score):
     return jnp.asarray(np.float32(min_score))  # staging-ok: 4-byte scalar
 
 
+_ROWS: tuple = ()
+
+
+def _row_scalars(n: int) -> tuple:
+    """Device int32 scalars ``0 .. n - 1``, staged once a process: a
+    stacked program's row is an input, never a Python int (jit would copy
+    it every call) nor static (a program a row)."""
+    global _ROWS
+    if len(_ROWS) < n:
+        _ROWS = _ROWS + tuple(
+            # staging-ok: one cached 4-byte scalar a row, once a process
+            jnp.asarray(np.int32(i)) for i in range(len(_ROWS), n))
+    return _ROWS
+
+
 def _ledger():
     from opensearch_tpu.common.device_ledger import device_ledger
     return device_ledger()
@@ -281,6 +297,89 @@ def _degraded(plan, why: str, exc: Optional[BaseException] = None):
     cause = f" ({type(exc).__name__}: {exc})" if exc is not None else ""
     return DeviceDegradedError(
         f"{why}: plan [{type(plan).__name__}] has no host fallback{cause}")
+
+
+class _StackedBag:
+    """A scored term bag at a request's root, bound on the host for every
+    segment it may launch on before the first launch and staged as ONE
+    ``int32[S_pad, 4 * t_pad + 1]``: a segment's packed inputs are a row
+    of it, and its program takes the stacked array and the row (a device
+    scalar, ``_row_scalars``) in place of its own packed array
+    (``plan._term_row``).  ``S_pad``, the segments rounded up to a power
+    of two and at least 8, lets a refresh or a merge mint no program key.
+    The prepared-bindings cache keeps the request's dims and its one
+    array under ``(ckey, the segments' identities)``: one get, one put and
+    one eviction a request, and nothing staged on a hit.  The cache and
+    the bind run in the first launched segment's ``segment.prepare``
+    span; every later one takes its row under ``cache_get``.  A segment
+    without a row, or every segment where one is quantized, prepares on
+    its own (``ShardSearcher._prepared``)."""
+
+    def __init__(self, searcher, plan, bind, ckey, prof, ms_host):
+        self.searcher = searcher
+        self.plan, self.bind, self.ckey = plan, bind, ckey
+        self.prof, self.ms_host = prof, ms_host
+        self.rows = None           # {id(seg): (dims, ins)} once bound
+        self.hit = False
+
+    def inputs(self, seg, span):
+        """``seg``'s (dims, ins), or None where it prepares on its own."""
+        if self.rows is None:
+            self.rows = {}         # a bind that raises leaves it off
+            self.rows = self._bound(span)
+            got = self.rows.get(id(seg))
+        else:
+            with span.part("cache_get"):
+                got = self.rows.get(id(seg))
+        if got is not None:
+            if self.hit:
+                span.set_attribute("prepared", "hit")
+            if self.prof is not None:
+                self.prof.inc("prepared_hits" if self.hit
+                              else "prepared_misses")
+        return got
+
+    def _bound(self, span):
+        s = self.searcher
+        segs = [seg for seg in s.segments
+                if s._prune_reason(self.plan, self.bind, seg, self.ms_host,
+                                   None) is None
+                and not getattr(seg, "_device_evicted", False)]
+        if self.ckey is None:
+            return self._bind(segs, span)
+        with span.part("cache_get"):
+            cache = s._prepared_cache()
+            key = (self.ckey, tuple(id(seg) for seg in segs))
+            out = cache.get(key)
+        if out is not None:
+            self.hit = True
+            return out
+        out = self._bind(segs, span)
+        if out:
+            with span.part("cache_put"):
+                cache.put(key, out)
+        return out
+
+    def _bind(self, segs, span):
+        plan, bind, ctx = self.plan, self.bind, self.searcher.ctx
+        with span.part("bind"), (
+                self.prof.phase("prepare") if self.prof is not None
+                else contextlib.nullcontext()):
+            dsegs = [seg.device() for seg in segs]
+            if not segs or (not plan.features and any(
+                    getattr(d, "quantized_mode", False) for d in dsegs)):
+                return {}
+            with P.host_inputs():
+                bound = [plan.prepare(bind, seg, dseg, ctx)
+                         for seg, dseg in zip(segs, dsegs)]
+            host = np.zeros((pad_pow2(len(bound), minimum=8),
+                             len(bound[0][1][0])), np.int32)
+            for i, (_dims, ins) in enumerate(bound):
+                host[i] = ins[0]
+            stacked = _ledger().stage_input(host)
+            rows = _row_scalars(len(host))
+            return {id(seg): (dims, (stacked,) + ins[1:] + (rows[i],))
+                    for i, (seg, (dims, ins)) in enumerate(zip(segs, bound))}
 
 
 class ShardSearcher:
@@ -383,14 +482,15 @@ class ShardSearcher:
         return total
 
     def _segment_inputs(self, plan, bind, seg, needed, ckey, prof,
-                        dseg=None):
+                        dseg=None, stacked=None):
         """(dseg, dims, ins, A): everything one segment's program takes,
         under a ``segment.prepare`` span — the host work and the H2D
         before a launch.  The span's parts: ``device`` (``seg.device()``),
-        ``cache_get`` / ``bind`` / ``cache_put`` (``_prepared``) and
-        ``arrays`` (the live mask and ``build_arrays``).  ``dseg``: a
-        staging of the caller's own (the mesh stages each shard on its
-        chip), in place of ``seg.device()``."""
+        ``cache_get`` / ``bind`` / ``cache_put`` (``_prepared``, or the
+        request's ``stacked`` bind) and ``arrays`` (the live mask and
+        ``build_arrays``).  ``dseg``: a staging of the caller's own (the
+        mesh stages each shard on its chip), in place of
+        ``seg.device()``."""
         with _tracer().start_span("segment.prepare", {"prepared": "miss"},
                                   cpu=True) as span:
             if dseg is None:
@@ -398,8 +498,9 @@ class ShardSearcher:
                     dseg = seg.device()
             # prepare FIRST: dims tells build_arrays which array groups
             # the lowering left deliberately partial (quantized segments)
-            dims, ins = self._prepared(plan, bind, seg, dseg, ckey, prof,
-                                       span)
+            got = stacked.inputs(seg, span) if stacked is not None else None
+            dims, ins = got or self._prepared(plan, bind, seg, dseg, ckey,
+                                              prof, span)
             with span.part("arrays"):
                 A = build_arrays(dseg, needed, self.mapper,
                                  live=self.ctx.live_jnp(seg, dseg),
@@ -426,13 +527,8 @@ class ShardSearcher:
 
         if ckey is None:
             return prepare()
-        from opensearch_tpu.common.cache import attached_cache
-
         with span.part("cache_get"):
-            cache = attached_cache(self, "_prep_cache",
-                                   name="search.prepare",
-                                   max_weight=64 << 20, breaker="fielddata",
-                                   weigher=self._prep_weight)
+            cache = self._prepared_cache()
             key = (ckey, id(seg))
             out = cache.get(key)
         if out is None:
@@ -444,6 +540,13 @@ class ShardSearcher:
             if prof is not None:
                 prof.inc("prepared_hits")
         return out
+
+    def _prepared_cache(self):
+        from opensearch_tpu.common.cache import attached_cache
+
+        return attached_cache(self, "_prep_cache", name="search.prepare",
+                              max_weight=64 << 20, breaker="fielddata",
+                              weigher=self._prep_weight)
 
     # -- public API -------------------------------------------------------
 
@@ -944,15 +1047,7 @@ class ShardSearcher:
         holder dispatched earlier, so it wins any tie at exactly the
         bound by the seg-asc tie-break, and totals become a lower
         bound).  A pruned segment is counted here, for every caller."""
-        reason = None
-        if not plan.can_match(bind, seg):
-            reason = "pruned_can_match"
-        elif ms_host is not None or kth is not None:
-            bound = plan.max_score_bound(bind, seg)
-            if ms_host is not None and bound < ms_host:
-                reason = "pruned_min_score"
-            elif kth is not None and bound <= kth:
-                reason = "pruned_kth"
+        reason = self._prune_reason(plan, bind, seg, ms_host, kth)
         if reason is not None:
             _metrics().counter("search.segments_pruned").inc()
             if iattrs is not None:
@@ -961,6 +1056,19 @@ class ShardSearcher:
                 prof.seg_pruned(seg.seg_id, reason,
                                 time.monotonic() - t_seg)
         return reason
+
+    @staticmethod
+    def _prune_reason(plan, bind, seg, ms_host, kth):
+        """``_pruned``'s reason, uncounted."""
+        if not plan.can_match(bind, seg):
+            return "pruned_can_match"
+        if ms_host is not None or kth is not None:
+            bound = plan.max_score_bound(bind, seg)
+            if ms_host is not None and bound < ms_host:
+                return "pruned_min_score"
+            if kth is not None and bound <= kth:
+                return "pruned_kth"
+        return None
 
     def _score_on_host(self, plan, bind, seg, k_want, min_score, why,
                        exc=None):
@@ -1120,11 +1228,17 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
-        bag_postings = bag_lanes = bag_sorted = None
+        bag_postings = bag_lanes = bag_sorted = bag_stacked = stacked = None
         if isinstance(plan, P.TermBagPlan) and plan.scored:
             bag_postings = _metrics().counter("search.term_bag.postings")
             bag_lanes = _metrics().counter("search.term_bag.budget_lanes")
             bag_sorted = _metrics().counter("search.term_bag.sorted_lanes")
+            bag_stacked = _metrics().counter(
+                "search.term_bag.stacked_segments")
+            if not allow_kth_prune:
+                # one staged input a request; a running k-th prunes after
+                # the bind, so that request stages one a segment
+                stacked = _StackedBag(self, plan, bind, ckey, prof, ms_host)
             if plan.features:
                 # where the bound plan runs, so a plan-cache hit counts
                 # too; a feature bag under a bool is not seen here
@@ -1173,7 +1287,8 @@ class ShardSearcher:
                 else:
                     try:
                         dseg, dims, ins, A = self._segment_inputs(
-                            plan, bind, seg, needed, ckey, prof)
+                            plan, bind, seg, needed, ckey, prof,
+                            stacked=stacked)
                         k = min(k_want, dseg.n_pad)
                         sorts = (plan.sorted_topk(dims, dseg.n_pad, k)
                                  and self.ctx.all_live(seg))
@@ -1203,6 +1318,8 @@ class ShardSearcher:
                                 # the lanes the program's sort ran over
                                 bag_sorted.inc(bm25_ops.sorted_width(
                                     dims[1], dims.postings, k))
+                            if ins[0].ndim == 2:   # a row of the stack
+                                bag_stacked.inc()
                         if phrases:
                             # wherever the phrase sits in the plan, and
                             # on a plan-cache hit too: what its anchor

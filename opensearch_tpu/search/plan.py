@@ -108,6 +108,16 @@ def _pack_term_inputs(tids, active, idfs, weights, required) -> np.ndarray:
     return out
 
 
+def _term_row(ins):
+    """A bag's packed inputs out of its ``ins``: ``ins[0]`` itself, or,
+    where a request's segments share one stacked ``int32[S_pad, 4 * t_pad
+    + 1]`` (rank 2: ``ShardSearcher``'s stacked bind), the row that
+    ``ins[-1]``, a device scalar, names."""
+    if ins[0].ndim == 2:
+        return lax.dynamic_index_in_dim(ins[0], ins[-1], keepdims=False)
+    return ins[0]
+
+
 def _unpack_term_inputs(packed, t_pad: int):
     """Traced side of ``_pack_term_inputs``: (tids i32[t_pad], active
     bool[t_pad], idfs f32[t_pad], weights f32[t_pad], required i32) by
@@ -452,7 +462,7 @@ class TermBagPlan(Plan):
         p = A["postings"][self.field]
         t_pad, budget, fast = dims
         tids, active, idfs, weights, required = _unpack_term_inputs(
-            ins[0], t_pad)
+            _term_row(ins), t_pad)
         return bm25_ops.impact_topk_sorted(  # engine-ok: TermBag scored lowering
             p["offsets"], p["doc_ids"], ins[1], tids, active, idfs,
             weights, required, min_score, n_pad=A["live"].shape[0],
@@ -486,7 +496,7 @@ class TermBagPlan(Plan):
         p = A["postings"][self.field]
         n_pad = A["live"].shape[0]
         tids, active, idfs, weights, required = _unpack_term_inputs(
-            ins[0], dims[0])
+            _term_row(ins), dims[0])
         if self.scored and len(dims) == 4:
             t_pad, budget, fast, width = dims
             qvals, scales, exact_vals, exact_offsets, packed, base = ins[1:]

@@ -261,14 +261,15 @@ def test_stage_input_counts_a_transfer_and_keeps_nothing_resident():
 
 
 def test_a_search_counts_its_per_query_inputs():
-    """One packed array a term-bag segment (PR 32), none when the same
-    query comes again: the prepared-bindings cache owns what was staged."""
+    """One stacked array a scored term bag's request, eight rows of nine
+    words at least, none when the same query comes again: the
+    prepared-bindings cache owns what was staged."""
     s = _searcher(n_segs=2)
     led = device_ledger()
     body = {"query": {"match": {"t": "alpha beta"}}, "size": 3}
     s.search(dict(body))
     first = led.stats()["transfers"]["input"]
-    assert first == {"bytes": 2 * 9 * 4, "arrays": 2}
+    assert first == {"bytes": 8 * 9 * 4, "arrays": 1}
     s.search(dict(body))
     assert led.stats()["transfers"]["input"] == first
 

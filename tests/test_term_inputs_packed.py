@@ -253,19 +253,23 @@ def _staged(node, query, hits=True) -> tuple:
             after["bytes"] - before["bytes"])
 
 
+S_PAD = 8                   # the stacked rows of up to eight segments
+
+
 def test_one_input_array_a_scanned_segment_and_none_on_a_hit(node):
-    # two terms: t_pad 2, nine words of four bytes a segment
-    assert _staged(node, {"match": {"t": "alpha w1"}}) == (S, S * 9 * 4)
+    # a scored bag at the root: its segments' rows cross as one array;
+    # two terms: t_pad 2, nine words of four bytes a row
+    assert _staged(node, {"match": {"t": "alpha w1"}}) == (1, S_PAD * 9 * 4)
     # the same body again: every segment's inputs are there already
     assert _staged(node, {"match": {"t": "alpha w1"}}) == (0, 0)
     # another query misses again
-    assert _staged(node, {"match": {"t": "alpha w2"}}) == (S, S * 9 * 4)
+    assert _staged(node, {"match": {"t": "alpha w2"}}) == (1, S_PAD * 9 * 4)
 
 
 def test_a_segment_that_cannot_match_stages_nothing(node):
     """``can_match`` drops the two segments without the word before any
     ``prepare``: one program, one array."""
-    assert _staged(node, {"match": {"t": "only2"}}) == (1, 5 * 4)
+    assert _staged(node, {"match": {"t": "only2"}}) == (1, S_PAD * 5 * 4)
     assert _staged(node, {"match": {"t": "nowhere"}}, hits=False) == (0, 0)
 
 
