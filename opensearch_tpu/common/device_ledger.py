@@ -415,17 +415,19 @@ class DeviceResidencyLedger:
                         phrase: bool = False,
                         mesh: bool = False) -> None:
         """One device program consumed this group's arrays — the LRU
-        signal budget eviction orders by.  ``slice_gather``: the
-        program's static shape took ``gather_postings``'s contiguous-
-        slice lowering (``ops/bm25.py::slice_lowering``, asked by the
-        caller as the kernel asks it).  ``block_topk``: its top-k took
-        the two stages of ``ops/topk.py`` (``block_size`` of the
-        lanes of the program's key and ``k``, asked the same way).
-        ``sorted_bag``: a scored term bag's top-k came from its sorted
-        postings, without the dense accumulator (``Plan.sorted_topk``,
-        i.e. ``ops/bm25.py::sorted_bag``, over a segment with no
-        deleted doc).  ``phrase``: the program holds a ``PhrasePlan``
-        somewhere in its plan (``plan.phrase_dims`` of its key).
+        signal budget eviction orders by.  The flags are what
+        ``search/plan.py::choose`` found of the program, booked by
+        ``plan.book``, the one caller on the query path; ``choose`` asks
+        the rules the kernels ask.  ``slice_gather``: the program's
+        static shape took ``gather_postings``'s contiguous-slice
+        lowering (``ops/bm25.py::slice_lowering``).  ``block_topk``: its
+        top-k took the two stages of ``ops/topk.py`` (``block_size`` of
+        the lanes of the program's key and ``k``).  ``sorted_bag``: a
+        scored term bag's top-k came from its sorted postings, without
+        the dense accumulator (``Plan.sorted_topk``, i.e. ``ops/bm25.py
+        ::sorted_bag``, over a segment with no deleted doc).
+        ``phrase``: the program holds a ``PhrasePlan`` somewhere in its
+        plan (``plan.phrase_dims`` of its key).
         ``mesh``: one program over the chips of a mesh (a segment index
         of every shard, or the merge; ``MeshSearcher``): it belongs to
         no one segment's group, and ``stats()`` adds it to

@@ -35,7 +35,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from opensearch_tpu.common.telemetry import metrics as _metrics
 from opensearch_tpu.common.telemetry import tracer as _tracer
 from opensearch_tpu.ops import bm25 as bm25_ops
-from opensearch_tpu.ops import topk as topk_ops
 from opensearch_tpu.search.fetch import filter_source
 
 
@@ -153,7 +152,7 @@ class MeshSearcher:
     A scored top-k request runs ``ShardSearcher``'s own lowering: every
     shard compiles the query through its plan cache with its own
     statistics (the reference's default ``query_then_fetch``) and
-    prepares each segment through ``_segment_inputs`` / ``_prepared``.
+    prepares each segment through ``ShardSearcher.segment_inputs``.
     Segment index ``g`` of every shard then runs as ONE SPMD program
     over the chips (``run_topk_mesh``): the program key common to the
     shards, the per-query inputs as one array sharded over the mesh,
@@ -469,14 +468,14 @@ class MeshSearcher:
         from opensearch_tpu.common.device_ledger import device_ledger
         from opensearch_tpu.common.tasks import check_current
         from opensearch_tpu.search import plan as planmod
-        from opensearch_tpu.search.profile import QueryProfiler
+        from opensearch_tpu.search.profile import NULL_PROFILER, QueryProfiler
 
         size = int(body.get("size", 10))
         from_ = int(body.get("from", 0))
         k = max(from_ + size, 1)
         if not S or any(len(s.segments) != G for s in self.shards):
             return None
-        profs = [QueryProfiler() if body.get("profile") else None
+        profs = [QueryProfiler() if body.get("profile") else NULL_PROFILER
                  for _ in self.shards]
         compiled = []
         for si, shard in enumerate(self.shards):
@@ -492,7 +491,6 @@ class MeshSearcher:
         mesh = self._sub_mesh(S)
         sharding = NamedSharding(mesh, P(self.axis))
         ms = self._min_score(mesh, body.get("min_score"))
-        led = device_ledger()
         parts, seg_of_part = [], []
         for g in range(G):
             check_current()        # cancellation point per segment index
@@ -506,7 +504,7 @@ class MeshSearcher:
                 with planmod.host_inputs():
                     for si, shard in enumerate(self.shards):
                         with jax.default_device(self.devices[si]):
-                            _d, dims, ins, A = shard._segment_inputs(
+                            _d, dims, ins, A = shard.segment_inputs(
                                 plan, binds[si], segs[si], needed,
                                 (_MESH_KEY, compiled[si][1]), profs[si],
                                 dseg=row[si])
@@ -521,18 +519,15 @@ class MeshSearcher:
                 (A, ins), shapes = placed
                 n_pad = row[0].n_pad
                 kk = min(k, n_pad)
-                sorts = (plan.sorted_topk(key, n_pad, kk)
-                         and all(s.ctx.all_live(seg)
-                                 for s, seg in zip(self.shards, segs)))
+                program = planmod.choose(
+                    plan, key, n_pad, kk,
+                    all(s.ctx.all_live(seg)
+                        for s, seg in zip(self.shards, segs)))
             with _tracer().start_span("mesh.launch", {"segment": g}):
                 parts.append(run_topk_mesh(
-                    mesh, self.axis, plan, key, kk, k, sorts, shapes, A,
-                    ins, ms))
-            led.record_dispatch(
-                None, mesh=True, slice_gather=plan.slice_gathers(key),
-                sorted_bag=sorts,
-                block_topk=topk_ops.block_size(key[1] if sorts else n_pad,
-                                               kk))
+                    mesh, self.axis, plan, key, kk, k, program.sorted_bag,
+                    shapes, A, ins, ms))
+            planmod.book(program, mesh=True)
             seg_of_part.append(g)
 
         total, max_score, rows = 0, None, []
@@ -540,12 +535,13 @@ class MeshSearcher:
             with _tracer().start_span("mesh.launch", {"segment": "merge"}):
                 out = merge_topk_mesh(mesh, self.axis, k, tuple(parts))
                 out.copy_to_host_async()
-            led.record_dispatch(None, mesh=True)
+            planmod.book(planmod.Program(), mesh=True)
             t_sync = time.monotonic()
             with _tracer().start_span("device.sync", {"site": "mesh"},
                                       cpu=True):
                 packed = np.asarray(out)
-            led.record_fetch(packed.nbytes, time.monotonic() - t_sync)
+            device_ledger().record_fetch(packed.nbytes,
+                                         time.monotonic() - t_sync)
             vals = packed[:k].view(np.float32)
             bad = check_finite(vals)
             if bad:
@@ -586,7 +582,7 @@ class MeshSearcher:
             "hits": {"total": {"value": total, "relation": "eq"},
                      "max_score": max_score, "hits": hits},
         }
-        if profs[0] is not None:
+        if body.get("profile"):
             from opensearch_tpu.search.profile import describe_plan
             sections = []
             for si, shard in enumerate(self.shards):

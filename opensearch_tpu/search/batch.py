@@ -59,6 +59,8 @@ from opensearch_tpu.common.device_ledger import \
 from opensearch_tpu.common.telemetry import metrics as _metrics
 from opensearch_tpu.index.segment import pad_bucket, pad_pow2
 from opensearch_tpu.ops import bm25 as bm25_ops
+from opensearch_tpu.search import plan as P
+from opensearch_tpu.search.profile import NULL_PROFILER
 
 _I32 = np.int32
 _F32 = np.float32
@@ -243,7 +245,7 @@ class BatchGroup:
                 "weights": self.weights[qi],
                 "required": self.required[qi], "avgdl": self.avgdl}
 
-    def _run_host(self, searcher, prof=None) -> dict:
+    def _run_host(self, searcher, prof=NULL_PROFILER) -> dict:
         """The group's recovery backend, never its first choice: ``run``
         comes here when the ``batch`` or ``staging`` breaker is open,
         when the device program raised a device error, and when its
@@ -252,31 +254,25 @@ class BatchGroup:
         tables — byte-identical to the device group and to the
         sequential path by construction (same accumulation order).
         Counted as one host fallback a group."""
-        import time
-
         from opensearch_tpu.common.tasks import check_current
-        from opensearch_tpu.search.plan import TermBagPlan
 
         _device_ledger().record_host_fallback()
-        if prof is not None:
-            prof.set("execution_path", "host_batched")
-        plan = TermBagPlan(field=self.field, scored=True)
+        prof.set("execution_path", "host_batched")
+        plan = P.TermBagPlan(field=self.field, scored=True)
         acc = {pos: {"v": [], "s": [], "l": [], "tot": 0, "mx": -np.inf}
                for pos in self.positions}
         pruned = 0
         scanned = 0
         for seg_order, seg in enumerate(searcher.segments):
             check_current()    # cancellation point per segment
-            t_seg = time.monotonic() if prof is not None else 0.0
+            clock = prof.segment(seg.seg_id)
             pf = seg.postings.get(self.field)
             if pf is None:
                 continue
             if not any(pf.term_id(t) >= 0
                        for terms in self.terms for t in terms):
                 pruned += 1    # no query term here: skip scoring
-                if prof is not None:
-                    prof.seg_pruned(seg.seg_id, "pruned_can_match",
-                                    time.monotonic() - t_seg)
+                clock.pruned("pruned_can_match")
                 continue
             live = searcher.ctx.lives[id(seg)]
             for qi, pos in enumerate(self.positions):
@@ -290,35 +286,31 @@ class BatchGroup:
                 a["tot"] += int(tot)
                 a["mx"] = max(a["mx"], float(mx))
             scanned += 1
-            if prof is not None:
-                # the per-segment dispatch attribution includes scoring
-                prof.seg_scanned(seg.seg_id, time.monotonic() - t_seg)
+            clock.scanned()    # the dispatch attribution includes scoring
         if pruned:
             _metrics().counter("search.segments_pruned").inc(pruned)
         # group-level attribution the msearch member insight records
         # carry (shared by construction — ONE pass served the group)
         self.last_stats = {"pruned": pruned, "scanned": scanned,
                            "path": "host_batched"}
-        t_red = time.monotonic() if prof is not None else 0.0
         out = {}
-        for pos in self.positions:
-            a = acc[pos]
-            if not a["v"]:
-                out[pos] = ([], 0, None)
-                continue
-            v = np.concatenate(a["v"])
-            s = np.concatenate(a["s"])
-            l = np.concatenate(a["l"])
-            order = np.lexsort((l, s, -v))[: self.k]
-            rows = [{"seg": int(s[i]), "local": int(l[i]),
-                     "score": float(v[i])} for i in order]
-            out[pos] = (rows, a["tot"],
-                        None if a["mx"] == -np.inf else float(a["mx"]))
-        if prof is not None:
-            prof.add("reduce", time.monotonic() - t_red)
+        with prof.phase("reduce"):
+            for pos in self.positions:
+                a = acc[pos]
+                if not a["v"]:
+                    out[pos] = ([], 0, None)
+                    continue
+                v = np.concatenate(a["v"])
+                s = np.concatenate(a["s"])
+                l = np.concatenate(a["l"])
+                order = np.lexsort((l, s, -v))[: self.k]
+                rows = [{"seg": int(s[i]), "local": int(l[i]),
+                         "score": float(v[i])} for i in order]
+                out[pos] = (rows, a["tot"],
+                            None if a["mx"] == -np.inf else float(a["mx"]))
         return out
 
-    def run(self, searcher, prof=None) -> dict:
+    def run(self, searcher, prof=NULL_PROFILER) -> dict:
         """Execute against every segment; returns {pos: (rows, total,
         max_score)} in the sequential path's row format.
 
@@ -346,45 +338,39 @@ class BatchGroup:
             health.record_failure("batch", exc)
             return self._run_host(searcher, prof=prof)
 
-    def _run_device(self, searcher, health, prof=None) -> dict:
+    def _run_device(self, searcher, health, prof=NULL_PROFILER) -> dict:
         import time
 
         from opensearch_tpu.common.cache import attached_cache
         from opensearch_tpu.common.device_health import check_finite
         from opensearch_tpu.common.tasks import check_current
 
-        if prof is not None:
-            prof.set("execution_path", "device_batched")
-            t_prep = time.monotonic()
-        cache = attached_cache(searcher, "_batch_prep_cache",
-                               name="search.batch_prep",
-                               max_weight=64 << 20,
-                               breaker="fielddata")
-        sig = self.signature()
-        prep = cache.get(sig)
-        if prep is None:
-            if prof is not None:
-                prof.set("batch_prep_cache", "miss")
-            prep = self._prepare(searcher)
-            cache.put(sig, prep)
-        elif prof is not None:
-            prof.set("batch_prep_cache", "hit")
-        if prof is not None:
-            prof.add("prepare", time.monotonic() - t_prep)
-            # segments the union prep dropped never dispatch: no query
-            # term exists there (the batch path's can-match analog)
-            staged = {so for so, _sp in prep["segs"]}
-            for so, seg in enumerate(searcher.segments):
-                if so not in staged:
-                    prof.seg_pruned(seg.seg_id, "pruned_can_match", 0.0)
+        prof.set("execution_path", "device_batched")
+        with prof.phase("prepare"):
+            cache = attached_cache(searcher, "_batch_prep_cache",
+                                   name="search.batch_prep",
+                                   max_weight=64 << 20,
+                                   breaker="fielddata")
+            sig = self.signature()
+            prep = cache.get(sig)
+            prof.set("batch_prep_cache", "miss" if prep is None else "hit")
+            if prep is None:
+                prep = self._prepare(searcher)
+                cache.put(sig, prep)
+        # segments the union prep dropped never dispatch: no query term
+        # exists there (the batch path's can-match analog)
+        staged = {so for so, _sp in prep["segs"]}
+        for so, seg in enumerate(searcher.segments):
+            if so not in staged:
+                prof.segment(seg.seg_id).pruned("pruned_can_match")
         self.last_stats = {
             "pruned": len(searcher.segments) - len(prep["segs"]),
             "scanned": len(prep["segs"]), "path": "device_batched"}
         launches = []             # (seg_order, vals[Q,k], idx, tot, mx)
         for seg_order, sp in prep["segs"]:
             check_current()    # cancellation point per segment program
-            t_seg = time.monotonic() if prof is not None else 0.0
             seg = searcher.segments[seg_order]
+            clock = prof.segment(seg.seg_id)
             dseg = seg.device()
             # the batched union kernel stays on the f32 lowering: on
             # quantized segments the full posting columns demand-stage
@@ -402,65 +388,59 @@ class BatchGroup:
                 n_pad=dseg.n_pad, budget=sp["budget"], k=kk,
                 need_counts=prep["need_counts"])
             launches.append((seg_order, vals, idx, tot, mx))
-            _device_ledger().record_dispatch(
-                getattr(dseg, "_ledger_group", None),
-                slice_gather=bm25_ops.slice_lowering(
-                    sp["union_tids"].shape[0], sp["budget"]))
-            if prof is not None:
-                prof.seg_scanned(seg.seg_id, time.monotonic() - t_seg)
+            P.book(P.union_program(sp["union_tids"].shape[0],
+                                   sp["budget"]), dseg)
+            clock.scanned()
         # ONE host sync region: convert whole launches after the dispatch loop
-        t_sync = time.monotonic()
-        t_red = t_sync if prof is not None else 0.0
-        synced = [(so, np.asarray(v), np.asarray(i), np.asarray(t),
-                   np.asarray(m)) for so, v, i, t, m in launches]
-        if synced:
-            _device_ledger().record_fetch(
-                sum(v.nbytes + i.nbytes + t.nbytes + m.nbytes
-                    for _so, v, i, t, m in synced),
-                time.monotonic() - t_sync)
-        # result-sanity guard at the batch sync region: non-finite
-        # scores mean the device returned poison — discard the whole
-        # group's device results and recompute on the byte-identical
-        # host path (files a flight-recorder capture + feeds the
-        # batch breaker via record_poison)
-        from opensearch_tpu.common.device_health import check_finite
-        for so, v, _i, _t, _m in synced:
-            bad = check_finite(v)
-            if bad:
-                seg = searcher.segments[so]
-                health.record_poison(
-                    kernel="batch_impact_union_topk",
-                    segment=seg.seg_id, index=searcher.index_name,
-                    shard=searcher.shard_id, bad=bad)
-                return self._run_host(searcher, prof=prof)
-        health.record_success("batch")
-        out = {}
-        for qi, pos in enumerate(self.positions):
-            rows_v, rows_s, rows_l = [], [], []
-            total = 0
-            max_score = -np.inf
-            for seg_order, avals, aidx, atot, amx in synced:
-                vals, idx = avals[qi], aidx[qi]
-                keep = vals > -np.inf
-                rows_v.append(vals[keep])
-                rows_s.append(np.full(int(keep.sum()), seg_order, _I32))
-                rows_l.append(idx[keep])
-                total += int(atot[qi])
-                max_score = max(max_score, float(amx[qi]))
-            if not rows_v:
-                out[pos] = ([], 0, None)
-                continue
-            v = np.concatenate(rows_v)
-            s = np.concatenate(rows_s)
-            l = np.concatenate(rows_l)
-            order = np.lexsort((l, s, -v))[: self.k]
-            rows = [{"seg": int(s[i]), "local": int(l[i]),
-                     "score": float(v[i])} for i in order]
-            out[pos] = (rows, total,
-                        None if max_score == -np.inf else float(max_score))
-        if prof is not None:
-            prof.add("reduce", time.monotonic() - t_red)
-        return out
+        with prof.phase("reduce"):
+            t_sync = time.monotonic()
+            synced = [(so, np.asarray(v), np.asarray(i), np.asarray(t),
+                       np.asarray(m)) for so, v, i, t, m in launches]
+            if synced:
+                _device_ledger().record_fetch(
+                    sum(v.nbytes + i.nbytes + t.nbytes + m.nbytes
+                        for _so, v, i, t, m in synced),
+                    time.monotonic() - t_sync)
+            # result-sanity guard at the batch sync region: non-finite
+            # scores mean the device returned poison — discard the whole
+            # group's device results and recompute on the byte-identical
+            # host path (files a flight-recorder capture + feeds the
+            # batch breaker via record_poison)
+            for so, v, _i, _t, _m in synced:
+                bad = check_finite(v)
+                if bad:
+                    seg = searcher.segments[so]
+                    health.record_poison(
+                        kernel="batch_impact_union_topk",
+                        segment=seg.seg_id, index=searcher.index_name,
+                        shard=searcher.shard_id, bad=bad)
+                    return self._run_host(searcher, prof=prof)
+            health.record_success("batch")
+            out = {}
+            for qi, pos in enumerate(self.positions):
+                rows_v, rows_s, rows_l = [], [], []
+                total = 0
+                max_score = -np.inf
+                for seg_order, avals, aidx, atot, amx in synced:
+                    vals, idx = avals[qi], aidx[qi]
+                    keep = vals > -np.inf
+                    rows_v.append(vals[keep])
+                    rows_s.append(np.full(int(keep.sum()), seg_order, _I32))
+                    rows_l.append(idx[keep])
+                    total += int(atot[qi])
+                    max_score = max(max_score, float(amx[qi]))
+                if not rows_v:
+                    out[pos] = ([], 0, None)
+                    continue
+                v = np.concatenate(rows_v)
+                s = np.concatenate(rows_s)
+                l = np.concatenate(rows_l)
+                order = np.lexsort((l, s, -v))[: self.k]
+                rows = [{"seg": int(s[i]), "local": int(l[i]),
+                         "score": float(v[i])} for i in order]
+                out[pos] = (rows, total,
+                            None if max_score == -np.inf else float(max_score))
+            return out
 
 
 def plan_batches(searcher, bodies: list) -> tuple[dict, list]:
@@ -472,8 +452,6 @@ def plan_batches(searcher, bodies: list) -> tuple[dict, list]:
     goes through the searcher's plan cache, so repeated bodies do zero
     parse/compile work here.
     """
-    from opensearch_tpu.search import plan as P
-
     groups: dict = {}
     fallback = []
     for pos, body in enumerate(bodies):

@@ -31,6 +31,7 @@ from opensearch_tpu.mapping.types import (
 from opensearch_tpu.ops import bm25 as bm25_ops
 from opensearch_tpu.search import query_dsl as dsl
 from opensearch_tpu.search import plan as P
+from opensearch_tpu.search.profile import NULL_PROFILER
 
 _I64_MIN = -(2**63)
 _I64_MAX = 2**63 - 1
@@ -169,7 +170,7 @@ def _ip_cidr_bind(value: str, boost: float) -> dict:
 
 
 def compile_query(q: dsl.Query, ctx: ShardContext, scored: bool = True,
-                  prof=None):
+                  prof=NULL_PROFILER):
     """Returns (plan, bind).  ``prof`` (a QueryProfiler) times the plan
     construction into the ``compile`` phase and records the root plan
     type — the compiler is a profile feeder, never a consumer."""
@@ -177,12 +178,8 @@ def compile_query(q: dsl.Query, ctx: ShardContext, scored: bool = True,
     if fn is None:
         raise IllegalArgumentError(
             f"query type [{type(q).__name__}] is not supported")
-    if prof is None:
-        return fn(q, ctx, scored)
-    import time
-    t0 = time.monotonic()
-    out = fn(q, ctx, scored)
-    prof.add("compile", time.monotonic() - t0)
+    with prof.phase("compile"):
+        out = fn(q, ctx, scored)
     prof.set("query_type", type(q).__name__)
     return out
 
@@ -869,8 +866,7 @@ def _knn_filter_masks(q, ctx) -> dict:
             with span.part("launch"):
                 _s, masks[seg_order] = P.run_full(fplan, dims, A, ins,
                                                   min_score)
-            ledger.record_dispatch(getattr(dseg, "_ledger_group", None),
-                                   slice_gather=fplan.slice_gathers(dims))
+            P.book(P.choose(fplan, dims, dseg.n_pad), dseg, ins)
         span.set_attribute("segments", len(masks))
     metrics().counter("search.knn.filtered.requests").inc()
     metrics().counter("search.knn.filter.programs").inc(len(masks))
@@ -892,7 +888,6 @@ def _c_knn(q, ctx, scored):
     from opensearch_tpu.common.telemetry import tracer
     from opensearch_tpu.ops.ivf import IvfPqIndex, ivf_search, ivfpq_search_l2
     from opensearch_tpu.ops.knn import knn_topk_auto
-    from opensearch_tpu.ops.topk import block_size
 
     ledger = device_ledger()
     ft = ctx.field_type(q.field)
@@ -973,9 +968,7 @@ def _c_knn(q, ctx, scored):
                 # the scan's call and the start of its copies
                 span.add_part("launch", time.monotonic_ns() - t_launch)
             pending.append((seg_order, vals, idx))
-            ledger.record_dispatch(
-                getattr(dseg, "_ledger_group", None),
-                block_topk=ann is None and block_size(dseg.n_pad, kk))
+            P.book(P.knn_scan(dseg.n_pad, kk, exact=ann is None), dseg)
     # phase 2: one host sync for all segments' top-k
     candidates = []          # (score, seg_order, local)
     if pending:

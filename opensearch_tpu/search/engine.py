@@ -422,18 +422,18 @@ class ContinuousBatcher:
         Every member shares (field, k) by group-key construction."""
         from opensearch_tpu.search.batch import BatchGroup
 
-        gprof = None
-        if any((m.body or {}).get("profile") for m in members):
-            from opensearch_tpu.search.profile import QueryProfiler
-            gprof = QueryProfiler()
-            gprof.set("plan_cache", "batched")
+        from opensearch_tpu.search.profile import (NULL_PROFILER,
+                                                   QueryProfiler)
+
+        gprof = (QueryProfiler() if any((m.body or {}).get("profile")
+                                        for m in members)
+                 else NULL_PROFILER)
+        gprof.set("plan_cache", "batched")
         group = BatchGroup(field, k)
         for i, m in enumerate(members):
             group.add(i, m.bind)
-        if gprof is not None:
-            gprof.set("batch", {"field": field, "k": k,
-                                "queries": len(members),
-                                "continuous": True})
+        gprof.set("batch", {"field": field, "k": k,
+                            "queries": len(members), "continuous": True})
         out = group.run(searcher, prof=gprof)
         _metrics().counter("search.batcher.dispatches").inc()
         _metrics().counter("search.batcher.batched").inc(len(members))
@@ -475,7 +475,7 @@ class ContinuousBatcher:
             scanned=member.stats.get("scanned", 0),
             batched=member.group_size,
             queue_wait_ms=member.wait_s * 1000)
-        if member.gprof is not None and body.get("profile"):
+        if body.get("profile"):
             # members share the group profiler's phases (that sharing IS
             # the coalescing attribution) plus their OWN queue wait
             from opensearch_tpu.search.profile import QueryProfiler

@@ -33,12 +33,13 @@ from opensearch_tpu.index.segment import (
     Segment,
     pad_pow2,
 )
-from opensearch_tpu.ops import bm25 as bm25_ops
-from opensearch_tpu.ops import topk as topk_ops
 from opensearch_tpu.search import insights
 from opensearch_tpu.search import plan as P
 from opensearch_tpu.search.compiler import ShardContext, compile_query
 from opensearch_tpu.search.fetch import filter_source
+from opensearch_tpu.search.insights import UNKEPT
+from opensearch_tpu.search.profile import (NULL_PROFILER, QueryProfiler,
+                                           describe_plan)
 from opensearch_tpu.search.query_dsl import parse_query
 
 _F32 = np.float32
@@ -311,9 +312,10 @@ class _StackedBag:
     array under ``(ckey, the segments' identities)``: one get, one put and
     one eviction a request, and nothing staged on a hit.  The cache and
     the bind run in the first launched segment's ``segment.prepare``
-    span; every later one takes its row under ``cache_get``.  A segment
-    without a row, or every segment where one is quantized, prepares on
-    its own (``ShardSearcher._prepared``)."""
+    span; every later one takes its row under ``cache_get``.
+    ``ShardSearcher.segment_inputs``, the one caller, asks it for every
+    segment; a segment without a row, or every segment where one is
+    quantized, is prepared there on its own."""
 
     def __init__(self, searcher, plan, bind, ckey, prof, ms_host):
         self.searcher = searcher
@@ -334,9 +336,8 @@ class _StackedBag:
         if got is not None:
             if self.hit:
                 span.set_attribute("prepared", "hit")
-            if self.prof is not None:
-                self.prof.inc("prepared_hits" if self.hit
-                              else "prepared_misses")
+            self.prof.inc("prepared_hits" if self.hit
+                          else "prepared_misses")
         return got
 
     def _bound(self, span):
@@ -362,9 +363,7 @@ class _StackedBag:
 
     def _bind(self, segs, span):
         plan, bind, ctx = self.plan, self.bind, self.searcher.ctx
-        with span.part("bind"), (
-                self.prof.phase("prepare") if self.prof is not None
-                else contextlib.nullcontext()):
+        with span.part("bind"), self.prof.phase("prepare"):
             dsegs = [seg.device() for seg in segs]
             if not segs or (not plan.features and any(
                     getattr(d, "quantized_mode", False) for d in dsegs)):
@@ -397,7 +396,8 @@ class ShardSearcher:
     # -- compiled-plan / prepared-bindings caches -------------------------
 
     def compiled(self, query_json: Optional[dict], scored: bool = True,
-                 with_key: bool = False, prof=None, iattrs=None):
+                 with_key: bool = False, prof=NULL_PROFILER,
+                 iattrs=UNKEPT):
         """(plan, bind) for a raw query body through the searcher's plan
         cache, keyed on the canonicalized JSON (key order in the body
         never misses).  The searcher is an immutable point-in-time view,
@@ -416,40 +416,30 @@ class ShardSearcher:
     def _compiled(self, query_json, scored, with_key, prof, iattrs):
         from opensearch_tpu.common.cache import attached_cache
 
-        t_lookup = time.monotonic() if prof is not None else 0.0
-        try:
-            ckey = (json.dumps(query_json, sort_keys=True,
-                               separators=(",", ":")), scored)
-        except (TypeError, ValueError):
-            ckey = None
-        if ckey is not None:
-            cache = attached_cache(self, "_plan_cache",
-                                   name="search.plan",
-                                   max_weight=16 << 20,
-                                   breaker="fielddata")
-            out = cache.get(ckey)
-            if prof is not None:
-                prof.add("plan_cache", time.monotonic() - t_lookup)
-            if out is not None:
-                _metrics().counter("search.plan_cache.hits").inc()
-                if prof is not None:
-                    prof.set("plan_cache", "hit")
-                if iattrs is not None:
-                    iattrs["plan_cache"] = "hit"
-                return (out, ckey) if with_key else out
-        elif prof is not None:
-            prof.add("plan_cache", time.monotonic() - t_lookup)
+        out = None
+        with prof.phase("plan_cache"):
+            try:
+                ckey = (json.dumps(query_json, sort_keys=True,
+                                   separators=(",", ":")), scored)
+            except (TypeError, ValueError):
+                ckey = None
+            if ckey is not None:
+                cache = attached_cache(self, "_plan_cache",
+                                       name="search.plan",
+                                       max_weight=16 << 20,
+                                       breaker="fielddata")
+                out = cache.get(ckey)
+        if out is not None:
+            _metrics().counter("search.plan_cache.hits").inc()
+            prof.set("plan_cache", "hit")
+            iattrs["plan_cache"] = "hit"
+            return (out, ckey) if with_key else out
         _metrics().counter("search.plan_cache.misses").inc()
-        if iattrs is not None:
-            iattrs["plan_cache"] = "miss"
-        if prof is not None:
-            prof.set("plan_cache", "miss")
-            with prof.phase("rewrite"):
-                q = parse_query(query_json)
-            out = compile_query(q, self.ctx, scored=scored, prof=prof)
-        else:
-            out = compile_query(parse_query(query_json), self.ctx,
-                                scored=scored)
+        iattrs["plan_cache"] = "miss"
+        prof.set("plan_cache", "miss")
+        with prof.phase("rewrite"):
+            q = parse_query(query_json)
+        out = compile_query(q, self.ctx, scored=scored, prof=prof)
         if ckey is not None:
             cache.put(ckey, out)
         return (out, ckey) if with_key else out
@@ -481,13 +471,14 @@ class ShardSearcher:
         walk(value)
         return total
 
-    def _segment_inputs(self, plan, bind, seg, needed, ckey, prof,
-                        dseg=None, stacked=None):
+    def segment_inputs(self, plan, bind, seg, needed, ckey,
+                       prof=NULL_PROFILER, dseg=None, stacked=None):
         """(dseg, dims, ins, A): everything one segment's program takes,
         under a ``segment.prepare`` span — the host work and the H2D
-        before a launch.  The span's parts: ``device`` (``seg.device()``),
-        ``cache_get`` / ``bind`` / ``cache_put`` (``_prepared``, or the
-        request's ``stacked`` bind) and ``arrays`` (the live mask and
+        before a launch, for this searcher's programs and the mesh's.
+        The span's parts: ``device`` (``seg.device()``), ``cache_get`` /
+        ``bind`` / ``cache_put`` (``_prepared``, or the request's
+        ``stacked`` bind) and ``arrays`` (the live mask and
         ``build_arrays``).  ``dseg``: a staging of the caller's own (the
         mesh stages each shard on its chip), in place of
         ``seg.device()``."""
@@ -518,12 +509,9 @@ class ShardSearcher:
         ``bind`` (``plan.prepare``: padding and the H2D ``stage_input``)
         and ``cache_put`` (weigher, lock, eviction)."""
         def prepare():
-            with span.part("bind"):
-                if prof is None:
-                    return plan.prepare(bind, seg, dseg, self.ctx)
-                prof.inc("prepared_misses")
-                with prof.phase("prepare"):
-                    return plan.prepare(bind, seg, dseg, self.ctx)
+            prof.inc("prepared_misses")
+            with span.part("bind"), prof.phase("prepare"):
+                return plan.prepare(bind, seg, dseg, self.ctx)
 
         if ckey is None:
             return prepare()
@@ -537,8 +525,7 @@ class ShardSearcher:
                 cache.put(key, out)
         else:
             span.set_attribute("prepared", "hit")
-            if prof is not None:
-                prof.inc("prepared_hits")
+            prof.inc("prepared_hits")
         return out
 
     def _prepared_cache(self):
@@ -558,14 +545,15 @@ class ShardSearcher:
             return 0
         (plan, bind), ckey = self.compiled(query_json, scored=False,
                                            with_key=True)
-        needed = plan.arrays()
-        total = 0
-        # can_match skip is safe here: count only sums, so segments the
-        # plan provably can't match contribute nothing either way
-        for seg, dseg, scores, matched in self._run_full(
-                plan, bind, needed, None, can_match_skip=True, ckey=ckey):
-            total += int(np.asarray(matched).sum())
-        return total
+        return self._matched(plan, bind, plan.arrays(), None, ckey=ckey)
+
+    def _matched(self, plan, bind, needed, min_score, **run) -> int:
+        """The docs ``plan`` matches: ``_run_full``'s masks, summed.  Its
+        can_match skip is safe here: a count only sums, so segments the
+        plan provably can't match contribute nothing either way."""
+        return sum(int(np.asarray(matched).sum()) for _s, _d, _sc, matched
+                   in self._run_full(plan, bind, needed, min_score,
+                                     can_match_skip=True, **run))
 
     def search(self, body: Optional[dict] = None, *,
                agg_partials: bool = False) -> dict:
@@ -575,13 +563,9 @@ class ShardSearcher:
         (QueryPhaseResultConsumer partial-reduce analog)."""
         body = body or {}
         t0 = time.monotonic()
-        prof = None
-        if body.get("profile"):
-            # plan-time guard: the profiler exists ONLY for profiled
-            # requests; every downstream instrumentation point checks
-            # ``prof is not None`` (zero cost when profile is absent)
-            from opensearch_tpu.search.profile import QueryProfiler
-            prof = QueryProfiler()
+        # the profiler exists ONLY for profiled requests; the null one
+        # reads no clock
+        prof = QueryProfiler() if body.get("profile") else NULL_PROFILER
         with _tracer().start_span(
                 "shard.query_phase",
                 {"index": self.index_name, "shard": self.shard_id,
@@ -596,7 +580,7 @@ class ShardSearcher:
         return resp
 
     def _search_body(self, body: dict, t0: float, *,
-                     agg_partials: bool = False, prof=None) -> dict:
+                     agg_partials: bool, prof) -> dict:
         size = int(body.get("size", 10))
         from_ = int(body.get("from", 0))
         deadline = SearchDeadline(body.get("timeout"), t0)
@@ -690,8 +674,9 @@ class ShardSearcher:
                 collapse, views, search_after=search_after)
         elif sort_specs is None:
             if views is not None:
-                rows, total, max_score = self._topk_from_views(
-                    views, k_want, prof=prof)
+                with prof.phase("reduce"):
+                    rows, total, max_score = self._topk_from_views(
+                        views, k_want)
             else:
                 rows, total, max_score, total_is_lower_bound = self._topk(
                     plan, bind, needed, k_want, min_score,
@@ -699,10 +684,13 @@ class ShardSearcher:
                     allow_kth_prune=allow_kth_prune, prof=prof,
                     iattrs=ia)
         else:
-            rows, total, max_score = self._field_sorted(
-                plan, bind, needed, k_want, sort_specs, min_score, views,
-                search_after=search_after, deadline=deadline, ckey=ckey,
-                prof=prof)
+            # host-side key build + comparator sort is the reduce share
+            # (the segment scan's phases, recorded inside, are not)
+            with prof.phase("reduce"):
+                rows, total, max_score = self._field_sorted(
+                    plan, bind, needed, k_want, sort_specs, min_score,
+                    views, search_after=search_after, deadline=deadline,
+                    ckey=ckey, prof=prof)
         if rescore is not None and rows:
             rows, max_score = self._rescored(rows, rescore)
         rows = rows[from_: from_ + size]
@@ -720,10 +708,8 @@ class ShardSearcher:
             else:
                 aggregations = execu.run(aggs_json, seg_views)
 
-        t_fetch = time.monotonic() if prof is not None else 0.0
-        hits = self._fetch(rows, source_spec, fetch_extras)
-        if prof is not None:
-            prof.add("fetch", time.monotonic() - t_fetch)
+        with prof.phase("fetch"):
+            hits = self._fetch(rows, source_spec, fetch_extras)
 
         took = int((time.monotonic() - t0) * 1000)
         xfer1 = _ledger().transfer_snapshot()
@@ -748,12 +734,11 @@ class ShardSearcher:
                 "hits": hits,
             },
         }
-        if prof is not None:
+        if body.get("profile"):
             # real phase-attributed profile (search/profile/query/
             # QueryProfiler analog at program granularity: the device
             # runs fused programs, so per-collector callbacks don't
             # exist — phases are the host-side stages around them)
-            from opensearch_tpu.search.profile import describe_plan
             resp["profile"] = {"shards": [prof.shard_section(
                 self.index_name, self.shard_id,
                 plan_type=type(plan).__name__,
@@ -799,9 +784,6 @@ class ShardSearcher:
         deadline = SearchDeadline(body.get("timeout"), t0)
         conf = NormalizationConfig(body.get("_hybrid_pipeline"))
         profiled = bool(body.get("profile"))
-        if profiled:
-            from opensearch_tpu.search.profile import (QueryProfiler,
-                                                       describe_plan)
         sections = []
         per_query_rows = []
         max_total = 0
@@ -809,7 +791,7 @@ class ShardSearcher:
         for i, sub_json in enumerate(body["query"]["hybrid"]["queries"]):
             if deadline.expired():
                 break            # partial: combine what completed
-            prof = QueryProfiler() if profiled else None
+            prof = QueryProfiler() if profiled else NULL_PROFILER
             sub_ia = {"pruned": 0, "scanned": 0}
             with _tracer().start_span("hybrid.subquery", {"i": i}) as span:
                 (plan, bind), ckey = self.compiled(
@@ -829,7 +811,7 @@ class ShardSearcher:
                 ia["execution_path"] = "host"
             ia["pruned"] += sub_ia["pruned"]
             ia["scanned"] += sub_ia["scanned"]
-            if prof is not None:
+            if profiled:
                 sections.append(prof.shard_section(
                     self.index_name, self.shard_id,
                     plan_type=type(plan).__name__,
@@ -902,22 +884,21 @@ class ShardSearcher:
         groups, fallback = plan_batches(self, bodies)
         results: list = [None] * len(bodies)
         for g in groups.values():
-            gprof = None
-            if any((bodies[p] or {}).get("profile") for p in g.positions):
-                # ONE profiler per coalesced group: members share the
-                # group's phase timings by construction (that sharing IS
-                # the batch-coalescing attribution)
-                from opensearch_tpu.search.profile import QueryProfiler
-                gprof = QueryProfiler()
-                # members were parsed/compiled during batch planning
-                # (through the plan cache, counted in the
-                # search.plan_cache.* metrics) — per-member hit/miss is
-                # not attributable after coalescing
-                gprof.set("plan_cache", "batched")
-                gprof.set("batch", {
-                    "field": g.field, "k": g.k,
-                    "queries": len(g.positions),
-                    "positions": list(g.positions)})
+            # ONE profiler per coalesced group: members share the
+            # group's phase timings by construction (that sharing IS
+            # the batch-coalescing attribution)
+            gprof = (QueryProfiler() if any(
+                (bodies[p] or {}).get("profile") for p in g.positions)
+                else NULL_PROFILER)
+            # members were parsed/compiled during batch planning
+            # (through the plan cache, counted in the
+            # search.plan_cache.* metrics) — per-member hit/miss is
+            # not attributable after coalescing
+            gprof.set("plan_cache", "batched")
+            gprof.set("batch", {
+                "field": g.field, "k": g.k,
+                "queries": len(g.positions),
+                "positions": list(g.positions)})
             xfer0 = _ledger().transfer_snapshot()
             g_out = g.run(self, prof=gprof)
             xfer1 = _ledger().transfer_snapshot()
@@ -926,10 +907,8 @@ class ShardSearcher:
             g_xfer = (xfer1[0] - xfer0[0]) + (xfer1[1] - xfer0[1])
             for pos, (rows, total, max_score) in g_out.items():
                 body = bodies[pos] or {}
-                t_fetch = time.monotonic() if gprof is not None else 0.0
-                hits = self._hits_from_rows(rows, body.get("_source"))
-                if gprof is not None:
-                    gprof.add("fetch", time.monotonic() - t_fetch)
+                with gprof.phase("fetch"):
+                    hits = self._hits_from_rows(rows, body.get("_source"))
                 # batched bodies never carry a [timeout] (plan_batches
                 # sends those to the sequential fallback, which owns the
                 # deadline checks), so false is exact here
@@ -956,7 +935,7 @@ class ShardSearcher:
                     scanned=g.last_stats["scanned"],
                     transfer_bytes=g_xfer,
                     batched=len(g.positions))
-                if gprof is not None and body.get("profile"):
+                if body.get("profile"):
                     results[pos]["profile"] = {"shards": [
                         gprof.shard_section(
                             self.index_name, self.shard_id,
@@ -1038,7 +1017,7 @@ class ShardSearcher:
 
     # -- internals --------------------------------------------------------
 
-    def _pruned(self, plan, bind, seg, ms_host, kth, iattrs, prof, t_seg):
+    def _pruned(self, plan, bind, seg, ms_host, kth, iattrs, clock):
         """Why ``seg`` gets no program, or None where it must be
         scored: no query term occurs in it (``pruned_can_match``); its
         block-max bound is under ``min_score`` (``pruned_min_score``,
@@ -1046,15 +1025,13 @@ class ShardSearcher:
         cannot beat the running k-th score (``pruned_kth``: the k-th
         holder dispatched earlier, so it wins any tie at exactly the
         bound by the seg-asc tie-break, and totals become a lower
-        bound).  A pruned segment is counted here, for every caller."""
+        bound).  A pruned segment is counted here, for every caller, and
+        given to the profiler's ``clock``."""
         reason = self._prune_reason(plan, bind, seg, ms_host, kth)
         if reason is not None:
             _metrics().counter("search.segments_pruned").inc()
-            if iattrs is not None:
-                iattrs["pruned"] += 1
-            if prof is not None:
-                prof.seg_pruned(seg.seg_id, reason,
-                                time.monotonic() - t_seg)
+            iattrs["pruned"] += 1
+            clock.pruned(reason)
         return reason
 
     @staticmethod
@@ -1088,7 +1065,7 @@ class ShardSearcher:
 
     def _run_full(self, plan, bind, needed, min_score,
                   can_match_skip=False, deadline=None, ckey=None,
-                  prof=None, iattrs=None):
+                  prof=NULL_PROFILER, iattrs=UNKEPT):
         """``can_match_skip`` is ONLY safe for consumers that don't index
         the yielded tuples by position (views/aggs paths align with
         self.segments and must see every segment).  An expired
@@ -1110,20 +1087,16 @@ class ShardSearcher:
             check_current()        # cancellation point per segment program
             if deadline is not None and deadline.expired():
                 return
-            t_seg = time.monotonic() if prof is not None else 0.0
+            clock = prof.segment(seg.seg_id)
             if can_match_skip and self._pruned(plan, bind, seg, None, None,
-                                               iattrs, prof, t_seg):
+                                               iattrs, clock):
                 continue
-            # phases stay disjoint: prepare time is measured inside
-            # _prepared, so the dispatch share is the remainder
-            prep0 = (prof.phases.get("prepare", 0.0)
-                     if prof is not None else 0.0)
             with _tracer().start_span(
                     "segment.dispatch",
                     {"segment": seg.seg_id, "index": self.index_name,
                      "shard": self.shard_id}) as span:
                 try:
-                    dseg, dims, ins, A = self._segment_inputs(
+                    dseg, dims, ins, A = self.segment_inputs(
                         plan, bind, seg, needed, ckey, prof)
                     with span.part("launch"):
                         scores, matched = P.run_full(plan, dims, A, ins,
@@ -1138,15 +1111,9 @@ class ShardSearcher:
                         plan, f"device failure on segment [{seg.seg_id}], "
                               "full-scores pass", exc) from exc
             health.record_success("dispatch")
-            _ledger().record_dispatch(
-                getattr(dseg, "_ledger_group", None),
-                slice_gather=plan.slice_gathers(dims))
-            if iattrs is not None:
-                iattrs["scanned"] += 1
-            if prof is not None:
-                prof.seg_scanned(seg.seg_id, max(
-                    0.0, time.monotonic() - t_seg
-                    - (prof.phases.get("prepare", 0.0) - prep0)))
+            P.book(P.choose(plan, dims, dseg.n_pad), dseg, ins)
+            iattrs["scanned"] += 1
+            clock.scanned()
             yield seg, dseg, scores, matched
 
     def _merge_topk(self, per_seg, k_want, total, max_score):
@@ -1168,7 +1135,8 @@ class ShardSearcher:
         return rows, total, (None if max_score == -np.inf else float(max_score))
 
     def _topk(self, plan, bind, needed, k_want, min_score, deadline=None,
-              ckey=None, allow_kth_prune=False, prof=None, iattrs=None):
+              ckey=None, allow_kth_prune=False, prof=NULL_PROFILER,
+              iattrs=UNKEPT):
         """Returns (rows, total, max_score, total_is_lower_bound).
 
         Block-max pruning: segments whose ``plan.max_score_bound`` can't
@@ -1176,39 +1144,24 @@ class ShardSearcher:
         from hits AND totals anyway).  With ``allow_kth_prune`` (the
         request waived exact totals via track_total_hits=false),
         segments that can't beat the running k-th score are skipped too
-        — the k-th score is harvested opportunistically from programs
-        that already finished, never blocking the async dispatch
-        pipeline.
+        — the k-th score is harvested from programs that already
+        finished, never blocking the async dispatch pipeline.
 
-        Every backend runs the one lowering, ``P.run_topk``.  A segment
-        leaves it for ``_score_on_host`` only on what this loop
-        observes: the device breaker not allowing, the segment evicted
-        under the device budget, a device error at dispatch or at sync,
-        a non-finite result.  ``execution_path`` reports what served the
-        request: ``host`` where every scored segment was recovered."""
-        from opensearch_tpu.common.device_health import (check_finite,
-                                                         is_device_error)
+        Every backend runs the one lowering, ``P.run_topk``, as
+        ``P.choose`` finds it and ``P.book`` books it.  A segment leaves
+        it for ``_score_on_host`` only on what this loop observes: the
+        device breaker not allowing, the segment evicted under the
+        device budget, a device error at dispatch or at sync, a
+        non-finite result (``_sync_topk``).  ``execution_path``: ``host``
+        where every scored segment was recovered."""
+        from opensearch_tpu.common.device_health import is_device_error
         from opensearch_tpu.common.tasks import check_current
 
-        health = _health()
-
         if k_want == 0:            # size=0: counts only (aggs-style request)
-            inner = ("can_match", "dispatch", "prepare")
-            if prof is not None:
-                t_red = time.monotonic()
-                spent0 = sum(prof.phases.get(p, 0.0) for p in inner)
-            total = sum(int(np.asarray(m).sum()) for _s, _d, _sc, m
-                        in self._run_full(plan, bind, needed, min_score,
-                                          can_match_skip=True,
-                                          deadline=deadline, ckey=ckey,
-                                          prof=prof, iattrs=iattrs))
-            if prof is not None:
-                # the generator's own phases were recorded inline; the
-                # residual host-side sum is the reduce share
-                spent = sum(prof.phases.get(p, 0.0)
-                            for p in inner) - spent0
-                prof.add("reduce", max(
-                    0.0, time.monotonic() - t_red - spent))
+            with prof.phase("reduce"):
+                total = self._matched(plan, bind, needed, min_score,
+                                      deadline=deadline, ckey=ckey,
+                                      prof=prof, iattrs=iattrs)
             return [], total, None, False
 
         # phase 1: DISPATCH every segment's program without a host sync —
@@ -1217,6 +1170,7 @@ class ShardSearcher:
         # search answer in the XLA model; ref search/query/
         # ConcurrentQueryPhaseSearcher.java gets the same overlap from
         # slice threads)
+        health = _health()
         ms = _min_score_scalar(min_score)
         ms_host = None if min_score is None else float(min_score)
         if hasattr(plan, "prefetch_quantized"):
@@ -1228,13 +1182,8 @@ class ShardSearcher:
                 plan.prefetch_quantized(bind, self.segments)
             except Exception:
                 pass
-        bag_postings = bag_lanes = bag_sorted = bag_stacked = stacked = None
+        stacked = None
         if isinstance(plan, P.TermBagPlan) and plan.scored:
-            bag_postings = _metrics().counter("search.term_bag.postings")
-            bag_lanes = _metrics().counter("search.term_bag.budget_lanes")
-            bag_sorted = _metrics().counter("search.term_bag.sorted_lanes")
-            bag_stacked = _metrics().counter(
-                "search.term_bag.stacked_segments")
             if not allow_kth_prune:
                 # one staged input a request; a running k-th prunes after
                 # the bind, so that request stages one a segment
@@ -1245,9 +1194,7 @@ class ShardSearcher:
                 _metrics().counter("search.neural_sparse.requests").inc()
                 _metrics().counter("search.neural_sparse.query_tokens").inc(
                     len(bind["terms"]))
-        # a request's phrases: slots (of one program), and over its
-        # programs the anchors' positions and the lanes keyed
-        phrase_slots = phrase_positions = phrase_lanes = 0
+        phrase_slots = 0           # a request's phrase slots, of one program
         # [si, out]: a recovered segment's (vals, idx, tot, mx), or the
         # device's packed result (P.run_topk), its copy to the host
         # under way
@@ -1259,18 +1206,13 @@ class ShardSearcher:
             check_current()        # cancellation point per segment program
             if deadline is not None and deadline.expired():
                 break              # partial top-k; response flags timed_out
-            t_seg = time.monotonic() if prof is not None else 0.0
+            clock = prof.segment(seg.seg_id)
             reason = self._pruned(plan, bind, seg, ms_host, kth, iattrs,
-                                  prof, t_seg)
+                                  clock)
             if reason is not None:     # no staging, no program
                 total_is_lower_bound |= reason == "pruned_kth"
                 continue
-            if prof is not None:
-                # decision cost so far is can_match; the dispatch share
-                # starts here and excludes _prepared's own prepare phase
-                prof.add("can_match", time.monotonic() - t_seg)
-                t_disp = time.monotonic()
-                prep0 = prof.phases.get("prepare", 0.0)
+            clock.decided()
             with _tracer().start_span(
                     "segment.dispatch",
                     {"segment": seg.seg_id, "index": self.index_name,
@@ -1286,48 +1228,23 @@ class ShardSearcher:
                         "device circuit breaker open")
                 else:
                     try:
-                        dseg, dims, ins, A = self._segment_inputs(
+                        dseg, dims, ins, A = self.segment_inputs(
                             plan, bind, seg, needed, ckey, prof,
                             stacked=stacked)
                         k = min(k_want, dseg.n_pad)
-                        sorts = (plan.sorted_topk(dims, dseg.n_pad, k)
-                                 and self.ctx.all_live(seg))
+                        program = P.choose(plan, dims, dseg.n_pad, k,
+                                           self.ctx.all_live(seg))
                         # the part: the jitted program's call, to its
                         # return, and the start of the result's copy
+                        # (phase 2 then finds it on the host)
                         with span.part("launch"):
                             out = P.run_topk(plan, dims, k, A, ins, ms,
-                                             sorted_bag=sorts)
-                            # queued behind the program: phase 2 finds
-                            # the result on the host instead of asking
-                            # for it
+                                             sorted_bag=program.sorted_bag)
                             out.copy_to_host_async()
-                        phrases = list(P.phrase_dims(dims))
-                        _ledger().record_dispatch(
-                            getattr(dseg, "_ledger_group", None),
-                            slice_gather=plan.slice_gathers(dims),
-                            sorted_bag=sorts, phrase=bool(phrases),
-                            # a sorted bag's key is its budget's lanes
-                            block_topk=topk_ops.block_size(
-                                dims[1] if sorts else dseg.n_pad, k))
-                        if bag_postings is not None:
-                            # what the 4^k bucket rule costs in lanes:
-                            # postings gathered against lanes keyed
-                            bag_postings.inc(dims.postings)
-                            bag_lanes.inc(dims[1])
-                            if sorts:
-                                # the lanes the program's sort ran over
-                                bag_sorted.inc(bm25_ops.sorted_width(
-                                    dims[1], dims.postings, k))
-                            if ins[0].ndim == 2:   # a row of the stack
-                                bag_stacked.inc()
-                        if phrases:
-                            # wherever the phrase sits in the plan, and
-                            # on a plan-cache hit too: what its anchor
-                            # holds against the bucket it was keyed with
-                            phrase_slots = sum(pd.slots for pd in phrases)
-                            phrase_positions += sum(
-                                pd.anchor_positions for pd in phrases)
-                            phrase_lanes += sum(pd[1] for pd in phrases)
+                        P.book(program, dseg, ins)
+                        if program.phrases:
+                            phrase_slots = sum(
+                                pd.slots for pd in program.phrases)
                     except Exception as exc:
                         if not is_device_error(exc):
                             raise
@@ -1342,92 +1259,87 @@ class ShardSearcher:
                             exc)
                 recovered += isinstance(out, tuple)
                 launched.append([si, out])
-            if iattrs is not None:
-                iattrs["scanned"] += 1
-            if prof is not None:
-                prof.seg_scanned(seg.seg_id, max(
-                    0.0, time.monotonic() - t_disp
-                    - (prof.phases.get("prepare", 0.0) - prep0)))
-            if allow_kth_prune and len(launched) >= 1 \
-                    and si + 1 < len(self.segments):
+            iattrs["scanned"] += 1
+            clock.scanned()
+            if allow_kth_prune and si + 1 < len(self.segments):
                 kth = self._harvest_kth(launched, k_want, kth)
-        if phrase_lanes:
+        if phrase_slots:
             _metrics().counter("search.phrase.requests").inc()
             _metrics().counter("search.phrase.slots").inc(phrase_slots)
-            _metrics().counter("search.phrase.anchor_positions").inc(
-                phrase_positions)
-            _metrics().counter("search.phrase.budget_lanes").inc(
-                phrase_lanes)
-        # phase 2: ONE host-sync region over all segments' results —
-        # also the result-sanity guard: non-finite device scores are
-        # poison (a misbehaving accelerator, not a query property);
-        # they are discarded, recomputed on the host byte-identically,
-        # and filed as flight-recorder evidence
-        t_sync = time.monotonic()
-        t_red = t_sync if prof is not None else 0.0
-        per_seg = []
-        total = 0
-        max_score = -np.inf
-        fetched_bytes = fetched_arrays = 0
-        # the span only where a device result is read back: a recovered
-        # segment left numpy arrays
-        with (_tracer().start_span("device.sync", {"site": "topk"},
-                                   cpu=True)
-              if recovered < len(launched)
-              else contextlib.nullcontext()):
-            for si, out in launched:
-                if not isinstance(out, tuple):   # device result: ONE D2H read
-                    seg = self.segments[si]
-                    bad, fault = 0, None
-                    try:
-                        packed = np.asarray(out)
-                        out = P.unpack_topk(packed)
-                        bad = check_finite(out[0])
-                    except Exception as exc:       # fault surfaced at sync
-                        if not is_device_error(exc):
-                            raise
-                        health.record_failure("dispatch", exc)
-                        fault = exc
-                    if bad:
-                        health.record_poison(
-                            kernel="run_topk", segment=seg.seg_id,
-                            index=self.index_name, shard=self.shard_id,
-                            bad=bad)
-                    if bad or fault is not None:
-                        out = self._score_on_host(
-                            plan, bind, seg, k_want, min_score,
-                            ("non-finite device scores on" if bad
-                             else "device failure syncing")
-                            + f" segment [{seg.seg_id}]", fault)
-                        recovered += 1
-                    else:
-                        health.record_success("dispatch")
-                        fetched_bytes += packed.nbytes
-                        fetched_arrays += 1
-                vals, idx, tot, mx = out
-                vals = np.asarray(vals)
-                idx = np.asarray(idx)
-                keep = vals > -np.inf
-                per_seg.append((vals[keep],
-                                np.full(int(keep.sum()), si, _I32),
-                                idx[keep]))
-                total += int(tot)
-                max_score = max(max_score, float(mx))
-        if fetched_arrays:
-            _ledger().record_fetch(fetched_bytes,
-                                   time.monotonic() - t_sync,
-                                   arrays=fetched_arrays)
-        path = ("host" if launched and recovered == len(launched)
-                else "device")
-        if iattrs is not None:
+        return (*self._sync_topk(plan, bind, launched, recovered, k_want,
+                                 min_score, health, prof, iattrs),
+                total_is_lower_bound)
+
+    def _sync_topk(self, plan, bind, launched, recovered, k_want,
+                   min_score, health, prof, iattrs):
+        """``_topk``'s phase 2: ONE host-sync region over every launched
+        segment's result, and the merge: (rows, total, max_score).  Also
+        the result-sanity guard: non-finite device scores are poison (a
+        misbehaving accelerator, not a query property); they are
+        discarded, recomputed on the host byte-identically, and filed as
+        flight-recorder evidence."""
+        from opensearch_tpu.common.device_health import (check_finite,
+                                                         is_device_error)
+
+        with prof.phase("reduce"):
+            t_sync = time.monotonic()
+            per_seg = []
+            total = 0
+            max_score = -np.inf
+            fetched_bytes = fetched_arrays = 0
+            # the span only where a device result is read back: a
+            # recovered segment left numpy arrays
+            with (_tracer().start_span("device.sync", {"site": "topk"},
+                                       cpu=True)
+                  if recovered < len(launched)
+                  else contextlib.nullcontext()):
+                for si, out in launched:
+                    if not isinstance(out, tuple):   # ONE D2H read
+                        seg = self.segments[si]
+                        bad, fault = 0, None
+                        try:
+                            packed = np.asarray(out)
+                            out = P.unpack_topk(packed)
+                            bad = check_finite(out[0])
+                        except Exception as exc:   # fault surfaced at sync
+                            if not is_device_error(exc):
+                                raise
+                            health.record_failure("dispatch", exc)
+                            fault = exc
+                        if bad:
+                            health.record_poison(
+                                kernel="run_topk", segment=seg.seg_id,
+                                index=self.index_name, shard=self.shard_id,
+                                bad=bad)
+                        if bad or fault is not None:
+                            out = self._score_on_host(
+                                plan, bind, seg, k_want, min_score,
+                                ("non-finite device scores on" if bad
+                                 else "device failure syncing")
+                                + f" segment [{seg.seg_id}]", fault)
+                            recovered += 1
+                        else:
+                            health.record_success("dispatch")
+                            fetched_bytes += packed.nbytes
+                            fetched_arrays += 1
+                    vals, idx, tot, mx = out
+                    vals = np.asarray(vals)
+                    idx = np.asarray(idx)
+                    keep = vals > -np.inf
+                    per_seg.append((vals[keep],
+                                    np.full(int(keep.sum()), si, _I32),
+                                    idx[keep]))
+                    total += int(tot)
+                    max_score = max(max_score, float(mx))
+            if fetched_arrays:
+                _ledger().record_fetch(fetched_bytes,
+                                       time.monotonic() - t_sync,
+                                       arrays=fetched_arrays)
+            path = ("host" if launched and recovered == len(launched)
+                    else "device")
             iattrs["execution_path"] = path
-        if prof is not None:
             prof.set("execution_path", path)
-        rows, total, max_score = self._merge_topk(per_seg, k_want, total,
-                                                  max_score)
-        if prof is not None:
-            prof.add("reduce", time.monotonic() - t_red)
-        return rows, total, max_score, total_is_lower_bound
+            return self._merge_topk(per_seg, k_want, total, max_score)
 
     @staticmethod
     def _harvest_kth(launched, k_want, kth):
@@ -1456,11 +1368,8 @@ class ShardSearcher:
         cand = float(np.partition(vals, -k_want)[-k_want])  # sync-ok
         return cand if kth is None or cand > kth else kth
 
-    def _topk_from_views(self, views, k_want, prof=None):
+    def _topk_from_views(self, views, k_want):
         """Top-k out of an already-run full-scores pass (aggs requests)."""
-        if prof is not None:
-            with prof.phase("reduce"):
-                return self._topk_from_views(views, k_want)
         if k_want == 0:
             return [], sum(int(np.asarray(matched).sum())
                            for _seg, _dseg, _scores, matched in views), None
@@ -1525,17 +1434,13 @@ class ShardSearcher:
 
     def _field_sorted(self, plan, bind, needed, k_want, sort_specs, min_score,
                       views=None, row_filter=None, search_after=None,
-                      deadline=None, ckey=None, prof=None):
+                      deadline=None, ckey=None, prof=NULL_PROFILER):
         """``k_want=None`` returns EVERY matched row (scroll
         materialization); ``row_filter(seg_i, local)`` implements sliced
         scans; ``search_after`` drops rows at-or-before the given sort
         tuple (PIT pagination)."""
         rows = []
         total = 0
-        _inner = ("can_match", "dispatch", "prepare")
-        if prof is not None:
-            t_sort = time.monotonic()
-            spent0 = sum(prof.phases.get(p, 0.0) for p in _inner)
         if views is None:
             views = self._run_full(plan, bind, needed, min_score,
                                    deadline=deadline, ckey=ckey,
@@ -1592,12 +1497,6 @@ class ShardSearcher:
                     sv, int) else sv)
             out.append({"seg": row["seg"], "local": row["local"],
                         "score": None, "sort": vals})
-        if prof is not None:
-            # host-side key build + comparator sort is the reduce share
-            # (segment scan phases were recorded inline by _run_full)
-            spent = sum(prof.phases.get(p, 0.0) for p in _inner) - spent0
-            prof.add("reduce", max(
-                0.0, time.monotonic() - t_sort - spent))
         return out, total, None
 
     def _rescored(self, rows, rescore):

@@ -66,6 +66,23 @@ def emit(**fields) -> Optional[dict]:
     return fields
 
 
+class _Unkept(dict):
+    """Attribution of a pass no insight record reads (a count, a sort,
+    a rescore): a write is dropped and a read is 0, so the executor
+    writes it without asking whether anyone keeps it."""
+
+    __slots__ = ()
+
+    def __setitem__(self, key, value) -> None:
+        pass
+
+    def __missing__(self, key) -> int:
+        return 0
+
+
+UNKEPT = _Unkept()
+
+
 def annotate_last(**fields) -> None:
     """Merge fields into the most recently emitted record (used by
     layers above the executor — e.g. the request-cache admission point

@@ -30,7 +30,7 @@ import re
 import threading
 from dataclasses import dataclass
 from functools import partial
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import numpy as np
 
@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from opensearch_tpu.common.device_ledger import device_ledger
+from opensearch_tpu.common.telemetry import metrics as _metrics
 from opensearch_tpu.index.segment import (LONG_MISSING_MAX, pad_bucket,
                                            pad_pow2)
 from opensearch_tpu.ops import bm25 as bm25_ops
@@ -163,21 +164,21 @@ class Plan:
         """True when the program ``eval`` traces for these dims copies
         postings runs as contiguous slices somewhere — decided by
         ``bm25_ops.slice_lowering``, which ``gather_postings`` itself
-        asks, from the same ``t_pad`` and ``budget``.  The executor
-        counts such programs (``_nodes/stats`` ``device.
-        slice_gather_programs``).  Composites ask their children."""
+        asks, from the same ``t_pad`` and ``budget``.  Asked by
+        ``choose`` alone, and counted by ``book`` (``_nodes/stats``
+        ``device.slice_gather_programs``).  Composites ask their
+        children."""
         return False
 
     def sorted_topk(self, dims, n_pad: int, k: int) -> bool:
         """True when ``run_topk`` can give this plan's top-k by
         ``eval_topk``, with no dense ``[n_pad]`` score vector: a scored
         term bag at the root, where ``bm25_ops.sorted_bag`` says so.
-        That path reads no live mask, so a caller (``ShardSearcher.
-        _topk``, the mesh) passes ``run_topk(sorted_bag=True)`` only for
-        a segment whose snapshot has no deleted doc (``ShardContext.
-        all_live``), and counts it (``_nodes/stats`` ``device.
-        sorted_bag_programs``); a segment with a deletion keeps the
-        dense path."""
+        That path reads no live mask, so ``choose``, the one caller,
+        takes it only for a segment whose snapshot has no deleted doc
+        (``ShardContext.all_live``), and ``book`` counts it
+        (``_nodes/stats`` ``device.sorted_bag_programs``); a segment
+        with a deletion keeps the dense path."""
         return False
 
     def max_score_bound(self, bind, seg) -> float:
@@ -2024,9 +2025,9 @@ def run_topk(plan: Plan, dims, k: int, A, ins, min_score, *,
     """One segment's top-k, packed (``unpack_topk`` on the host gives
     (top_scores[k], top_local_ids[k], total_matched, max_score)).
     ``min_score`` (-inf when unset) excludes docs from hits AND total,
-    matching MinimumScoreCollector semantics.  ``sorted_bag``: the
-    caller found ``plan.sorted_topk`` true and no deleted doc in its
-    snapshot of the segment; the same bits either way."""
+    matching MinimumScoreCollector semantics.  ``sorted_bag``: what
+    ``choose`` found (``plan.sorted_topk`` and no deleted doc in the
+    caller's snapshot of the segment); the same bits either way."""
     return _pack_topk(*_run_topk(plan, dims, k, A, ins, min_score,
                                  sorted_bag))
 
@@ -2047,3 +2048,94 @@ def run_full(plan: Plan, dims, A, ins, min_score):
     scores, matched = plan.eval(A, dims, ins)
     matched = matched & A["live"] & (scores >= min_score)
     return jnp.where(matched, scores, 0.0), matched
+
+
+# ---------------------------------------------------------------------------
+# What a launched program does, chosen once, and its booking.  Outside
+# ``ops/`` only this asks the kernels' rules (``sorted_bag``,
+# ``block_size``, ``slice_lowering``, ``sorted_width``), so that a rule
+# and the counters of the programs it shapes change together.
+# ---------------------------------------------------------------------------
+
+
+class Program(NamedTuple):
+    """What one launched program does, for ``book``: the lowerings its
+    key took and, for a scored term bag's top-k, what its bucket holds
+    (0 for any other program)."""
+
+    sorted_bag: bool = False     # its top-k from sorted postings
+    block_topk: bool = False     # ops/topk.py's two stages
+    slice_gather: bool = False   # gather_postings' contiguous slices
+    phrases: tuple = ()          # the PhraseDims of its key
+    postings: int = 0            # the bag's summed df (BagDims.postings)
+    budget_lanes: int = 0        # the lanes its gather bucket keys
+    sorted_lanes: int = 0        # the lanes its sort runs over
+
+
+def choose(plan: Plan, dims, n_pad: int, k: Optional[int] = None,
+           all_live: bool = False) -> Program:
+    """What the program of ``plan`` keyed ``dims`` does over a segment of
+    ``n_pad`` docs: a top-k of ``k`` (``run_topk``, ``run_topk_mesh``,
+    which take ``sorted_bag`` from here; ``all_live``: the caller's
+    snapshot of the segment holds no deleted doc), or, with ``k`` None,
+    the full scores (``run_full``), which take no top-k and are booked
+    by their gathers alone."""
+    slices = plan.slice_gathers(dims)
+    if k is None:
+        return Program(slice_gather=slices)
+    sorts = plan.sorted_topk(dims, n_pad, k) and all_live
+    # a sorted bag's top-k key is its budget's lanes
+    block = bool(topk_ops.block_size(dims[1] if sorts else n_pad, k))
+    phrases = tuple(phrase_dims(dims))
+    if not (isinstance(plan, TermBagPlan) and plan.scored):
+        return Program(sorts, block, slices, phrases)
+    return Program(sorts, block, slices, phrases, dims.postings, dims[1],
+                   bm25_ops.sorted_width(dims[1], dims.postings, k)
+                   if sorts else 0)
+
+
+def knn_scan(n_pad: int, k: int, exact: bool) -> Program:
+    """A kNN scan's top-k of ``k`` over ``n_pad`` rows: an ``exact`` one
+    takes ``ops/topk.py``'s two stages where ``block_size`` says so; an
+    ANN probe ranks its own candidates."""
+    return Program(block_topk=exact and bool(topk_ops.block_size(n_pad, k)))
+
+
+def union_program(t_pad: int, budget: int) -> Program:
+    """The batched union's program (``batch_impact_union_topk``) over a
+    union of ``t_pad`` terms and ``budget`` lanes."""
+    return Program(slice_gather=bm25_ops.slice_lowering(t_pad, budget))
+
+
+def book(program: Program, dseg=None, ins=(), *, mesh: bool = False):
+    """Books one launched program, after its launch: the ledger's
+    dispatch with the program's lowerings (``dseg``: the segment's
+    staging, whose group the dispatch makes the most recently used),
+    and the counters that sum over programs.  ``ins``: the program's
+    inputs; a bag's packed array of rank 2 is a row of the request's
+    stack (``_term_row``).  A program over the chips of a mesh
+    (``mesh``) books its lowerings and ``device.mesh_programs`` only:
+    the phrase and term-bag counters are the single chip's."""
+    device_ledger().record_dispatch(
+        getattr(dseg, "_ledger_group", None),
+        slice_gather=program.slice_gather,
+        block_topk=program.block_topk, sorted_bag=program.sorted_bag,
+        phrase=bool(program.phrases) and not mesh, mesh=mesh)
+    if mesh:
+        return
+    m = _metrics()
+    if program.budget_lanes:
+        # what the 4^k bucket rule costs in lanes: postings gathered
+        # against lanes keyed, and the lanes the sort ran over
+        m.counter("search.term_bag.postings").inc(program.postings)
+        m.counter("search.term_bag.budget_lanes").inc(program.budget_lanes)
+        m.counter("search.term_bag.sorted_lanes").inc(program.sorted_lanes)
+        m.counter("search.term_bag.stacked_segments").inc(
+            int(ins[0].ndim == 2))
+    if program.phrases:
+        # wherever the phrase sits in the plan: what its anchor holds
+        # against the bucket it was keyed with
+        m.counter("search.phrase.anchor_positions").inc(
+            sum(pd.anchor_positions for pd in program.phrases))
+        m.counter("search.phrase.budget_lanes").inc(
+            sum(pd[1] for pd in program.phrases))

@@ -22,10 +22,11 @@ XLA retrace/compile events, host-vs-device execution path, and msearch
 batch-coalescing group membership.
 
 Zero-cost contract: a ``QueryProfiler`` exists only when the request
-carried ``profile: true`` — every instrumentation point in the engine is
-guarded by ``prof is not None`` at plan/segment granularity (never
-per-posting), and profiled execution takes the *same* code path, so hits
-are byte-identical with and without profiling (pinned in
+carried ``profile: true``; every other request gets ``NULL_PROFILER``,
+whose methods do nothing and read no clock.  The engine calls either at
+plan/segment granularity (never per-posting) without asking which it
+holds, and profiled execution takes the *same* code path, so hits are
+byte-identical with and without profiling (pinned in
 tests/test_profile.py).
 """
 
@@ -71,7 +72,8 @@ class QueryProfiler:
     for ONE query execution (or one msearch batch group — members of a
     coalesced group share the group's timings by construction)."""
 
-    __slots__ = ("phases", "counts", "attrs", "segments", "_xla0")
+    __slots__ = ("phases", "counts", "attrs", "segments", "_xla0",
+                 "_spent", "_seg")
 
     def __init__(self):
         self.phases: dict[str, float] = {}       # name -> seconds
@@ -79,20 +81,27 @@ class QueryProfiler:
         self.attrs: dict = {}
         self.segments: list[dict] = []
         self._xla0 = xla_program_count()
+        self._spent = 0.0                        # every phase's seconds
+        self._seg = ("", 0.0, 0.0)               # (id, start, _spent then)
 
     # -- timing ------------------------------------------------------------
 
     def add(self, phase: str, seconds: float, n: int = 1) -> None:
         self.phases[phase] = self.phases.get(phase, 0.0) + seconds
         self.counts[phase] = self.counts.get(phase, 0) + n
+        self._spent += seconds
 
     @contextlib.contextmanager
     def phase(self, name: str):
-        t0 = time.monotonic()
+        """Times the block into ``name``, less what the phases nested in
+        it recorded meanwhile (a ``prepare`` inside a ``reduce`` pass):
+        the phases stay disjoint."""
+        t0, spent0 = time.monotonic(), self._spent
         try:
             yield
         finally:
-            self.add(name, time.monotonic() - t0)
+            self.add(name, max(0.0, time.monotonic() - t0
+                               - (self._spent - spent0)))
 
     # -- attribution -------------------------------------------------------
 
@@ -104,23 +113,39 @@ class QueryProfiler:
 
     # -- per-segment decisions ---------------------------------------------
 
-    def seg_scanned(self, seg_id: str, seconds: float) -> None:
-        """A segment that actually dispatched (device program launched,
-        or recovered by the host scorer)."""
-        self.add("dispatch", seconds)
-        self._seg(seg_id, "scanned", seconds)
+    def segment(self, seg_id: str) -> "QueryProfiler":
+        """Starts one segment's clock and returns the profiler: then
+        ``pruned``, or ``decided`` and ``scanned``, record the segment's
+        decision and its time, less the phases nested in it
+        (``prepare``).  From the start to ``decided`` is the
+        ``can_match`` share, from there to ``scanned`` the ``dispatch``
+        share; all of a ``pruned`` segment's time is ``can_match``'s."""
+        self._seg = (seg_id, time.monotonic(), self._spent)
+        return self
 
-    def seg_pruned(self, seg_id: str, reason: str,
-                   seconds: float) -> None:
-        """A segment skipped without dispatch: ``pruned_can_match`` /
-        ``pruned_min_score`` / ``pruned_kth`` — the decision cost lands
-        in the can_match phase."""
-        self.add("can_match", seconds)
-        self._seg(seg_id, reason, seconds)
+    def _seg_elapsed(self) -> float:
+        _seg_id, t0, spent0 = self._seg
+        return max(0.0, time.monotonic() - t0 - (self._spent - spent0))
 
-    def _seg(self, seg_id: str, decision: str, seconds: float) -> None:
+    def decided(self) -> None:
+        self.add("can_match", self._seg_elapsed())
+        self._seg = (self._seg[0], time.monotonic(), self._spent)
+
+    def pruned(self, reason: str) -> None:
+        """Skipped without dispatch: ``pruned_can_match`` /
+        ``pruned_min_score`` / ``pruned_kth``."""
+        self._decided(reason, "can_match")
+
+    def scanned(self) -> None:
+        """Dispatched: a device program launched, or the host scorer
+        recovered it."""
+        self._decided("scanned", "dispatch")
+
+    def _decided(self, decision: str, phase: str) -> None:
+        seconds = self._seg_elapsed()
+        self.add(phase, seconds)
         if len(self.segments) < _MAX_SEGMENT_RECORDS:
-            self.segments.append({"segment": seg_id,
+            self.segments.append({"segment": self._seg[0],
                                   "decision": decision,
                                   "time_in_nanos": int(seconds * 1e9)})
 
@@ -188,6 +213,28 @@ class QueryProfiler:
         if self.segments:
             section["segments"] = list(self.segments)
         return section
+
+
+class _NullProfiler:
+    """``QueryProfiler``'s stand-in for a request without ``profile``:
+    every method does nothing and reads no clock."""
+
+    __slots__ = ()
+    _PHASE = contextlib.nullcontext()
+
+    def phase(self, name: str) -> contextlib.nullcontext:
+        return self._PHASE
+
+    def segment(self, seg_id: str) -> "_NullProfiler":
+        return self
+
+    def _nothing(self, *args) -> None:
+        pass
+
+    set = inc = decided = pruned = scanned = _nothing
+
+
+NULL_PROFILER = _NullProfiler()
 
 
 def describe_plan(plan, bind) -> str:

@@ -214,6 +214,13 @@ def test_delayed_node_deranked_queries_reroute_cleanly(cluster):
                  if any(e["primary"] == "n2" and n not in copies_of(e)
                         for e in routing))
 
+    # the searches' programs compile first, through another coordinator:
+    # a process's first search pays the compile on whichever node serves
+    # it, which would rank that healthy node below the slow one here
+    warm = next(n for n in ids if n != coord)
+    for size in (10, 30):
+        nodes[warm].search("ars", {"query": {"match_all": {}},
+                                   "size": size})
     faults = FaultInjector(hub, seed=11)
     faults.slow_search_node("n2", 0.3)
     # first search: no evidence yet, legacy order dispatches to n2 —
